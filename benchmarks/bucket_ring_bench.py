@@ -35,6 +35,7 @@ import jax.numpy as jnp                                  # noqa: E402
 
 from repro.core import dist                              # noqa: E402
 from repro.launch import roofline                        # noqa: E402
+from repro.launch.mesh import make_host_mesh             # noqa: E402
 from repro.models.toy import ToyMLP                      # noqa: E402
 from repro.optim import sgd                              # noqa: E402
 
@@ -92,7 +93,7 @@ def main():
     model = ToyMLP(n_layers=6 if ARGS.fast else 12, d=64)
     params = model.init(jax.random.PRNGKey(0))
     batch = model.batch(jax.random.PRNGKey(1), n=4 * ARGS.workers)
-    mesh = dist.make_worker_mesh((ARGS.workers,), ("pod",))
+    mesh = make_host_mesh((ARGS.workers,), ("pod",))
 
     wires = {w: bench_wire(w, model, params, batch, mesh, steps=steps)
              for w in ("leaf", "bucketed")}

@@ -54,11 +54,12 @@ OVERHEAD_BUDGET_PCT = 5.0
 def run_step_child(wire: str, scrub: bool):
     import jax
     from repro.core import dist, faults
+    from repro.launch.mesh import make_host_mesh
     from repro.models.toy import ToyMLP
     from repro.optim import sgd
 
     workers = 4 if FAST else 8
-    mesh = dist.make_worker_mesh((workers,), ("pod",))
+    mesh = make_host_mesh((workers,), ("pod",))
     model = ToyMLP(n_layers=4, d=256)
     params = model.init(jax.random.PRNGKey(0))
     dcfg = dist.DistConfig(
@@ -85,7 +86,9 @@ def run_step_child(wire: str, scrub: bool):
 
 
 def _step_us(wire: str, scrub: bool) -> float:
-    env = dict(os.environ)
+    # fake CPU devices; pinned to the CPU so the child never competes for
+    # an accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d" % (
         4 if FAST else 8)
     src = os.path.join(_ROOT, "src")

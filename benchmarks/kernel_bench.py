@@ -47,7 +47,10 @@ def kernel_suite():
     us_ref = _bench(lambda: ref.squant_encode_ref(x, u, 1, *block))
     rows.append(("kernel/squant_encode_ref", us_ref, "oracle"))
 
-    us = _bench(lambda: fm.fused_memory_update(x, h, u, 0.25, s=1, block=block,
+    def tiles(a):                      # [M, N] -> [T, 256, 256] tile stack
+        return ref._blockify(a, *block).reshape((-1,) + block)
+    xt, ht, ut = tiles(x), tiles(h), tiles(u)
+    us = _bench(lambda: fm.fused_memory_update(xt, ht, ut, 0.25, s=1,
                                                interpret=True))
     # unfused: delta=g-h (2r 1w), encode (2r 1w), decode+h update (3r 1w)
     # fused: g,h,u read once; q, h_new written once

@@ -22,8 +22,10 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 def main() -> None:
     from benchmarks import dist_bench, fault_bench, kernel_bench, paper_figs
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import EventLog, span
 
+    enable_compile_cache()
     args = [a for a in sys.argv[1:]]
     fast = "--fast" in args
     if fast:

@@ -175,10 +175,11 @@ def _child_mesh_wires():
     from repro.core import codec as wire
     from repro.core import dist
     from repro.launch import roofline
+    from repro.launch.mesh import make_host_mesh
     from repro.models.toy import ToyMLP
     from repro.optim import sgd
 
-    mesh = dist.make_worker_mesh((4,), ("pod",))
+    mesh = make_host_mesh((4,), ("pod",))
     model = ToyMLP(n_layers=2, d=32)
     params = model.init(jax.random.PRNGKey(0))
     n_elems = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))

@@ -224,10 +224,11 @@ def _child_bucket_ring():
     (same construction idiom as tests/helpers/bucket_scenarios._setup)."""
     import jax
     from repro.core import dist
+    from repro.launch.mesh import make_host_mesh
     from repro.models.toy import ToyMLP
     from repro.optim import sgd
 
-    mesh = dist.make_worker_mesh((2, 2), ("p", "q"))
+    mesh = make_host_mesh((2, 2), ("p", "q"))
     model = ToyMLP(n_layers=2, d=32)
     params = model.init(jax.random.PRNGKey(0))
     dcfg = dist.DistConfig(worker_axes=("p", "q"), variant="artemis", s=3,

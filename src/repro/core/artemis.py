@@ -168,9 +168,10 @@ def _uplink_pallas(cfg: ArtemisConfig, c_up: wire.Codec, state: ArtemisState,
     memory update in one HBM pass (kernels/fused_memory.py) and server
     dequant-accumulate (kernels/ring_sum).
 
-    Each worker row is one kernel block, so the per-block scale is the
-    per-worker global L2 norm — identical semantics to ``squant`` on the
-    dense path (same keys, same uniforms, same levels up to fp reassociation).
+    Each worker row is one kernel tile (``[N, 1, d]``, a grid over N), so the
+    per-tile scale is the per-worker global L2 norm — identical semantics to
+    ``squant`` on the dense path (same keys, same uniforms, same levels up
+    to fp reassociation).
     Error feedback folds in by encoding ``g + e - h`` instead of ``g - h``
     (the EF buffer update happens outside the kernel).
     """
@@ -185,7 +186,9 @@ def _uplink_pallas(cfg: ArtemisConfig, c_up: wire.Codec, state: ArtemisState,
     # same uniforms the dense compressor would draw under vmap
     u = jax.vmap(lambda k: jax.random.uniform(k, (d,)))(up_keys)
     q, scales, h_fused = fused_memory_update(
-        g_in, state.h, u, alpha, s=s, block=(1, d), interpret=itp)
+        g_in[:, None], state.h[:, None], u[:, None], alpha, s=s,
+        interpret=itp)
+    q, scales, h_fused = q[:, 0], scales[:, 0], h_fused[:, 0]
     if not fc.wire_faults:
         # inactive workers neither transmit nor touch their memory
         new_h = active * h_fused + (1 - active) * state.h

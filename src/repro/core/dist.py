@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +50,7 @@ from repro.core import codec as wire
 from repro.core import faults as FLT
 from repro.kernels import bucket_ring as BK
 from repro.kernels import default_interpret
+from repro.launch.mesh import strip_axes
 from repro.obs import telemetry as obs_tel
 
 PyTree = Any
@@ -58,41 +59,6 @@ VARIANTS = ("sgd", "qsgd", "diana", "biqsgd", "artemis", "dore")
 
 WIRES = ("bucketed", "leaf")
 REDUCE_IMPLS = ("pipelined", "sequential", "psum")
-
-
-# ---------------------------------------------------------------------------
-# shard_map compatibility (new jax.shard_map API vs jax<=0.4 experimental)
-# ---------------------------------------------------------------------------
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs,
-                     manual_axes: Sequence[str]):
-    """Partial-manual shard_map on either jax API generation.
-
-    New API: ``jax.shard_map(axis_names=..., check_vma=False)`` — replication
-    of params/hbar across workers holds by construction (aggregate is summed
-    identically; downlink uses a shared PRNG key) but vma tracking cannot see
-    through it.  Old API (jax<=0.4.x): ``jax.experimental.shard_map`` with
-    ``auto = mesh axes - manual`` and ``check_rep=False`` (same reasoning).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
-
-
-def make_worker_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Worker-only mesh that works on both jax API generations (tests and
-    benchmarks simulate multi-host rings with fake CPU devices)."""
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -243,10 +209,17 @@ def _payload_acc(codec: wire.Codec, acc: jax.Array,
                  payload: wire.WirePayload, interpret: bool) -> jax.Array:
     """One dequant-accumulate: the native row-scale payload rides the fused
     kernels/bucket_ring path; any other codec decodes then adds."""
-    if codec.fused_acc:
-        return BK.bucket_acc(acc, payload["levels"], payload["scales"],
-                             interpret=interpret)
-    return acc + payload_decode(codec, payload)
+    if not codec.fused_acc:
+        return acc + payload_decode(codec, payload)
+    fold = functools.partial(BK.bucket_acc, interpret=interpret)
+    # GSPMD cannot partition a Mosaic kernel: where the worker shard_map
+    # leaves mesh axes auto, run it manual over every axis (the bucket stack
+    # is replicated over the auto ones)
+    mesh = jax.sharding.get_abstract_mesh()
+    if any(t != jax.sharding.AxisType.Manual for t in mesh.axis_types):
+        fold = jax.shard_map(fold, in_specs=P(), out_specs=P(),
+                             check_vma=False)
+    return fold(acc, payload["levels"], payload["scales"])
 
 
 def bucket_ring_reduce(codec: wire.Codec, payload: wire.WirePayload,
@@ -704,8 +677,10 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
             return (st._replace(artemis=st.artemis._replace(acc=acc)),
                     (loss, metrics))
 
-        return shard_map_compat(inner, mesh, (sspec, bspec),
-                                (sspec, (P(), mspec)), waxes)(state, batch)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
+                             out_specs=(sspec, (P(), mspec)),
+                             axis_names=set(waxes),
+                             check_vma=False)(state, batch)
 
     return local_fn
 
@@ -723,8 +698,17 @@ def state_shardings(mesh: Mesh, state, pshard, dcfg: Optional[DistConfig]):
     Bucketed wire: the artemis leaves are single stacked arrays, not
     per-param trees — h/e/acc carry a leading worker dim ([W, B, R, C] or a
     [W] stub) sharded over the worker axes, hbar ([B, R, C]) is replicated
-    (every worker applies the identical summed update)."""
+    (every worker applies the identical summed update).
+
+    Params (the policy's ``pshard``) lose the worker axes: the step takes
+    and returns them replicated over the workers, and an FSDP-style split
+    over a worker axis would make the second step compile again."""
     rep = NamedSharding(mesh, P())
+    if dcfg is not None and dcfg.worker_axes:
+        pshard = jax.tree.map(
+            lambda ns: NamedSharding(mesh, strip_axes(ns.spec,
+                                                      dcfg.worker_axes)),
+            pshard)
     if dcfg is not None and dcfg.bucketed:
         waxes = dcfg.worker_axes
         wsh = NamedSharding(mesh, P(waxes))
@@ -886,7 +870,12 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
             return (TrainState(params, opt_state, art, st.step + 1),
                     (loss, metrics))
 
-        return shard_map_compat(inner, mesh, (sspec, bspec),
-                                (sspec, (P(), mspec)), waxes)(state, batch)
+        # check_vma=False: params/hbar stay replicated across workers by
+        # construction (identically summed aggregate, shared downlink key),
+        # which vma tracking cannot see
+        return jax.shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
+                             out_specs=(sspec, (P(), mspec)),
+                             axis_names=set(waxes),
+                             check_vma=False)(state, batch)
 
     return init_state, step_fn

@@ -21,6 +21,7 @@ oracle in tests.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,15 +33,33 @@ def _acc_kernel(acc_ref, q_ref, s_ref, o_ref):
                                  * s_ref[...].astype(jnp.float32))
 
 
+# Scoped-VMEM budget for one block's buffers (acc, q, scales, out); the
+# pipeline double-buffers them, so twice this must stay under v5e's 16 MiB
+# default scoped limit.
+VMEM_BLOCK_BYTES = 4 << 20
+
+
+def default_block_rows(c: int) -> int:
+    """Rows per block for a [.., R, c] payload: a multiple of 32 (int8
+    sublane tiling) whose acc + q + scales + out buffers fit
+    ``VMEM_BLOCK_BYTES``.  The [br, 1] f32 scales block pads to 128 lanes
+    in VMEM, so it costs 512 B per row."""
+    per_row = c * (4 + 1 + 4) + 128 * 4
+    return max(32, VMEM_BLOCK_BYTES // per_row // 32 * 32)
+
+
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def bucket_acc(acc: jax.Array, q: jax.Array, scales: jax.Array, *,
-               block_rows: int = 0, interpret: bool = True) -> jax.Array:
+               block_rows: Optional[int] = None,
+               interpret: bool = True) -> jax.Array:
     """One ring-hop fold: ``acc + dequant(q, scales)``.
 
     acc [B, R, C] f32, q [B, R, C] int8, scales [B, R, 1] f32 (per-row,
     matching ``core/dist.squant_encode`` vmapped over buckets).
-    ``block_rows``: rows per grid block (0 = whole bucket per block; must
-    divide R otherwise).
+    ``block_rows``: rows per grid block; None sizes it to the VMEM budget
+    (``default_block_rows``).  The grid is ``cdiv(R, block_rows)`` per
+    bucket: the fold is elementwise, so the ragged last block needs no mask
+    (its out-of-range rows are never written back).
 
     In interpret mode with default blocking the grid is dropped entirely
     (one cell over the whole stack): each interpret-mode grid cell costs a
@@ -52,25 +71,24 @@ def bucket_acc(acc: jax.Array, q: jax.Array, scales: jax.Array, *,
     b, r, c = q.shape
     # named_scope: metadata-only tag so the kernel launch is findable on
     # the profiler timeline (repro.obs spans/Perfetto capture)
-    if interpret and block_rows == 0:
+    if interpret and block_rows is None:
         with jax.named_scope("bucket_acc"):
             return pl.pallas_call(
                 _acc_kernel,
                 out_shape=jax.ShapeDtypeStruct((b, r, c), jnp.float32),
                 interpret=interpret,
             )(acc, q, scales)
-    br = r if block_rows == 0 else block_rows
-    assert r % br == 0, (q.shape, block_rows)
+    br = min(r, default_block_rows(c) if block_rows is None else block_rows)
     with jax.named_scope("bucket_acc"):
         return pl.pallas_call(
             _acc_kernel,
-            grid=(b, r // br),
+            grid=(b, pl.cdiv(r, br)),
             in_specs=[
-                pl.BlockSpec((1, br, c), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, br, c), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, br, 1), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((None, br, c), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((None, br, c), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((None, br, 1), lambda i, j: (i, j, 0)),
             ],
-            out_specs=pl.BlockSpec((1, br, c), lambda i, j: (i, j, 0)),
+            out_specs=pl.BlockSpec((None, br, c), lambda i, j: (i, j, 0)),
             out_shape=jax.ShapeDtypeStruct((b, r, c), jnp.float32),
             interpret=interpret,
         )(acc, q, scales)

@@ -3,13 +3,18 @@
 Computes, in ONE pass over HBM (reads g, h, u; writes q, scale, h_new):
 
     delta  = g - h
-    (q,sc) = squant_encode(delta)           # per-tile s-quantization
+    (q,sc) = squant_encode(delta)           # one scale per tile
     h_new  = h + alpha * dequant(q, sc)     # memory update (Algorithm 1, line 4)
 
 Unfused this costs 3 reads + 2 writes of gradient-sized buffers plus the
 intermediate ``delta`` roundtrip; fused it is 3 reads + 2 writes total with
-delta/levels kept in VMEM — the memory-roofline win measured in
-benchmarks/kernel_bench.py.
+delta/levels kept in VMEM.
+
+Layout: a stack of T tiles ``[T, bm, bn]``, one grid step and one scale per
+tile.  Each block spans a whole tile, so its last two dims equal the
+array's and the TPU compiler accepts any (bm, bn): the simulator's
+``[N, 1, d]`` (one tile per worker row) as well as ``ops``' (256, 256)
+tiles.  Scales come back as ``[T, 1, 1]`` for the same reason.
 """
 from __future__ import annotations
 
@@ -19,55 +24,47 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.squant import DEFAULT_BLOCK, _grid
-
 
 def _fused_kernel(g_ref, h_ref, u_ref, alpha_ref, q_ref, scale_ref, h_new_ref,
                   *, s: int):
     g = g_ref[...]
     h = h_ref[...]
     delta = (g - h).astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(delta * delta))
+    sq = jnp.sum(delta * delta, axis=1, keepdims=True)
+    norm = jnp.sqrt(jnp.sum(sq, axis=0, keepdims=True))          # (1, 1)
     # non-finite tile => zero scale: wire payload decodes to 0 and the memory
     # update below degrades to h_new = h (matches squant.py's clamp)
     scale = jnp.where(jnp.isfinite(norm), norm / s, 0.0)
-    scale_ref[0, 0] = scale
+    scale_ref[...] = scale
     safe = jnp.where(norm > 0, norm, 1.0)
     r = jnp.abs(delta) / safe * s
     low = jnp.floor(r)
     psi = low + (u_ref[...].astype(jnp.float32) < (r - low)).astype(jnp.float32)
-    q = (jnp.sign(delta) * psi).astype(jnp.int8)
-    q_ref[...] = q
-    alpha = alpha_ref[0, 0].astype(g.dtype)
+    q = jnp.sign(delta) * psi
+    q_ref[...] = q.astype(jnp.int8)
+    alpha = alpha_ref[...].astype(g.dtype)
     h_new_ref[...] = h + alpha * (q.astype(g.dtype) * scale.astype(g.dtype))
 
 
-@functools.partial(jax.jit, static_argnames=("s", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("s", "interpret"))
 def fused_memory_update(g: jax.Array, h: jax.Array, u: jax.Array,
-                        alpha: jax.Array, *, s: int = 1, block=DEFAULT_BLOCK,
+                        alpha: jax.Array, *, s: int = 1,
                         interpret: bool = True):
-    """Returns (q int8, scales f32 grid, h_new)."""
+    """g, h, u: [T, bm, bn] tile stacks.  Returns (q int8 [T, bm, bn],
+    scales f32 [T, 1, 1], h_new [T, bm, bn])."""
     assert 1 <= s <= 126, s
-    bm, bn = block
-    gm, gn = _grid(g.shape, block)
+    t, bm, bn = g.shape
     alpha = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
+    tile = pl.BlockSpec((None, bm, bn), lambda i: (i, 0, 0))
+    one = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))
     return pl.pallas_call(
         functools.partial(_fused_kernel, s=s),
-        grid=(gm, gn),
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        ],
+        grid=(t,),
+        in_specs=[tile, tile, tile, pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        out_specs=[tile, one, tile],
         out_shape=[
             jax.ShapeDtypeStruct(g.shape, jnp.int8),
-            jax.ShapeDtypeStruct((gm, gn), jnp.float32),
+            jax.ShapeDtypeStruct((t, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct(g.shape, g.dtype),
         ],
         interpret=interpret,

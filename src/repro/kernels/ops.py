@@ -89,9 +89,14 @@ def memory_update(key: jax.Array, g: jax.Array, h: jax.Array, alpha,
     h2d, _ = _pack(h, block)
     u = jax.random.uniform(key, g2d.shape, dtype=g2d.dtype)
     itp = _auto_interpret(interpret)
-    q, scales, h_new2d = _fm.fused_memory_update(g2d, h2d, u, alpha, s=s,
-                                                 block=block, interpret=itp)
-    c = Compressed(q, scales)
+    # _pack's layout is one block column wide, so its (bm, bn) tiles are
+    # consecutive row blocks: the [T, bm, bn] tile stack is a free reshape
+    bm, bn = block
+    q, scales, h_new = _fm.fused_memory_update(
+        g2d.reshape(-1, bm, bn), h2d.reshape(-1, bm, bn),
+        u.reshape(-1, bm, bn), alpha, s=s, interpret=itp)
+    h_new2d = h_new.reshape(g2d.shape)
+    c = Compressed(q.reshape(g2d.shape), scales.reshape(-1, 1))
     delta_hat = decode(c, shape, block=block, dtype=g.dtype, interpret=itp)
     return delta_hat, _unpack(h_new2d, shape), c
 

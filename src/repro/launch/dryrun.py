@@ -1,4 +1,6 @@
 import os
+# a CPU-mesh simulation by design: it never competes for an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)

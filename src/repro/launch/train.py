@@ -10,13 +10,15 @@ configurable worker axis, checkpointing, and loss logging.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
 
 from repro import configs
 from repro.checkpoint import checkpointer
@@ -25,10 +27,59 @@ from repro.core import faults
 from repro.data.pipeline import ShardedBatches
 from repro.data.synthetic import TokenStream, TokenStreamConfig
 from repro.launch import mesh as M
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.obs import events as obs_events
 from repro.obs import spans as obs_spans
 from repro.optim import adam, sgd
+
+
+def make_mesh(spec: Optional[str] = None) -> Mesh:
+    """``"DxM"`` -> a (data=D, model=M) mesh; None -> every device on data."""
+    if spec is None:
+        return M.make_host_mesh()
+    d, m = (int(x) for x in spec.split("x"))
+    return M.make_host_mesh((d, m), ("data", "model"))
+
+
+def setup(cfg, mesh: Mesh, dcfg: Optional[dist.DistConfig], opt):
+    """(model, grad_specs, placed initial state, step_fn) as ``main`` trains
+    them: params from ``PRNGKey(0)`` under the mesh sharding policy."""
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    pshard = M.params_shardings(mesh, params)
+    banned = dcfg.worker_axes if dcfg else ()
+    model.set_sharding(M.layer_constraint_fn(mesh, banned),
+                       M.act_constraint_fn(mesh, banned))
+    gspecs = (jax.tree.map(lambda ns: M.strip_axes(ns.spec, banned), pshard)
+              if dcfg else None)
+    init_state, step_fn = dist.make_train_step(model, opt, dcfg, mesh,
+                                               grad_specs=gspecs)
+    with jax.set_mesh(mesh):
+        state = init_state(jax.device_put(params, pshard))
+    return model, gspecs, state, step_fn
+
+
+def batch_source(cfg, mesh: Mesh, batch: int, seq: int, workers: str):
+    """step -> the synthetic training batch, sharded over the batch axes."""
+    stream = TokenStream(TokenStreamConfig(vocab=cfg.vocab, seq_len=seq,
+                                           batch=batch))
+    batches = ShardedBatches(stream, mesh, batch_axes=(workers, "data"))
+    if cfg.family != "encdec":
+        return batches.batch_at
+    # the audio front end is stubbed (configs.input_specs): frame embeddings
+    # [B, n_frames, d_model] are drawn on the device from the stream's seed
+    # and the step, so no host copy enters the step
+    fkey = jax.random.PRNGKey(stream.cfg.seed)
+    frames_at = jax.jit(
+        lambda i: jax.random.normal(jax.random.fold_in(fkey, i),
+                                    (batch, cfg.n_frames, cfg.d_model)),
+        out_shardings=batches.sharding)
+
+    def batch_at(step: int) -> dict:
+        return {**batches.batch_at(step), "frames": frames_at(step)}
+
+    return batch_at
 
 
 def main(argv=None):
@@ -74,15 +125,9 @@ def main(argv=None):
     ap.add_argument("--max-rollbacks", type=int, default=3)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, reduced=args.reduced)
-    model = build_model(cfg)
-
-    if args.mesh:
-        d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:
-        mesh = M.make_host_mesh()
+    mesh = make_mesh(args.mesh)
 
     fc = faults.FaultConfig(
         straggler_rate=args.straggler_rate, p_stay=args.p_stay,
@@ -97,21 +142,10 @@ def main(argv=None):
                                faults=fc if fc.enabled else None)
 
     opt = adam(args.lr) if args.optimizer == "adam" else sgd(args.lr)
-    params = model.init(jax.random.PRNGKey(0))
-    pshard = M.params_shardings(mesh, params)
-    banned = dcfg.worker_axes if dcfg else ()
-    model.set_sharding(M.layer_constraint_fn(mesh, banned),
-                       M.act_constraint_fn(mesh, banned))
-    gspecs = (jax.tree.map(lambda ns: M.strip_axes(ns.spec, banned), pshard)
-              if dcfg else None)
-    init_state, step_fn = dist.make_train_step(model, opt, dcfg, mesh,
-                                               grad_specs=gspecs)
+    model, gspecs, state, step_fn = setup(cfg, mesh, dcfg, opt)
     local_fn = (dist.make_local_step(model, dcfg, mesh)
                 if dcfg and dcfg.local_steps > 1 else None)
-
-    stream = TokenStream(TokenStreamConfig(
-        vocab=cfg.vocab, seq_len=args.seq, batch=args.batch))
-    batches = ShardedBatches(stream, mesh, batch_axes=(args.workers, "data"))
+    batch_at = batch_source(cfg, mesh, args.batch, args.seq, args.workers)
 
     # console output + optional JSONL log share one schema-checked sink
     log = obs_events.EventLog(args.events, echo=True)
@@ -120,8 +154,6 @@ def main(argv=None):
                       "seq": args.seq, "lr": args.lr},
               fingerprint=f"{args.arch}:{args.dist}:s{args.s}")
     with jax.set_mesh(mesh):
-        params = jax.device_put(params, pshard)
-        state = init_state(params)
         jstep = jax.jit(step_fn)
         if (args.resume and args.ckpt_dir
                 and checkpointer.latest_step(args.ckpt_dir) is not None):
@@ -139,61 +171,70 @@ def main(argv=None):
         good_state, lr_scale, rollbacks = state, 1.0, 0
         start = int(state.step)
         i = start
-        while i < start + args.steps:
-            batch = batches.batch_at(i)
-            if jlocal is not None and (i + 1) % args.local_steps:
-                state, (loss, metrics) = jlocal(state, batch)
-            elif compile_s is None:
-                # compile-vs-execute split: the first communicating step
-                # pays the trace+compile; block so the span measures it
-                with obs_spans.span("train/compile+first_step"):
+        # the steady window opens at the first step after the compile and
+        # closes once the device has finished the last step
+        n_steady = 0
+        with contextlib.ExitStack() as steady_window:
+            while i < start + args.steps:
+                batch = batch_at(i)
+                if jlocal is not None and (i + 1) % args.local_steps:
+                    state, (loss, metrics) = jlocal(state, batch)
+                elif compile_s is None:
+                    # compile-vs-execute split: the first communicating step
+                    # pays the trace+compile; block so the span measures it
+                    with obs_spans.span("train/compile+first_step"):
+                        state, (loss, metrics) = jstep(state, batch)
+                        jax.block_until_ready(loss)
+                    compile_s = time.perf_counter() - t0
+                else:
+                    if not n_steady:
+                        steady_window.enter_context(
+                            obs_spans.span("train/steady_steps"))
+                    n_steady += 1
                     state, (loss, metrics) = jstep(state, batch)
-                    jax.block_until_ready(loss)
-                compile_s = time.perf_counter() - t0
-            else:
-                with obs_spans.span("train/step"):
-                    state, (loss, metrics) = jstep(state, batch)
-            if i % args.log_every == 0 or i == start + args.steps - 1:
-                loss_f = float(loss)
-                bad = not np.isfinite(loss_f) or (
-                    args.sentinel > 0 and loss_f > args.sentinel)
-                if bad and args.sentinel > 0:
-                    rollbacks += 1
-                    if rollbacks > args.max_rollbacks:
-                        raise RuntimeError(
-                            f"loss diverged {rollbacks} times; giving up")
-                    lr_scale *= args.backoff
-                    state = good_state
-                    opt2 = (adam(args.lr * lr_scale)
-                            if args.optimizer == "adam"
-                            else sgd(args.lr * lr_scale))
-                    _, step_fn2 = dist.make_train_step(model, opt2, dcfg,
-                                                       mesh, grad_specs=gspecs)
-                    jstep = jax.jit(step_fn2)
-                    log.emit("rollback", step=int(state.step),
-                             count=rollbacks, lr_scale=lr_scale)
-                    i = int(state.step)
-                    continue
-                rec = {"step": int(state.step), "loss": round(loss_f, 4),
-                       "nll": round(float(metrics["nll"]), 4),
-                       "wall_s": round(time.perf_counter() - t0, 1),
-                       "rollbacks": rollbacks}
-                logs.append(rec)
-                log.emit("train_step", **rec)
-                assert np.isfinite(loss_f), "loss diverged"
-                good_state = state
-            if (args.ckpt_every and args.ckpt_dir
-                    and int(state.step) % args.ckpt_every == 0):
-                checkpointer.save(args.ckpt_dir, int(state.step), state)
-            i += 1
+                if i % args.log_every == 0 or i == start + args.steps - 1:
+                    loss_f = float(loss)
+                    bad = not np.isfinite(loss_f) or (
+                        args.sentinel > 0 and loss_f > args.sentinel)
+                    if bad and args.sentinel > 0:
+                        rollbacks += 1
+                        if rollbacks > args.max_rollbacks:
+                            raise RuntimeError(
+                                f"loss diverged {rollbacks} times; giving up")
+                        lr_scale *= args.backoff
+                        state = good_state
+                        opt2 = (adam(args.lr * lr_scale)
+                                if args.optimizer == "adam"
+                                else sgd(args.lr * lr_scale))
+                        _, step_fn2 = dist.make_train_step(
+                            model, opt2, dcfg, mesh, grad_specs=gspecs)
+                        jstep = jax.jit(step_fn2)
+                        log.emit("rollback", step=int(state.step),
+                                 count=rollbacks, lr_scale=lr_scale)
+                        i = int(state.step)
+                        continue
+                    rec = {"step": int(state.step), "loss": round(loss_f, 4),
+                           "nll": round(float(metrics["nll"]), 4),
+                           "wall_s": round(time.perf_counter() - t0, 1),
+                           "rollbacks": rollbacks}
+                    logs.append(rec)
+                    log.emit("train_step", **rec)
+                    assert np.isfinite(loss_f), "loss diverged"
+                    good_state = state
+                if (args.ckpt_every and args.ckpt_dir
+                        and int(state.step) % args.ckpt_every == 0):
+                    checkpointer.save(args.ckpt_dir, int(state.step), state)
+                i += 1
+            jax.block_until_ready(state)
         if args.ckpt_dir:
             checkpointer.save(args.ckpt_dir, int(state.step), state)
     wall = time.perf_counter() - t0
-    steady = obs_spans.total("train/step")
+    steady = obs_spans.total("train/steady_steps")
     if compile_s is not None:
         log.emit("span", name="train/compile+first_step", dur_s=compile_s)
-    if steady > 0:
-        log.emit("span", name="train/steady_steps", dur_s=steady)
+    if n_steady:
+        log.emit("span", name="train/steady_steps", dur_s=steady,
+                 steps=n_steady)
     log.end(status="ok", wall_s=round(wall, 3))
     log.close()
     if args.log_file:

@@ -1,9 +1,7 @@
 """Bucketed-wire mesh scenarios (run in a subprocess with 8 fake CPU devices).
 
 Unlike tests/helpers/dist_scenarios.py (which exercises the production
-partial-manual mesh and needs the new-jax explicit-sharding API), these run
-on worker-only meshes through ``dist.shard_map_compat`` and therefore work on
-BOTH jax API generations — the bucketed transport is tested everywhere.
+partial-manual mesh), these run on worker-only meshes.
 
 Invoked by tests/test_bucketed.py as:
     python tests/helpers/bucket_scenarios.py <scenario>
@@ -21,6 +19,7 @@ import numpy as np
 
 from repro.core import bucketing, dist, faults
 from repro.launch import roofline
+from repro.launch.mesh import make_host_mesh
 from repro.models.toy import ToyMLP
 from repro.optim import sgd
 
@@ -32,7 +31,7 @@ def _setup(variant="artemis", *, wire="bucketed", reduce_impl="pipelined",
            bucket_bytes=4096, max_buckets=8, row=64, local_steps=1,
            error_feedback=False, fault_cfg=None,
            codec="squant", codec_kwargs=()):
-    mesh = dist.make_worker_mesh(mesh_shape, axes)
+    mesh = make_host_mesh(mesh_shape, axes)
     model = ToyMLP(n_layers=4, d=64)
     params = model.init(jax.random.PRNGKey(0))
     dcfg = dist.DistConfig(worker_axes=axes, variant=variant, s=s,

@@ -168,6 +168,35 @@ def scenario_dore_and_local_steps():
         assert not colls, f"local step must not communicate: {colls[:5]}"
 
 
+def scenario_train_main_compiles_once():
+    """``launch/train.py:main`` with the Artemis workers on ``data``, which
+    the param policy also splits (FSDP): whisper-tiny (reduced) trains on
+    its device-drawn frames, and the step compiles exactly once, so the
+    steady window times no compile."""
+    import logging
+    from repro.launch import train
+    from repro.obs import spans as obs_spans
+
+    compiles = []
+
+    class Count(logging.Handler):
+        def emit(self, rec):
+            if "Compiling jit(step_fn)" in rec.getMessage():
+                compiles.append(rec)
+
+    logging.getLogger("jax").addHandler(Count())
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_log_compiles", True)
+    logs = train.main(["--arch", "whisper-tiny", "--reduced", "--dist",
+                       "artemis", "--mesh", "8x1", "--workers", "data",
+                       "--steps", "3", "--batch", "8", "--seq", "32",
+                       "--log-every", "1"])
+    assert [r["step"] for r in logs] == [1, 2, 3], logs
+    assert all(np.isfinite(r["loss"]) for r in logs), logs
+    assert len(compiles) == 1, len(compiles)
+    assert obs_spans.total("train/steady_steps") > 0
+
+
 if __name__ == "__main__":
     name = sys.argv[1]
     globals()[f"scenario_{name}"]()

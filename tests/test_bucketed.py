@@ -1,10 +1,5 @@
 """Bucketed-wire transport tests. Each scenario runs in a subprocess with 8
-fake CPU devices (XLA device count is locked at first jax init).
-
-No AxisType skip here: the bucketed wire goes through
-``dist.shard_map_compat`` / ``dist.make_worker_mesh``, which work on both
-jax API generations — these scenarios are the dist coverage that always runs.
-"""
+fake CPU devices (XLA device count is locked at first jax init)."""
 import os
 import subprocess
 import sys

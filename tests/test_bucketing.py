@@ -45,7 +45,7 @@ def test_roundtrip_exact(spec):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(64, 4096), st.integers(1, 8), st.sampled_from([8, 32, 128]))
 def test_layout_invariants(bucket_bytes, max_buckets, row):
     tree = _tree(KEY, [(37, 11), (5,), (301,), (2, 3, 7)])
@@ -121,12 +121,22 @@ def test_bucket_acc_matches_oracle():
                                atol=1e-6)
 
 
-def test_bucket_acc_block_rows():
-    q, sc = _payload(KEY, 1, 2, 8, 16)
-    acc = jnp.zeros((2, 8, 16))
+@pytest.mark.parametrize("rows,block_rows", [(8, 4), (20, 8), (13, 32)])
+def test_bucket_acc_block_rows(rows, block_rows):
+    """Row-blocked grids, ragged last block included, match the gridless
+    fold bitwise."""
+    q, sc = _payload(KEY, 1, 2, rows, 16)
+    acc = jax.random.normal(jax.random.PRNGKey(2), (2, rows, 16))
     full = BK.bucket_acc(acc, q[0], sc[0])
-    blocked = BK.bucket_acc(acc, q[0], sc[0], block_rows=4)
+    blocked = BK.bucket_acc(acc, q[0], sc[0], block_rows=block_rows)
     np.testing.assert_array_equal(np.asarray(full), np.asarray(blocked))
+
+
+def test_default_block_rows_fit_vmem():
+    for c in (64, 256, 1024, 4096):
+        br = BK.default_block_rows(c)
+        assert br % 32 == 0
+        assert br * (c * 9 + 512) <= BK.VMEM_BLOCK_BYTES or br == 32
 
 
 def test_bucket_ring_sum_matches_hop_chain():

@@ -5,13 +5,7 @@ import os
 import subprocess
 import sys
 
-import jax.sharding
 import pytest
-
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="dist scenarios need jax.sharding.AxisType (jax >= 0.5 explicit-"
-           "sharding API); not available in this jax build")
 
 HELPER = os.path.join(os.path.dirname(__file__), "helpers", "dist_scenarios.py")
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -25,6 +19,7 @@ SCENARIOS = [
     "mesh_policy",
     "pipeline_sharding",
     "dore_and_local_steps",
+    "train_main_compiles_once",
 ]
 
 

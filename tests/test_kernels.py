@@ -65,12 +65,18 @@ def test_fused_memory_matches_ref(shape, s, alpha):
     block = (256, 256)
     g, u = _mk(shape, jnp.float32, seed=5)
     h, _ = _mk(shape, jnp.float32, seed=6)
-    q, scales, h_new = fm.fused_memory_update(g, h, u, alpha, s=s, block=block,
-                                              interpret=True)
+
+    def tiles(x):                      # [M, N] -> [T, bm, bn] tile stack
+        return ref._blockify(x, *block).reshape((-1,) + block)
+
+    q, scales, h_new = fm.fused_memory_update(tiles(g), tiles(h), tiles(u),
+                                              alpha, s=s, interpret=True)
     qr, sr, hr = ref.fused_memory_ref(g, h, u, alpha, s, *block)
-    np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
-    np.testing.assert_allclose(np.asarray(scales), np.asarray(sr), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(h_new), np.asarray(hr), rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(tiles(qr)))
+    np.testing.assert_allclose(np.asarray(scales).reshape(sr.shape),
+                               np.asarray(sr), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(h_new), np.asarray(tiles(hr)),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_dequant_apply_matches_ref():
