@@ -376,8 +376,6 @@ def artemis_aggregate_bucketed(cfg: DistConfig, state: ArtemisDistState,
     n = n_workers
     fc = FLT.of(cfg.faults)
     wc = cfg.wire_codec(layout.row)
-    up_key, dwn_key, active, part, flt_key = _round_keys(
-        cfg, state.step, wid, state.prev_active[0])
     alpha = cfg.alpha if cfg.alpha is not None else (
         _codec_alpha(cfg, [layout.row]) if cfg.memory else 0.0)
     p = cfg.p_participation
@@ -387,45 +385,56 @@ def artemis_aggregate_bucketed(cfg: DistConfig, state: ArtemisDistState,
     obs_scrub = jnp.zeros((), jnp.float32)
     obs_bytes = 0.0
 
-    g32 = gbuckets.astype(jnp.float32)
-    if fc.blowup_rate > 0.0:
-        hit = jax.random.bernoulli(jax.random.fold_in(flt_key, 2),
-                                   fc.blowup_rate, ())
-        g32 = jnp.where(hit, jnp.float32(fc.blowup_value), g32)
-        if cfg.telemetry:
-            obs_blow = hit.astype(jnp.float32)
-    if fc.scrub:
-        # non-finite local gradient => worker masked inactive BEFORE any
-        # arithmetic (0 * NaN is NaN, so the rows are zeroed too)
-        finite = jnp.all(jnp.isfinite(g32)).astype(jnp.float32)
-        active = active * finite
-        g32 = FLT.nan_to_zero(g32)
-    h = state.h[0].astype(jnp.float32) if cfg.memory else jnp.zeros_like(g32)
-    e_buf = state.e[0] if cfg.use_ef else None
-    delta = (g32 - h) * active
-    if cfg.use_ef:
-        delta = delta + e_buf
-
-    ok = active
-    if cfg.up_compress:
-        enc = bucketing.encode_buckets(wc, up_key, delta)
-        # PP2: an inactive worker's payload (its EF buffer under Dore) must
-        # contribute EXACTLY zero to the sum — zero the wire float leaves
-        # (the scales for squant, the values for sparsify).
-        enc = FLT.mask_payload(enc, active)
-        if fc.bitflip_rate > 0.0:
-            # only a payload actually on the wire can pick up flipped bits
-            enc = FLT.corrupt_payload(jax.random.fold_in(flt_key, 3), enc,
-                                      fc.bitflip_rate, only=active)
-        if fc.scrub:
-            # per-BUCKET checksum: a corrupt bucket is dropped through the
-            # same zero-scale path as inactivity; its h/e slices stay put
-            valid = jax.vmap(wc.validate)(enc)         # [B]
-            ok = active * valid.reshape(-1, 1, 1)      # [B,1,1] broadcast
-            enc = FLT.scrub_payload(enc, valid)
+    with jax.named_scope("encode"):
+        up_key, dwn_key, active, part, flt_key = _round_keys(
+            cfg, state.step, wid, state.prev_active[0])
+        g32 = gbuckets.astype(jnp.float32)
+        if fc.blowup_rate > 0.0:
+            hit = jax.random.bernoulli(jax.random.fold_in(flt_key, 2),
+                                       fc.blowup_rate, ())
+            g32 = jnp.where(hit, jnp.float32(fc.blowup_value), g32)
             if cfg.telemetry:
-                obs_scrub = valid.shape[0] - jnp.sum(valid)
-        if cfg.reduce_impl == "psum":
+                obs_blow = hit.astype(jnp.float32)
+        if fc.scrub:
+            # non-finite local gradient => worker masked inactive BEFORE any
+            # arithmetic (0 * NaN is NaN, so the rows are zeroed too)
+            finite = jnp.all(jnp.isfinite(g32)).astype(jnp.float32)
+            active = active * finite
+            g32 = FLT.nan_to_zero(g32)
+        h = (state.h[0].astype(jnp.float32) if cfg.memory
+             else jnp.zeros_like(g32))
+        e_buf = state.e[0] if cfg.use_ef else None
+        delta = (g32 - h) * active
+        if cfg.use_ef:
+            delta = delta + e_buf
+
+        ok = active
+        if cfg.up_compress:
+            enc = bucketing.encode_buckets(wc, up_key, delta)
+            # PP2: an inactive worker's payload (its EF buffer under Dore)
+            # must contribute EXACTLY zero to the sum — zero the wire float
+            # leaves (the scales for squant, the values for sparsify).
+            enc = FLT.mask_payload(enc, active)
+            if fc.bitflip_rate > 0.0:
+                # only a payload actually on the wire can pick up flipped bits
+                enc = FLT.corrupt_payload(jax.random.fold_in(flt_key, 3), enc,
+                                          fc.bitflip_rate, only=active)
+            if fc.scrub:
+                # per-BUCKET checksum: a corrupt bucket is dropped through the
+                # same zero-scale path as inactivity; its h/e slices stay put
+                valid = jax.vmap(wc.validate)(enc)         # [B]
+                ok = active * valid.reshape(-1, 1, 1)      # [B,1,1] broadcast
+                enc = FLT.scrub_payload(enc, valid)
+                if cfg.telemetry:
+                    obs_scrub = valid.shape[0] - jnp.sum(valid)
+        else:
+            dhat_i = delta * active
+
+    with jax.named_scope("ring"):
+        if not cfg.up_compress:
+            dhat_sum = jax.lax.psum(dhat_i, axes)
+            obs_bytes = 4.0 * float(np.prod(g32.shape))
+        elif cfg.reduce_impl == "psum":
             dhat_sum = jax.lax.psum(payload_decode(wc, enc), axes)
             # all-reduce proxy: result bytes ~ bytes sent per device on a
             # ring (the same convention launch/roofline uses)
@@ -436,27 +445,27 @@ def artemis_aggregate_bucketed(cfg: DistConfig, state: ArtemisDistState,
         else:
             dhat_sum = bucket_ring_reduce(wc, enc, axes, n)
             obs_bytes = (n - 1) * _payload_nbytes(enc)
-        dhat_i = payload_decode(wc, enc)
-    else:
-        dhat_i = delta * active
-        dhat_sum = jax.lax.psum(dhat_i, axes)
-        obs_bytes = 4.0 * float(np.prod(g32.shape))
 
-    if cfg.use_ef:
-        e_new = (ok * (delta - dhat_i) + (1 - ok) * e_buf)[None]
-    else:
-        e_new = state.e
-    if cfg.memory:
-        hbar = state.hbar.astype(jnp.float32)
-        ghat = hbar + dhat_sum / (p * n)
-        h_new = (h + alpha * dhat_i).astype(mdt)[None]
-        hbar_new = (hbar + alpha * dhat_sum / n).astype(mdt)
-    else:
-        ghat = dhat_sum / (p * n)
-        h_new, hbar_new = state.h, state.hbar
+    with jax.named_scope("memory"):
+        if cfg.up_compress:
+            dhat_i = payload_decode(wc, enc)
+        if cfg.use_ef:
+            e_new = (ok * (delta - dhat_i) + (1 - ok) * e_buf)[None]
+        else:
+            e_new = state.e
+        if cfg.memory:
+            hbar = state.hbar.astype(jnp.float32)
+            ghat = hbar + dhat_sum / (p * n)
+            h_new = (h + alpha * dhat_i).astype(mdt)[None]
+            hbar_new = (hbar + alpha * dhat_sum / n).astype(mdt)
+        else:
+            ghat = dhat_sum / (p * n)
+            h_new, hbar_new = state.h, state.hbar
     if cfg.dwn_compress:
         # zero-byte broadcast: identical key -> identical compression
-        ghat = payload_decode(wc, bucketing.encode_buckets(wc, dwn_key, ghat))
+        with jax.named_scope("downlink"):
+            ghat = payload_decode(wc, bucketing.encode_buckets(wc, dwn_key,
+                                                               ghat))
 
     new_state = ArtemisDistState(h_new, hbar_new, e_new, state.acc,
                                  jnp.reshape(part, (1,)), state.step + 1)
@@ -666,14 +675,17 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
         mspec = {"nll": P(), "aux": P()}
 
         def inner(st: TrainState, bt):
-            (loss, metrics), grads = jax.value_and_grad(
-                model.loss, has_aux=True)(st.params, bt)
-            if dcfg.bucketed:
-                gb = bucketing.bucketize(dcfg.layout(grads), grads)
-                acc = st.artemis.acc + gb[None]
-            else:
-                acc = jax.tree.map(lambda a, g: a + g.astype(a.dtype)[None],
-                                   st.artemis.acc, grads)
+            with jax.named_scope("model"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    model.loss, has_aux=True)(st.params, bt)
+            with jax.named_scope("wire"), jax.named_scope("bucketize"):
+                if dcfg.bucketed:
+                    gb = bucketing.bucketize(dcfg.layout(grads), grads)
+                    acc = st.artemis.acc + gb[None]
+                else:
+                    acc = jax.tree.map(
+                        lambda a, g: a + g.astype(a.dtype)[None],
+                        st.artemis.acc, grads)
             return (st._replace(artemis=st.artemis._replace(acc=acc)),
                     (loss, metrics))
 
@@ -796,20 +808,28 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
     telem = dcfg is not None and dcfg.telemetry
 
     def sgd_core(params, opt_state, art, stepno, batch, wid):
-        (loss, metrics), grads = jax.value_and_grad(model.loss, has_aux=True)(
-            params, batch)
+        # the phase scopes open outside jax.value_and_grad: under a transform
+        # a scope's name comes out wrapped (``jvp(model)``), not as a plain
+        # op_name path part
+        with jax.named_scope("model"):
+            (loss, metrics), grads = jax.value_and_grad(
+                model.loss, has_aux=True)(params, batch)
         obs = None
         if dcfg is not None and dcfg.worker_axes and dcfg.bucketed:
-            layout = dcfg.layout(grads)
-            gb = bucketing.bucketize(layout, grads)
-            if k_local > 1:
-                # fold in the locally-accumulated gradients since last sync
-                gb = (art.acc[0] + gb) / k_local
-                art = art._replace(acc=jnp.zeros_like(art.acc))
-            out = artemis_aggregate_bucketed(dcfg, art, gb, layout,
-                                             n_workers, wid)
-            (agg_b, art, obs) = out if telem else out + (None,)
-            agg = bucketing.unbucketize(layout, agg_b, like=grads)
+            with jax.named_scope("wire"):
+                layout = dcfg.layout(grads)
+                with jax.named_scope("bucketize"):
+                    gb = bucketing.bucketize(layout, grads)
+                    if k_local > 1:
+                        # fold in the locally-accumulated gradients since
+                        # last sync
+                        gb = (art.acc[0] + gb) / k_local
+                        art = art._replace(acc=jnp.zeros_like(art.acc))
+                out = artemis_aggregate_bucketed(dcfg, art, gb, layout,
+                                                 n_workers, wid)
+                (agg_b, art, obs) = out if telem else out + (None,)
+                with jax.named_scope("unbucketize"):
+                    agg = bucketing.unbucketize(layout, agg_b, like=grads)
         else:
             if k_local > 1:
                 grads = jax.tree.map(lambda a, g: (a[0] + g) / k_local,
@@ -817,15 +837,18 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
                 art = art._replace(acc=jax.tree.map(
                     lambda a: jnp.zeros_like(a), art.acc))
             if dcfg is not None and dcfg.worker_axes:
-                out = artemis_aggregate(dcfg, art, grads, n_workers, wid,
-                                        grad_specs)
+                with jax.named_scope("wire"):
+                    out = artemis_aggregate(dcfg, art, grads, n_workers, wid,
+                                            grad_specs)
                 (agg, art, obs) = out if telem else out + (None,)
             else:
                 agg = grads
                 art = art._replace(step=art.step + 1)
-        updates, opt_state = optimizer.update(agg, opt_state, stepno)
-        params = jax.tree.map(lambda pp, u: (pp - u.astype(pp.dtype)).astype(pp.dtype),
-                              params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(agg, opt_state, stepno)
+            params = jax.tree.map(
+                lambda pp, u: (pp - u.astype(pp.dtype)).astype(pp.dtype),
+                params, updates)
         return params, opt_state, art, loss, metrics, obs
 
     if dcfg is None or not dcfg.worker_axes:

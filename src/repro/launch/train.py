@@ -163,9 +163,13 @@ def main(argv=None):
             log.emit("note", text=f"restored step {int(state.step)}")
 
         logs = []
+        # host spans of the loop (obs.spans; summed per name at the end):
+        # train/batch (input), train/dispatch (the step call returns once
+        # the step is queued), train/loss_fetch (the read-back, where the
+        # host waits for the device)
         obs_spans.reset()
         t0 = time.perf_counter()
-        compile_s = None        # first jstep call = compile + one step
+        compiled = False        # first jstep call = compile + one step
         jlocal = jax.jit(local_fn) if local_fn else None
         # host-side divergence sentinel: last good state + geometric lr backoff
         good_state, lr_scale, rollbacks = state, 1.0, 0
@@ -176,24 +180,28 @@ def main(argv=None):
         n_steady = 0
         with contextlib.ExitStack() as steady_window:
             while i < start + args.steps:
-                batch = batch_at(i)
+                with obs_spans.span("train/batch"):
+                    batch = batch_at(i)
                 if jlocal is not None and (i + 1) % args.local_steps:
-                    state, (loss, metrics) = jlocal(state, batch)
-                elif compile_s is None:
+                    with obs_spans.span("train/dispatch"):
+                        state, (loss, metrics) = jlocal(state, batch)
+                elif not compiled:
                     # compile-vs-execute split: the first communicating step
                     # pays the trace+compile; block so the span measures it
                     with obs_spans.span("train/compile+first_step"):
                         state, (loss, metrics) = jstep(state, batch)
                         jax.block_until_ready(loss)
-                    compile_s = time.perf_counter() - t0
+                    compiled = True
                 else:
                     if not n_steady:
                         steady_window.enter_context(
                             obs_spans.span("train/steady_steps"))
                     n_steady += 1
-                    state, (loss, metrics) = jstep(state, batch)
+                    with obs_spans.span("train/dispatch"):
+                        state, (loss, metrics) = jstep(state, batch)
                 if i % args.log_every == 0 or i == start + args.steps - 1:
-                    loss_f = float(loss)
+                    with obs_spans.span("train/loss_fetch"):
+                        loss_f = float(loss)
                     bad = not np.isfinite(loss_f) or (
                         args.sentinel > 0 and loss_f > args.sentinel)
                     if bad and args.sentinel > 0:
@@ -229,12 +237,11 @@ def main(argv=None):
         if args.ckpt_dir:
             checkpointer.save(args.ckpt_dir, int(state.step), state)
     wall = time.perf_counter() - t0
-    steady = obs_spans.total("train/steady_steps")
-    if compile_s is not None:
-        log.emit("span", name="train/compile+first_step", dur_s=compile_s)
-    if n_steady:
-        log.emit("span", name="train/steady_steps", dur_s=steady,
-                 steps=n_steady)
+    # one event per span name: count and total (spans kept in memory and
+    # written once, so the loop itself writes nothing)
+    for agg in obs_spans.summarize_spans():
+        log.emit("span", name=agg["name"], dur_s=agg["total_s"],
+                 count=agg["count"])
     log.end(status="ok", wall_s=round(wall, 3))
     log.close()
     if args.log_file:

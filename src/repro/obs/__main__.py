@@ -4,14 +4,11 @@
   PYTHONPATH=src python -m repro.obs validate events.jsonl
   PYTHONPATH=src python -m repro.obs diff     a.jsonl b.jsonl
   PYTHONPATH=src python -m repro.obs dashboard events.jsonl [-o dashboard.md]
-  PYTHONPATH=src python -m repro.obs bench-append LEDGER NAME VALUE UNIT ...
-  PYTHONPATH=src python -m repro.obs bench-check  LEDGER
   PYTHONPATH=src python -m repro.obs smoke -o obs_out/   # instrumented sweep
 
 ``smoke`` is CI stage 5's entry point: it runs a small instrumented sweep
-grid (telemetry on, one faulted variant), captures a Perfetto trace, writes
-a schema-valid ``events.jsonl`` + ``dashboard.md``, and appends to the
-``BENCH_history.jsonl`` ledger.
+grid (telemetry on, one faulted variant), captures a Perfetto trace, and
+writes a schema-valid ``events.jsonl`` + ``dashboard.md``.
 """
 from __future__ import annotations
 
@@ -22,7 +19,7 @@ import time
 
 import numpy as np
 
-from repro.obs import bench, events, spans
+from repro.obs import events, spans
 from repro.obs import telemetry as T
 
 _BLOCKS = "▁▂▃▄▅▆▇█"
@@ -218,27 +215,9 @@ def cmd_dashboard(args) -> int:
     return 0
 
 
-def cmd_bench_append(args) -> int:
-    e = bench.append(args.ledger, args.name, args.value, args.unit,
-                     direction=args.direction, tol=args.tol,
-                     run_id=args.run_id)
-    print(json.dumps(e))
-    return 0
-
-
-def cmd_bench_check(args) -> int:
-    verdicts = bench.check(args.ledger, window=args.window)
-    bad = 0
-    for v in verdicts:
-        print(v.describe())
-        bad += v.status == "regression"
-    print(f"{args.ledger}: {len(verdicts)} metrics, {bad} regressions")
-    return 1 if bad else 0
-
-
 def cmd_smoke(args) -> int:
     """Instrumented end-to-end smoke: sweep grid with telemetry + a faulted
-    variant, Perfetto capture, JSONL log, dashboard, bench ledger."""
+    variant, Perfetto capture, JSONL log, dashboard."""
     import os
 
     import jax
@@ -294,26 +273,6 @@ def cmd_smoke(args) -> int:
         f.write(md)
     arts = spans.perfetto_artifacts(trace_dir)
 
-    if args.ledger:
-        run_id = evs[0]["run_id"]
-        tel = res.telemetry
-        faulted_bits = float(res.bits[2, 0, 0, -1])
-        bench.append(args.ledger, "obs_smoke.wall_s",
-                     time.perf_counter() - t_start, "s", tol=1.0,
-                     run_id=run_id)
-        bench.append(args.ledger, "obs_smoke.traces", res.traces, "compiles",
-                     tol=0.0, run_id=run_id)
-        bench.append(args.ledger, "obs_smoke.schema_errors", len(errs),
-                     "errors", tol=0.0, run_id=run_id)
-        bench.append(args.ledger, "obs_smoke.final_loss_1bit",
-                     float(res.losses[1, 0, 0, -1]), "nll", tol=0.05,
-                     run_id=run_id)
-        bench.append(args.ledger, "obs_smoke.bits_faulted", faulted_bits,
-                     "bits", tol=0.05, run_id=run_id)
-        bench.append(args.ledger, "obs_smoke.blowup_hits",
-                     float(tel["blowup_hits"][2, 0, 0, -1]), "workers",
-                     tol=0.0, run_id=run_id)
-
     print(f"events: {log_path} ({len(evs)} events, {len(errs)} schema "
           f"errors)")
     print(f"dashboard: {dash}")
@@ -349,29 +308,11 @@ def main(argv=None) -> int:
     p.add_argument("-o", "--out", default=None)
     p.set_defaults(fn=cmd_dashboard)
 
-    p = sub.add_parser("bench-append", help="append one ledger entry")
-    p.add_argument("ledger")
-    p.add_argument("name")
-    p.add_argument("value", type=float)
-    p.add_argument("unit")
-    p.add_argument("--direction", default="lower",
-                   choices=list(bench.DIRECTIONS))
-    p.add_argument("--tol", type=float, default=0.25)
-    p.add_argument("--run-id", default="")
-    p.set_defaults(fn=cmd_bench_append)
-
-    p = sub.add_parser("bench-check", help="regression-gate the ledger")
-    p.add_argument("ledger")
-    p.add_argument("--window", type=int, default=bench.WINDOW)
-    p.set_defaults(fn=cmd_bench_check)
-
     p = sub.add_parser("smoke", help="instrumented smoke sweep (CI stage 5)")
     p.add_argument("-o", "--out", default="obs_out")
     p.add_argument("--iters", type=int, default=200)
     p.add_argument("--every", type=int, default=10)
     p.add_argument("--gamma", type=float, default=0.05)
-    p.add_argument("--ledger", default=None,
-                   help="BENCH_history.jsonl to append to")
     p.add_argument("--echo", action="store_true")
     p.set_defaults(fn=cmd_smoke)
 

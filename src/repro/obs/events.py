@@ -236,8 +236,9 @@ def summarize(events: List[dict]) -> dict:
     for rec in events:
         if rec.get("ev") != "span":
             continue
+        # a summed span event (``count`` > 1) stands for that many spans
         a = spans.setdefault(rec["name"], {"count": 0, "total_s": 0.0})
-        a["count"] += 1
+        a["count"] += rec.get("count", 1)
         a["total_s"] += rec["dur_s"]
     rollbacks = sum(r["count"] for r in events if r.get("ev") == "rollback")
     return {

@@ -5,8 +5,13 @@
 name shows up (a) in the in-process span ledger this module keeps, (b) in
 the JSONL event log when a sink is installed, and (c) on the Perfetto
 timeline when a ``profile(...)`` capture is active.  The launch layer wraps
-compile vs. execute, the sweep CLI wraps lower/compile/run, and the mesh
+the train loop's compile, input, dispatch and loss read-back
+(``train/*``), the sweep CLI wraps lower/compile/run, and the mesh
 benchmarks wrap ring steps — one vocabulary everywhere.
+
+The record list is capped (the oldest half goes when it is full); per-name
+counts and totals are kept beside it, exact for any run length, and are
+what ``total`` and ``summarize_spans()`` read.
 
 These are HOST spans: they never appear inside a traced function.  For
 in-trace annotation (visible in XLA op names / the profiler's device
@@ -23,7 +28,7 @@ import contextlib
 import dataclasses
 import pathlib
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import jax
 
@@ -39,6 +44,7 @@ class SpanRecord:
 
 
 _SPANS: List[SpanRecord] = []
+_TOTALS: Dict[str, List[float]] = {}   # name -> [count, total_s, max_s]
 _DEPTH = [0]
 _SINK = [None]      # optional EventLog; list cell so tests can swap it
 
@@ -54,6 +60,7 @@ def uninstall_sink() -> None:
 
 def reset() -> None:
     _SPANS.clear()
+    _TOTALS.clear()
 
 
 def records() -> List[SpanRecord]:
@@ -62,7 +69,7 @@ def records() -> List[SpanRecord]:
 
 def total(name: str) -> float:
     """Summed duration of every closed span called ``name`` (seconds)."""
-    return sum(r.dur_s for r in _SPANS if r.name == name)
+    return _TOTALS.get(name, [0, 0.0, 0.0])[1]
 
 
 @contextlib.contextmanager
@@ -79,6 +86,7 @@ def span(name: str, **attrs):
         if len(_SPANS) >= _MAX_SPANS:      # bounded: drop oldest
             del _SPANS[: _MAX_SPANS // 2]
         _SPANS.append(SpanRecord(name, t0, dur, _DEPTH[0]))
+        _add(_TOTALS, name, dur)
         if _SINK[0] is not None:
             _SINK[0].emit("span", name=name, dur_s=dur, depth=_DEPTH[0],
                           **attrs)
@@ -103,30 +111,22 @@ def perfetto_artifacts(log_dir: str) -> List[str]:
     return [str(p) for p in hits]
 
 
-def compile_execute_split(fn, *args) -> dict:
-    """Time ``fn``'s first call (trace+compile+run) vs. a steady-state call,
-    under the spans ``obs/compile`` and ``obs/execute``.  Returns the two
-    durations; the caller reuses ``fn``'s warm executable afterwards."""
-    with span("obs/compile"):
-        out = jax.block_until_ready(fn(*args))
-    with span("obs/execute"):
-        out = jax.block_until_ready(fn(*args))
-    del out
-    return {"compile_s": _SPANS[-2].dur_s - _SPANS[-1].dur_s,
-            "first_call_s": _SPANS[-2].dur_s,
-            "execute_s": _SPANS[-1].dur_s}
+def _add(totals: Dict[str, List[float]], name: str, dur: float) -> None:
+    agg = totals.setdefault(name, [0, 0.0, 0.0])
+    agg[0] += 1
+    agg[1] += dur
+    agg[2] = max(agg[2], dur)
 
 
 def summarize_spans(recs: Optional[List[SpanRecord]] = None) -> List[dict]:
-    """Aggregate by name: count, total, mean, max (sorted by total desc)."""
-    recs = _SPANS if recs is None else recs
-    agg = {}
-    for r in recs:
-        a = agg.setdefault(r.name, {"name": r.name, "count": 0,
-                                    "total_s": 0.0, "max_s": 0.0})
-        a["count"] += 1
-        a["total_s"] += r.dur_s
-        a["max_s"] = max(a["max_s"], r.dur_s)
-    for a in agg.values():
-        a["mean_s"] = a["total_s"] / a["count"]
-    return sorted(agg.values(), key=lambda a: -a["total_s"])
+    """Aggregate by name: count, total, mean, max (sorted by total desc).
+    Without ``recs``: every span closed since ``reset``, from the exact
+    per-name totals."""
+    totals = _TOTALS
+    if recs is not None:
+        totals = {}
+        for r in recs:
+            _add(totals, r.name, r.dur_s)
+    out = [{"name": n, "count": int(c), "total_s": t, "mean_s": t / c,
+            "max_s": m} for n, (c, t, m) in totals.items()]
+    return sorted(out, key=lambda a: -a["total_s"])

@@ -12,10 +12,10 @@ Pins, in order of importance:
   3. the JSONL event log round-trips: write -> read -> validate (zero
      schema errors) -> summarize, including rollback events of a faulted
      run; the schema actually rejects malformed events;
-  4. the bench ledger gates: first entry is baseline, within-tolerance is
-     ok, beyond-tolerance is a regression (both directions);
-  5. spans ledger + sink mirroring + the mesh wire-byte reconciliation
-     (subprocess, 8 fake CPU devices — tests/helpers/bucket_scenarios.py).
+  4. spans ledger + sink mirroring, exact per-name span totals past the
+     record cap, ``train.main``'s ``train/*`` span events, and the mesh
+     wire-byte reconciliation (subprocess, 8 fake CPU devices —
+     tests/helpers/bucket_scenarios.py).
 """
 import dataclasses
 import json
@@ -24,7 +24,6 @@ import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -32,7 +31,7 @@ from repro.core import artemis as art
 from repro.core import faults
 from repro.core import federated as fed
 from repro.core import sweep as sw
-from repro.obs import bench, events, spans
+from repro.obs import events, spans
 from repro.obs import telemetry as T
 
 KEY = jax.random.PRNGKey(42)
@@ -213,41 +212,7 @@ def test_catalogue_is_closed_registry():
 
 
 # ---------------------------------------------------------------------------
-# 4. bench ledger gate
-# ---------------------------------------------------------------------------
-
-def test_bench_gate_baseline_ok_regression(tmp_path):
-    path = str(tmp_path / "ledger.jsonl")
-    bench.append(path, "wall_s", 10.0, "s", tol=0.25)
-    assert [v.status for v in bench.check(path)] == ["baseline"]
-    bench.append(path, "wall_s", 11.0, "s", tol=0.25)      # +10% < 25%
-    assert [v.status for v in bench.check(path)] == ["ok"]
-    bench.append(path, "wall_s", 14.0, "s", tol=0.25)      # +40% vs best=10
-    v, = bench.check(path)
-    assert v.status == "regression" and v.best == 10.0
-    assert [r.name for r in bench.regressions(path)] == ["wall_s"]
-
-
-def test_bench_gate_higher_direction_and_exact(tmp_path):
-    path = str(tmp_path / "ledger.jsonl")
-    bench.append(path, "tok_s", 100.0, "tok/s", direction="higher", tol=0.2)
-    bench.append(path, "tok_s", 90.0, "tok/s", direction="higher", tol=0.2)
-    assert bench.check(path)[0].status == "ok"               # -10% > -20%
-    bench.append(path, "tok_s", 70.0, "tok/s", direction="higher", tol=0.2)
-    assert bench.check(path)[0].status == "regression"
-    # tol=0 pins deterministic metrics exactly
-    bench.append(path, "schema_errors", 0.0, "count", tol=0.0)
-    bench.append(path, "schema_errors", 0.0, "count", tol=0.0)
-    assert bench.check(path, names=["schema_errors"])[0].status == "ok"
-    bench.append(path, "schema_errors", 1.0, "count", tol=0.0)
-    assert bench.check(path, names=["schema_errors"])[0].status == \
-        "regression"
-    with pytest.raises(ValueError):
-        bench.append(path, "x", 1.0, "", direction="sideways")
-
-
-# ---------------------------------------------------------------------------
-# 5. spans + sink, mesh wire telemetry (subprocess)
+# 4. spans + sink, train loop spans, mesh wire telemetry (subprocess)
 # ---------------------------------------------------------------------------
 
 def test_spans_ledger_and_sink(tmp_path):
@@ -272,13 +237,47 @@ def test_spans_ledger_and_sink(tmp_path):
     assert agg["outer"]["count"] == 1
 
 
-def test_compile_execute_split():
+def test_span_totals_exact_past_the_record_cap():
+    """The record list drops its oldest half when full; the per-name count
+    and total that ``total`` and ``summarize_spans()`` read do not."""
     spans.reset()
-    fn = jax.jit(lambda x: (x * 2.0).sum())
-    out = spans.compile_execute_split(fn, jnp.arange(128.0))
-    assert out["first_call_s"] >= out["execute_s"] > 0.0
-    assert out["compile_s"] == pytest.approx(
-        out["first_call_s"] - out["execute_s"])
+    n = spans._MAX_SPANS + 904
+    for _ in range(n):
+        with spans.span("tick"):
+            pass
+    assert len(spans.records()) < n
+    agg, = spans.summarize_spans()
+    assert agg["name"] == "tick" and agg["count"] == n
+    assert agg["total_s"] == pytest.approx(spans.total("tick"))
+    assert agg["total_s"] >= sum(r.dur_s for r in spans.records())
+    assert agg["mean_s"] == pytest.approx(agg["total_s"] / n)
+    spans.reset()
+    assert spans.summarize_spans() == [] and spans.total("tick") == 0.0
+
+
+def test_train_main_writes_loop_span_events(tmp_path, monkeypatch):
+    """``train.main`` (one CPU device, 5 steps, loss read every 2) writes
+    one ``span`` event per name, counted over the whole loop: an input
+    batch per step, a dispatch per step after the compiling first one, and
+    a loss read-back at steps 0, 2, 4."""
+    from repro.launch import train
+    # a cache directory named in the environment is JAX's to read at start:
+    # main then leaves this process's compile cache as it is
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    path = str(tmp_path / "events.jsonl")
+    train.main(["--arch", "whisper-tiny", "--reduced", "--dist", "artemis",
+                "--steps", "5", "--batch", "2", "--seq", "16",
+                "--log-every", "2", "--events", path])
+    evs = events.read_events(path)
+    assert events.validate_events(evs) == []
+    got = {e["name"]: e for e in evs if e["ev"] == "span"}
+    assert {n: got[n]["count"] for n in got} == {
+        "train/batch": 5, "train/dispatch": 4, "train/loss_fetch": 3,
+        "train/compile+first_step": 1, "train/steady_steps": 1}
+    assert all(e["dur_s"] > 0 for e in got.values())
+    s = events.summarize(evs)["spans"]
+    assert s["train/batch"] == {"count": 5,
+                                "total_s": got["train/batch"]["dur_s"]}
 
 
 def test_mesh_wire_telemetry_subprocess():
