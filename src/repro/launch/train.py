@@ -28,6 +28,7 @@ from repro.data.pipeline import ShardedBatches
 from repro.data.synthetic import TokenStream, TokenStreamConfig
 from repro.launch import mesh as M
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models import layers as L
 from repro.models.model import build_model
 from repro.obs import events as obs_events
 from repro.obs import spans as obs_spans
@@ -188,10 +189,14 @@ def main(argv=None):
                 elif not compiled:
                     # compile-vs-execute split: the first communicating step
                     # pays the trace+compile; block so the span measures it
-                    with obs_spans.span("train/compile+first_step"):
+                    with obs_spans.span("train/compile+first_step"), \
+                            L.record_attention_paths() as attn_calls:
                         state, (loss, metrics) = jstep(state, batch)
                         jax.block_until_ready(loss)
                     compiled = True
+                    # the path each distinct attention call took in the
+                    # step's trace
+                    log.emit("attn_path", sites=attn_calls)
                 else:
                     if not n_steady:
                         steady_window.enter_context(

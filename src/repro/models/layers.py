@@ -1,18 +1,21 @@
-"""Shared neural-net layers: norms, RoPE, chunked attention, MLPs.
+"""Shared neural-net layers: norms, RoPE, attention, MLPs.
 
 Everything is functional: ``init_*(key, ...) -> params`` and
-``apply(params, x, ...) -> y``.  Attention is q-chunked (scan over query
-blocks against the full K/V with masking) so 32k-token prefill never
-materializes an S x S score matrix.
+``apply(params, x, ...) -> y``.  Attention takes one of three paths
+(``attention`` says which and when): a fused Pallas flash attention on
+TPU, a scan over query chunks, or one masked block.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro.kernels import flash_attn as FA
 
 
 def _init(key, shape, scale=None, dtype=jnp.float32):
@@ -48,7 +51,7 @@ def rope(x, positions, theta=10000.0):
 
 
 # ---------------------------------------------------------------------------
-# Attention (GQA, causal, optional sliding window), q-chunked
+# Attention (GQA, causal, optional sliding window): fused, q-chunked or whole
 # ---------------------------------------------------------------------------
 
 def _mask(q_pos, k_pos, window: int, causal: bool):
@@ -66,19 +69,108 @@ def _mask(q_pos, k_pos, window: int, causal: bool):
 
 _INVALID_POS = jnp.iinfo(jnp.int32).max // 2
 
+# Shape rule of the fused path: whisper-tiny's train step on a v5e was
+# fastest with its 1500-key calls (encoder self- and cross-attention) fused
+# (chip runs in PERF.md, section 6); decode calls (one query) stay on XLA
+FUSED_MIN_SK = 1024
+FUSED_MIN_SQ = 128
+
+
+def attention_path(sq: int, sk: int, heads: int, kv_heads: int,
+                   head_dim: int, *, causal: bool, window: int = 0,
+                   softmax_dtype=jnp.float32, sharded: bool = False,
+                   backend: str) -> tuple:
+    """The path one ``attention`` call takes: ``("fused", "")`` or
+    ``("xla", reason)``, from what the call can see.
+
+    Causal calls stay on XLA (the kernel has no causal mask), and with them
+    every cached decode, which ``attn_apply`` runs causal.  ``sharded``: a
+    mesh axis that GSPMD partitions (not manual, size > 1) is in scope, and
+    a Mosaic kernel cannot be partitioned.
+    """
+    if backend != "tpu":
+        return "xla", f"backend {backend}"
+    if causal:
+        return "xla", "causal"
+    if 0 < window < max(sq, sk):
+        return "xla", "window"
+    if heads != kv_heads:
+        return "xla", "grouped kv heads"
+    if jnp.dtype(softmax_dtype) != jnp.float32:
+        return "xla", "softmax dtype"
+    if head_dim >= FA.LANES:
+        return "xla", "head dim"
+    if sharded:
+        return "xla", "partitioned mesh axis"
+    if sk > FA.MAX_SK or sq > FA.MAX_SQ:
+        return "xla", "long"
+    if sk < FUSED_MIN_SK or sq < FUSED_MIN_SQ:
+        return "xla", "short"
+    return "fused", ""
+
+
+def _partitioned_axes(mesh) -> bool:
+    return any(t != jax.sharding.AxisType.Manual and n > 1
+               for t, n in zip(mesh.axis_types, mesh.axis_sizes))
+
+
+_traced_paths: Optional[list] = None     # open in record_attention_paths
+
+
+@contextlib.contextmanager
+def record_attention_paths():
+    """Yield a list that collects each distinct ``attention`` call traced
+    inside the block: its q and k shapes, ``causal``, ``window``, and the
+    path and reason ``attention_path`` gave it."""
+    global _traced_paths
+    outer, _traced_paths = _traced_paths, []
+    try:
+        yield _traced_paths
+    finally:
+        _traced_paths = outer
+
 
 def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
               causal: bool = True, softmax_dtype=jnp.float32):
     """q: [B, Sq, H, D]; k, v: [B, Sk, KV, D]; grouped-query attention.
 
-    Scans over query chunks; each chunk attends to the full K/V with a
-    position mask — peak score memory is [B, H, q_chunk, Sk].
+    Three paths, chosen from shapes and flags (``attention_path``):
+
+    - fused (TPU, not causal, no window below the lengths, KV == H, f32
+      softmax, head dim below 128, ``FUSED_MIN_SK`` to ``MAX_SK`` keys,
+      at least ``FUSED_MIN_SQ`` queries):
+      ``kernels/flash_attn``; score tiles stay in VMEM, and no
+      [B, H, Sq, Sk] tensor reaches HBM, forward or backward;
+    - q-chunked (``Sq > q_chunk`` and ``Sq % q_chunk == 0``): a scan over
+      query chunks, each against the full K/V with a position mask; peak
+      score memory is [B, H, q_chunk, Sk] in ``softmax_dtype``;
+    - whole block (every other call, e.g. whisper's 1500 frames on the
+      CPU): one masked block; peak score memory is [B, H, Sq, Sk].
     """
     b, sq, h, d = q.shape
     kv = k.shape[2]
     assert h % kv == 0
     rep = h // kv
     scale = d ** -0.5
+
+    mesh = jax.sharding.get_abstract_mesh()
+    path, reason = attention_path(
+        sq, k.shape[1], h, kv, d, causal=causal, window=window,
+        softmax_dtype=softmax_dtype, sharded=_partitioned_axes(mesh),
+        backend=jax.default_backend())
+    if _traced_paths is not None:
+        call = {"q": list(q.shape), "k": list(k.shape), "causal": causal,
+                "window": window, "path": path, "reason": reason}
+        if call not in _traced_paths:
+            _traced_paths.append(call)
+    if path == "fused":
+        fused = functools.partial(FA.flash_attention, scale=scale)
+        if any(t != jax.sharding.AxisType.Manual for t in mesh.axis_types):
+            # size-1 auto axes: run the kernel manual over every axis, as
+            # GSPMD cannot partition a Mosaic call
+            fused = jax.shard_map(fused, in_specs=P(), out_specs=P(),
+                                  check_vma=False)
+        return fused(q, k, v)
 
     if sq <= q_chunk or sq % q_chunk != 0:
         return _attn_block(q, k, v, q_pos, k_pos, rep, scale, window, causal,

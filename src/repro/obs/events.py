@@ -36,6 +36,7 @@ SCHEMA: Dict[str, tuple] = {
     "span": ("name", "dur_s"),
     "train_step": ("step", "loss", "wall_s"),
     "wire": ("wire", "reduce_impl", "measured_bytes", "model_bytes"),
+    "attn_path": ("sites",),
     "rollback": ("step", "count"),
     "note": ("text",),
     "bench": ("name", "value", "unit"),

@@ -280,6 +280,29 @@ def test_train_main_writes_loop_span_events(tmp_path, monkeypatch):
                                 "total_s": got["train/batch"]["dur_s"]}
 
 
+def test_train_main_writes_attn_path_event(tmp_path, monkeypatch):
+    """``train.main`` writes one ``attn_path`` event with its first step:
+    each distinct attention call of whisper-tiny's traced step with its
+    shapes and path (XLA on the CPU, with the reason)."""
+    from repro.launch import train
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    path = str(tmp_path / "events.jsonl")
+    train.main(["--arch", "whisper-tiny", "--reduced", "--dist", "artemis",
+                "--steps", "1", "--batch", "2", "--seq", "16",
+                "--events", path])
+    evs = events.read_events(path)
+    assert events.validate_events(evs) == []
+    ev, = [e for e in evs if e["ev"] == "attn_path"]
+    # reduced whisper-tiny: 4 heads of 32, 8 frames; encoder self, decoder
+    # self, cross, in trace order
+    cpu = dict(window=0, path="xla", reason="backend cpu")
+    assert ev["sites"] == [
+        {"q": [2, 8, 4, 32], "k": [2, 8, 4, 32], "causal": False, **cpu},
+        {"q": [2, 16, 4, 32], "k": [2, 16, 4, 32], "causal": True, **cpu},
+        {"q": [2, 16, 4, 32], "k": [2, 8, 4, 32], "causal": False, **cpu}]
+    assert [e["ev"] for e in evs].index("attn_path") == 1  # after run_start
+
+
 def test_mesh_wire_telemetry_subprocess():
     """wire_bytes matches the codec-derived roofline model on both mesh
     wires (8 fake CPU devices; see scenario_obs_wire_telemetry)."""

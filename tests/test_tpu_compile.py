@@ -6,16 +6,26 @@ The topology is described inside a module fixture, so only the worker that
 runs these tests loads the TPU compiler, and the tests skip where it cannot
 be described.  Nothing here runs a kernel.
 """
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
+from repro import configs
+from repro.core import dist
 from repro.kernels import bucket_ring as BK
+from repro.kernels import flash_attn as FA
 from repro.kernels import fused_memory as fm
 from repro.kernels import ring_sum as rs
+from repro.launch import mesh as M
+from repro.models import layers as L
+from repro.models.model import build_model
+from repro.optim import adam
 
 # whisper-tiny (56.5 M params) on the default bucketed wire: 16 buckets of
 # 13795 rows x 256
@@ -23,7 +33,8 @@ WHISPER_TINY_BUCKETS = (16, 13795, 256)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described v5e:2x2."""
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no logs under /tmp
     try:
@@ -35,8 +46,13 @@ def one_chip():
     # persistent cache without one, so keep it out of the cache
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _compile_text(fn, one_chip, *specs):
@@ -72,3 +88,64 @@ def test_ring_sum(one_chip, shape, block):
         lambda q, s: rs.ring_sum(q, s, block=block, interpret=False),
         one_chip, (shape, jnp.int8), ((n, m, 1), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("sq,sk", [(1500, 1500),     # encoder self (1536 padded)
+                                   (448, 1500)])     # cross-attention
+def test_flash_attention_whisper_tiny_b8(one_chip, sq, sk):
+    """Forward and backward of the fused attention at whisper-tiny b8
+    shapes (8 x 6 heads of 64, 1500 frames padded to 1536): Mosaic's block
+    and VMEM checks run without a chip."""
+    def loss(q, k, v):
+        o = FA.flash_attention(q, k, v, scale=0.125)
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                         ((8, sq, 6, 64), jnp.bfloat16),
+                         ((8, sk, 6, 64), jnp.bfloat16),
+                         ((8, sk, 6, 64), jnp.bfloat16))
+    # the forward (with its row statistics) and the one backward kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_artemis_step_fused_attention_on_four_chips(v5e_2x2, monkeypatch):
+    """The bucketed Artemis step on a 4x1 mesh (one worker per chip, as
+    ``train --mesh 4x1`` and ``chip_smoke.py --four-chips`` run it) with
+    the fused attention: the kernel sits inside the worker shard_map under
+    remat, scan and grad, wrapped manual over the size-1 model axis.
+    Reduced whisper-tiny with its 1500 frames, 128 tokens per worker."""
+    monkeypatch.setattr(L.jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(configs.get_config("whisper-tiny", reduced=True),
+                              n_frames=1500)
+    mesh = Mesh(np.array(v5e_2x2).reshape(4, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    dcfg = dist.DistConfig(worker_axes=("data",), variant="artemis")
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pshard = M.params_shardings(mesh, params)
+    model.set_sharding(M.layer_constraint_fn(mesh, ("data",)),
+                       M.act_constraint_fn(mesh, ("data",)))
+    gspecs = jax.tree.map(lambda ns: M.strip_axes(ns.spec, ("data",)), pshard)
+    init_state, step = dist.make_train_step(model, adam(3e-3), dcfg, mesh,
+                                            grad_specs=gspecs)
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sh), tree, shardings)
+
+    rows = NamedSharding(mesh, P("data"))
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 128), jnp.int32,
+                                            sharding=rows),
+             "frames": jax.ShapeDtypeStruct((4, 1500, cfg.d_model),
+                                            jnp.float32, sharding=rows)}
+    with jax.set_mesh(mesh):
+        state = jax.eval_shape(init_state, placed(params, pshard))
+        state = placed(state, dist.state_shardings(mesh, state, pshard, dcfg))
+        with L.record_attention_paths() as calls:
+            lowered = jax.jit(step).lower(state, batch)
+        text = lowered.compile().as_text()
+    # encoder self (1500 x 1500), decoder self (causal), cross (128 x 1500)
+    assert [c["path"] for c in calls] == ["fused", "xla", "fused"]
+    assert calls[0]["q"] == [1, 1500, 4, 32]
+    for name in ("flash_attn_fwd", "flash_attn_bwd", "bucket_acc"):
+        assert name in text, name
