@@ -25,6 +25,7 @@ ARCHS = {
     "recurrentgemma-2b": "recurrentgemma_2b",
     "mistral-large-123b": "mistral_large_123b",
     "starcoder2-7b": "starcoder2_7b",
+    "kanana-2-30b-a3b": "kanana_2_30b_a3b",
 }
 
 

@@ -17,5 +17,5 @@ CONFIG = ModelConfig(
 
 REDUCED = dataclasses.replace(
     CONFIG, name="mixtral-reduced", n_layers=2, d_model=256, n_heads=4,
-    n_kv=2, d_ff=256, vocab=512, n_experts=4, top_k=2, moe_group=64,
+    n_kv=2, d_ff=256, vocab=512, n_experts=4, top_k=2,
     window=64, q_chunk=64, xent_chunk=64, remat=False)

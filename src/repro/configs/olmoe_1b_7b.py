@@ -14,5 +14,5 @@ CONFIG = ModelConfig(
 
 REDUCED = dataclasses.replace(
     CONFIG, name="olmoe-reduced", n_layers=2, d_model=128, n_heads=4, n_kv=4,
-    d_ff=128, vocab=512, n_experts=4, top_k=2, moe_group=64,
+    d_ff=128, vocab=512, n_experts=4, top_k=2,
     q_chunk=64, xent_chunk=64, remat=False)

@@ -51,7 +51,6 @@ from repro.core import faults as FLT
 from repro.kernels import bucket_ring as BK
 from repro.kernels import default_interpret
 from repro.launch.mesh import strip_axes
-from repro.obs import telemetry as obs_tel
 
 PyTree = Any
 
@@ -672,7 +671,6 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
     def local_fn(state: TrainState, batch):
         sspec = state_specs(dcfg, state)
         bspec = jax.tree.map(lambda _: P(waxes), batch)
-        mspec = {"nll": P(), "aux": P()}
 
         def inner(st: TrainState, bt):
             with jax.named_scope("model"):
@@ -690,7 +688,7 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
                     (loss, metrics))
 
         return jax.shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
-                             out_specs=(sspec, (P(), mspec)),
+                             out_specs=(sspec, P()),
                              axis_names=set(waxes),
                              check_vma=False)(state, batch)
 
@@ -764,6 +762,13 @@ def state_shardings(mesh: Mesh, state, pshard, dcfg: Optional[DistConfig]):
         artemis=ArtemisDistState(h=h_sh, hbar=hbar_sh, e=e_sh, acc=acc_sh,
                                  prev_active=waxes_sh, step=rep),
         step=rep)
+
+
+def reduce_metrics(metrics: dict, axes) -> dict:
+    """The model's step metrics over the workers: the mean of each, but a
+    maximum (``moe_max_load``) is the largest."""
+    return {k: (jax.lax.pmax if k == "moe_max_load" else jax.lax.pmean)(
+        m, axes) for k, m in metrics.items()}
 
 
 def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
@@ -870,11 +875,6 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
     def step_fn(state: TrainState, batch):
         sspec = state_specs(dcfg, state)
         bspec = jax.tree.map(lambda _: P(waxes), batch)
-        mspec = {"nll": P(), "aux": P()}
-        if telem:
-            # telemetry rides the metrics pytree; per-worker scalars are
-            # psum'd to fleet totals, so the out-spec is replicated too
-            mspec = {**mspec, "obs": {k: P() for k in obs_tel.MESH_METRICS}}
 
         def inner(st: TrainState, bt):
             wid = jnp.zeros((), jnp.int32)
@@ -883,7 +883,7 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
             params, opt_state, art, loss, metrics, obs = sgd_core(
                 st.params, st.opt_state, st.artemis, st.step, bt, wid)
             loss = jax.lax.pmean(loss, waxes)
-            metrics = jax.tree.map(lambda m: jax.lax.pmean(m, waxes), metrics)
+            metrics = reduce_metrics(metrics, waxes)
             if telem:
                 # totals over the worker ring (bytes moved, workers active,
                 # payloads scrubbed, blowups injected this step)
@@ -895,9 +895,10 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
 
         # check_vma=False: params/hbar stay replicated across workers by
         # construction (identically summed aggregate, shared downlink key),
-        # which vma tracking cannot see
+        # which vma tracking cannot see; the loss and every metric (the
+        # telemetry among them) leave reduced over the workers, replicated
         return jax.shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
-                             out_specs=(sspec, (P(), mspec)),
+                             out_specs=(sspec, P()),
                              axis_names=set(waxes),
                              check_vma=False)(state, batch)
 

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 LANES = 128
 # the forward holds a [Sk, BLOCK_Q] f32 score tile (4 MiB at the bounds);
@@ -192,19 +193,31 @@ def _bwd_call(qt, k, vt, ot, lse, dot_, scale):
     return dqt.astype(qt.dtype), dk, dvt
 
 
+def _manual(call):
+    """``call`` as the mesh in scope allows a Mosaic kernel: GSPMD cannot
+    partition one, so where an axis is left to it (the size-1 axes beside
+    the Artemis worker axis) the call runs manual over the mesh.  The map
+    sits inside the custom VJP and is never differentiated: the transpose
+    of a map would sum the inputs' cotangents over every axis it makes
+    manual, the workers' among them, adding up their gradients."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if all(t == jax.sharding.AxisType.Manual for t in mesh.axis_types):
+        return call
+    return jax.shard_map(call, in_specs=P(), out_specs=P(), check_vma=False)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _attend(qt, k, vt, scale):
-    return _fwd_call(qt, k, vt, scale)[0]
+    return _manual(functools.partial(_fwd_call, scale=scale))(qt, k, vt)[0]
 
 
 def _attend_fwd(qt, k, vt, scale):
-    ot, lse = _fwd_call(qt, k, vt, scale)
+    ot, lse = _manual(functools.partial(_fwd_call, scale=scale))(qt, k, vt)
     return ot, (qt, k, vt, ot, lse)
 
 
 def _attend_bwd(scale, res, dot_):
-    qt, k, vt, ot, lse = res
-    return _bwd_call(qt, k, vt, ot, lse, dot_, scale)
+    return _manual(functools.partial(_bwd_call, scale=scale))(*res, dot_)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)

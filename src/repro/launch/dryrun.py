@@ -66,9 +66,6 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, dist_variant: str,
 
     mesh = M.make_production_mesh(multi_pod=(mesh_kind == "multipod"))
     model = build_model(cfg)
-    if cfg.family == "moe":
-        from repro.models import moe as moe_mod
-        moe_mod.set_moe_sharding(True)
     params = _param_structs(model)
     pshard = M.params_shardings(mesh, params)
     t0 = time.time()

@@ -35,6 +35,13 @@ from repro.obs import spans as obs_spans
 from repro.optim import adam, sgd
 
 
+MOE_COUNTERS = ("moe_held_assignments", "moe_max_load")
+
+
+def _copy(tree):
+    return jax.tree.map(lambda a: a.copy(), tree)
+
+
 def make_mesh(spec: Optional[str] = None) -> Mesh:
     """``"DxM"`` -> a (data=D, model=M) mesh; None -> every device on data."""
     if spec is None:
@@ -155,7 +162,9 @@ def main(argv=None):
                       "seq": args.seq, "lr": args.lr},
               fingerprint=f"{args.arch}:{args.dist}:s{args.s}")
     with jax.set_mesh(mesh):
-        jstep = jax.jit(step_fn)
+        # the state is donated: the step's new state takes the old one's
+        # buffers, so one state (not one per step in flight) is live
+        jstep = jax.jit(step_fn, donate_argnums=0)
         if (args.resume and args.ckpt_dir
                 and checkpointer.latest_step(args.ckpt_dir) is not None):
             # one restore, one (re)trace: the killed run's state slots into
@@ -171,9 +180,11 @@ def main(argv=None):
         obs_spans.reset()
         t0 = time.perf_counter()
         compiled = False        # first jstep call = compile + one step
-        jlocal = jax.jit(local_fn) if local_fn else None
-        # host-side divergence sentinel: last good state + geometric lr backoff
-        good_state, lr_scale, rollbacks = state, 1.0, 0
+        jlocal = jax.jit(local_fn, donate_argnums=0) if local_fn else None
+        # host-side divergence sentinel: a copy of the last good state (the
+        # step donates the state it is given) + geometric lr backoff
+        keep = _copy if args.sentinel > 0 else (lambda st: None)
+        good_state, lr_scale, rollbacks = keep(state), 1.0, 0
         start = int(state.step)
         i = start
         # the steady window opens at the first step after the compile and
@@ -215,13 +226,13 @@ def main(argv=None):
                             raise RuntimeError(
                                 f"loss diverged {rollbacks} times; giving up")
                         lr_scale *= args.backoff
-                        state = good_state
+                        state = keep(good_state)
                         opt2 = (adam(args.lr * lr_scale)
                                 if args.optimizer == "adam"
                                 else sgd(args.lr * lr_scale))
                         _, step_fn2 = dist.make_train_step(
                             model, opt2, dcfg, mesh, grad_specs=gspecs)
-                        jstep = jax.jit(step_fn2)
+                        jstep = jax.jit(step_fn2, donate_argnums=0)
                         log.emit("rollback", step=int(state.step),
                                  count=rollbacks, lr_scale=lr_scale)
                         i = int(state.step)
@@ -230,10 +241,14 @@ def main(argv=None):
                            "nll": round(float(metrics["nll"]), 4),
                            "wall_s": round(time.perf_counter() - t0, 1),
                            "rollbacks": rollbacks}
+                    # MoE expert counters: assignments to the held experts
+                    # (over the layers) and the largest held expert's load
+                    rec.update({k: int(metrics[k]) for k in MOE_COUNTERS
+                                if k in metrics})
                     logs.append(rec)
                     log.emit("train_step", **rec)
                     assert np.isfinite(loss_f), "loss diverged"
-                    good_state = state
+                    good_state = keep(state)
                 if (args.ckpt_every and args.ckpt_dir
                         and int(state.step) % args.ckpt_every == 0):
                     checkpointer.save(args.ckpt_dir, int(state.step), state)

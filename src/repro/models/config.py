@@ -24,11 +24,25 @@ class ModelConfig:
     window: int = 4096          # sliding-window size when attn_kind == sliding
     rope_theta: float = 10000.0
     q_chunk: int = 512
-    # MoE
+    # multi-head latent attention (DeepSeek-V2/V3; on when kv_lora_rank > 0):
+    # head_dim is the per-head non-rotary q/k width, qk_rope_dim the rotary
+    # width (one rotary key shared by all heads), v_head_dim V's head width
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # MoE (dropless).  n_experts is the router's width; each MoE layer is
+    # divided over expert_shards chips and this one holds the contiguous
+    # n_experts // expert_shards experts of share expert_shard
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
-    moe_group: int = 4096
+    router: str = "softmax"     # softmax | sigmoid (+ selection bias, noaux_tc)
+    routed_scale: float = 1.0   # routed_scaling_factor on the top-k weights
+    n_shared: int = 0           # shared experts, one SwiGLU of n_shared * d_ff
+    expert_shards: int = 1
+    expert_shard: int = 0
+    aux_weight: float = 0.01    # weight of the load-balance loss
+    first_dense: int = 0        # leading dense layers (first_k_dense_replace)
+    dense_ff: int = 0           # their MLP width (0: d_ff)
     # SSM (mamba-1)
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -66,6 +80,23 @@ class ModelConfig:
         return self.d_model // max(self.n_heads, 1)
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def v_hd(self) -> int:
+        return self.v_head_dim or self.hd
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts this chip holds in each MoE layer."""
+        return self.n_experts // self.expert_shards
+
+    @property
+    def first_held(self) -> int:
+        return self.expert_shard * self.n_held
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can this arch serve long_500k decode (O(1)/O(window) state)?"""
         return self.family in ("ssm", "hybrid") or self.attn_kind == "sliding"
@@ -91,7 +122,8 @@ class ModelConfig:
             reps = -(-self.n_layers // len(self.pattern))
             return (self.pattern * reps)[: self.n_layers]
         if self.family == "moe":
-            return ("attn+moe",) * self.n_layers
+            return (("attn+mlp",) * self.first_dense
+                    + ("attn+moe",) * (self.n_layers - self.first_dense))
         return ("attn+mlp",) * self.n_layers   # dense, vlm, encdec decoder
 
     def validate(self):
@@ -99,6 +131,10 @@ class ModelConfig:
             assert self.n_heads % max(self.n_kv, 1) == 0, "GQA requires H % KV == 0"
         if self.family == "moe":
             assert self.n_experts > 0 and 0 < self.top_k <= self.n_experts
+            assert self.n_experts % self.expert_shards == 0
+            assert 0 <= self.expert_shard < self.expert_shards
+            assert self.router in ("softmax", "sigmoid")
+            assert 0 <= self.first_dense < self.n_layers
         if self.family == "ssm":
             assert self.ssm_state > 0
         if self.family == "hybrid":

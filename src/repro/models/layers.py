@@ -13,7 +13,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attn as FA
 
@@ -79,19 +78,22 @@ FUSED_MIN_SQ = 128
 def attention_path(sq: int, sk: int, heads: int, kv_heads: int,
                    head_dim: int, *, causal: bool, window: int = 0,
                    softmax_dtype=jnp.float32, sharded: bool = False,
-                   backend: str) -> tuple:
+                   backend: str, v_head_dim: Optional[int] = None) -> tuple:
     """The path one ``attention`` call takes: ``("fused", "")`` or
     ``("xla", reason)``, from what the call can see.
 
     Causal calls stay on XLA (the kernel has no causal mask), and with them
     every cached decode, which ``attn_apply`` runs causal.  ``sharded``: a
     mesh axis that GSPMD partitions (not manual, size > 1) is in scope, and
-    a Mosaic kernel cannot be partitioned.
+    a Mosaic kernel cannot be partitioned.  ``v_head_dim``: V's head dim
+    when it differs from q's (latent attention); the kernel takes one.
     """
     if backend != "tpu":
         return "xla", f"backend {backend}"
     if causal:
         return "xla", "causal"
+    if v_head_dim is not None and v_head_dim != head_dim:
+        return "xla", "v head dim"
     if 0 < window < max(sq, sk):
         return "xla", "window"
     if heads != kv_heads:
@@ -132,7 +134,8 @@ def record_attention_paths():
 
 def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
               causal: bool = True, softmax_dtype=jnp.float32):
-    """q: [B, Sq, H, D]; k, v: [B, Sk, KV, D]; grouped-query attention.
+    """q: [B, Sq, H, D]; k: [B, Sk, KV, D]; v: [B, Sk, KV, Dv] (Dv may
+    differ from D, as in latent attention); grouped-query attention.
 
     Three paths, chosen from shapes and flags (``attention_path``):
 
@@ -143,12 +146,14 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
       [B, H, Sq, Sk] tensor reaches HBM, forward or backward;
     - q-chunked (``Sq > q_chunk`` and ``Sq % q_chunk == 0``): a scan over
       query chunks, each against the full K/V with a position mask; peak
-      score memory is [B, H, q_chunk, Sk] in ``softmax_dtype``;
+      score memory is [B, H, q_chunk, Sk] in ``softmax_dtype``; under
+      autodiff a chunk keeps only its matmul outputs (the scores in q's
+      dtype) for the backward and recomputes its softmax;
     - whole block (every other call, e.g. whisper's 1500 frames on the
       CPU): one masked block; peak score memory is [B, H, Sq, Sk].
     """
     b, sq, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[-1]
     assert h % kv == 0
     rep = h // kv
     scale = d ** -0.5
@@ -157,20 +162,14 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
     path, reason = attention_path(
         sq, k.shape[1], h, kv, d, causal=causal, window=window,
         softmax_dtype=softmax_dtype, sharded=_partitioned_axes(mesh),
-        backend=jax.default_backend())
+        backend=jax.default_backend(), v_head_dim=dv)
     if _traced_paths is not None:
         call = {"q": list(q.shape), "k": list(k.shape), "causal": causal,
                 "window": window, "path": path, "reason": reason}
         if call not in _traced_paths:
             _traced_paths.append(call)
     if path == "fused":
-        fused = functools.partial(FA.flash_attention, scale=scale)
-        if any(t != jax.sharding.AxisType.Manual for t in mesh.axis_types):
-            # size-1 auto axes: run the kernel manual over every axis, as
-            # GSPMD cannot partition a Mosaic call
-            fused = jax.shard_map(fused, in_specs=P(), out_specs=P(),
-                                  check_vma=False)
-        return fused(q, k, v)
+        return FA.flash_attention(q, k, v, scale=scale)
 
     if sq <= q_chunk or sq % q_chunk != 0:
         return _attn_block(q, k, v, q_pos, k_pos, rep, scale, window, causal,
@@ -181,6 +180,8 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
     ps = q_pos.reshape(b, n_chunks, q_chunk).transpose(1, 0, 2) if q_pos.ndim == 2 \
         else q_pos.reshape(n_chunks, q_chunk)
 
+    @functools.partial(jax.checkpoint,
+                       policy=jax.checkpoint_policies.dots_saveable)
     def body(_, qc_pc):
         qc, pc = qc_pc
         o = _attn_block(qc, k, v, pc, k_pos, rep, scale, window, causal,
@@ -188,7 +189,7 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
         return None, o
 
     _, out = jax.lax.scan(body, None, (qs, ps))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, d)
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dv)
 
 
 def _attn_block(q, k, v, q_pos, k_pos, rep, scale, window, causal, softmax_dtype):
@@ -203,7 +204,7 @@ def _attn_block(q, k, v, q_pos, k_pos, rep, scale, window, causal, softmax_dtype
     scores = jnp.where(mask[:, None, None], scores, jnp.finfo(softmax_dtype).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkrqs,bskd->bqkrd", probs, v)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def init_attn(key, d_model, n_heads, n_kv, head_dim, dtype=jnp.float32):
@@ -216,12 +217,13 @@ def init_attn(key, d_model, n_heads, n_kv, head_dim, dtype=jnp.float32):
     }
 
 
-def init_attn_cache(b, cache_len, n_kv, head_dim, dtype=jnp.bfloat16):
+def init_attn_cache(b, cache_len, n_kv, head_dim, dtype=jnp.bfloat16,
+                    v_head_dim=None):
     """Ring-buffer KV cache. ``pos`` holds the absolute position stored in
     each slot (sentinel = empty); sliding-window archs use cache_len=window."""
     return {
         "k": jnp.zeros((b, cache_len, n_kv, head_dim), dtype),
-        "v": jnp.zeros((b, cache_len, n_kv, head_dim), dtype),
+        "v": jnp.zeros((b, cache_len, n_kv, v_head_dim or head_dim), dtype),
         "pos": jnp.full((cache_len,), _INVALID_POS, jnp.int32),
     }
 

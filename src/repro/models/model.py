@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as MOE
 from repro.models import rglru as RG
 from repro.models import ssm as SSM
@@ -26,11 +27,16 @@ PyTree = Any
 # per-layer init / apply
 # ---------------------------------------------------------------------------
 
-def _init_layer(cfg: ModelConfig, kind: str, key, cross: bool = False):
+def _init_layer(cfg: ModelConfig, kind: str, key, cross: bool = False,
+                d_ff: Optional[int] = None):
     ks = jax.random.split(key, 8)
     d, dt = cfg.d_model, cfg.pdtype
     p: Dict[str, Any] = {"ln1": jnp.zeros((d,), jnp.float32)}
-    if kind in ("attn+mlp", "attn+moe", "la"):
+    if kind in ("attn+mlp", "attn+moe", "la") and cfg.mla:
+        p["attn"] = MLA.init_mla(ks[0], d, cfg.n_heads, cfg.hd,
+                                 cfg.qk_rope_dim, cfg.v_hd, cfg.kv_lora_rank,
+                                 dtype=dt)
+    elif kind in ("attn+mlp", "attn+moe", "la"):
         p["attn"] = L.init_attn(ks[0], d, cfg.n_heads, cfg.n_kv, cfg.hd, dtype=dt)
     if kind == "mamba":
         p["mamba"] = SSM.init_mamba(ks[1], d, cfg.ssm_state, cfg.ssm_conv,
@@ -40,21 +46,41 @@ def _init_layer(cfg: ModelConfig, kind: str, key, cross: bool = False):
         p["rg"] = RG.init_rglru(ks[2], d, cfg.lru_width, dtype=dt)
     p["ln2"] = jnp.zeros((d,), jnp.float32)
     if kind == "attn+moe":
-        p["moe"] = MOE.init_moe(ks[3], d, cfg.d_ff, cfg.n_experts,
-                                cfg.activation, dtype=dt)
+        p["moe"] = MOE.init_moe(ks[3], d, cfg.d_ff, cfg.n_experts, cfg.n_held,
+                                cfg.activation, router=cfg.router, dtype=dt)
+        if cfg.n_shared:
+            p["shared"] = L.init_mlp(ks[6], d, cfg.n_shared * cfg.d_ff,
+                                     cfg.activation, dtype=dt)
     else:
-        p["mlp"] = L.init_mlp(ks[4], d, cfg.d_ff, cfg.activation, dtype=dt)
+        p["mlp"] = L.init_mlp(ks[4], d, d_ff or cfg.d_ff, cfg.activation,
+                              dtype=dt)
     if cross:
         p["lnx"] = jnp.zeros((d,), jnp.float32)
         p["xattn"] = L.init_attn(ks[5], d, cfg.n_heads, cfg.n_kv, cfg.hd, dtype=dt)
     return p
 
 
+def _zero_stats(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """The per-layer statistics a step's layers add up: the MoE
+    load-balance loss and, for MoE models, the expert counters."""
+    stats = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.family == "moe":
+        stats["moe_held_assignments"] = jnp.zeros((), jnp.float32)
+        stats["moe_max_load"] = jnp.zeros((), jnp.float32)
+    return stats
+
+
+def _add_stats(a, b):
+    """Sums over layers, but the largest load is a maximum."""
+    return {k: jnp.maximum(a[k], b[k]) if k == "moe_max_load" else a[k] + b[k]
+            for k in a}
+
+
 def _apply_layer(cfg: ModelConfig, kind: str, p, x, positions, *, causal=True,
                  cache=None, pos=None, cross_kv=None):
-    """Returns (x, aux_loss, new_cache)."""
+    """Returns (x, stats, new_cache); ``stats`` as ``_zero_stats``."""
     cd = cfg.cdtype
-    aux = jnp.zeros((), jnp.float32)
+    stats = _zero_stats(cfg)
     new_cache = cache
 
     def norm(g, y):
@@ -64,13 +90,21 @@ def _apply_layer(cfg: ModelConfig, kind: str, p, x, positions, *, causal=True,
         y, st = SSM.mamba_apply(_cast(p["mamba"], cd), norm(p["ln1"], x),
                                 d_state=cfg.ssm_state, chunk=cfg.scan_chunk,
                                 state=cache)
-        return x + y, aux, st
+        return x + y, stats, st
 
     if kind == "rg":
         y, st = RG.rglru_apply(_cast(p["rg"], cd), norm(p["ln1"], x),
                                chunk=cfg.scan_chunk, state=cache)
         x = x + y
         new_cache = st
+    elif cfg.mla:
+        y, new_cache = MLA.mla_apply(
+            _cast(p["attn"], cd), norm(p["ln1"], x), positions,
+            n_heads=cfg.n_heads, nope=cfg.hd, rope_dim=cfg.qk_rope_dim,
+            v_dim=cfg.v_hd, rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
+            causal=causal, q_chunk=cfg.q_chunk,
+            softmax_dtype=jnp.dtype(cfg.softmax_dtype), cache=cache, pos=pos)
+        x = x + y
     else:  # attention kinds
         window = 0
         if kind == "la":
@@ -94,15 +128,18 @@ def _apply_layer(cfg: ModelConfig, kind: str, p, x, positions, *, causal=True,
             x = x + y
 
     if "moe" in p:
-        y, aux = MOE.moe_apply(_cast(p["moe"], cd), norm(p["ln2"], x),
-                               top_k=cfg.top_k,
-                               capacity_factor=cfg.capacity_factor,
-                               activation=cfg.activation,
-                               group_size=cfg.moe_group)
+        # the router stays float32: its scores pick the experts
+        moe_p = {**_cast(p["moe"], cd), "router": p["moe"]["router"]}
+        y, moe_stats = MOE.moe_apply(
+            moe_p, norm(p["ln2"], x), top_k=cfg.top_k,
+            first_expert=cfg.first_held, router=cfg.router,
+            routed_scale=cfg.routed_scale, activation=cfg.activation,
+            shared=_cast(p["shared"], cd) if "shared" in p else None)
+        stats = _add_stats(stats, moe_stats)
         x = x + y
     elif "mlp" in p:
         x = x + L.mlp_apply(_cast(p["mlp"], cd), norm(p["ln2"], x), cfg.activation)
-    return x, aux, new_cache
+    return x, stats, new_cache
 
 
 def _remat_policy(cfg):
@@ -128,6 +165,10 @@ def _init_layer_cache(cfg: ModelConfig, kind: str, b: int, cache_len: int):
         clen = min(cache_len, cfg.local_window)
     elif cfg.attn_kind == "sliding":
         clen = min(cache_len, cfg.window)
+    if cfg.mla:     # keys and values expanded per head
+        return L.init_attn_cache(b, clen, cfg.n_heads,
+                                 cfg.hd + cfg.qk_rope_dim, dtype=cfg.cdtype,
+                                 v_head_dim=cfg.v_hd)
     return L.init_attn_cache(b, clen, cfg.n_kv, cfg.hd, dtype=cfg.cdtype)
 
 
@@ -139,7 +180,10 @@ class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg.validate()
         self.kinds = cfg.layer_kinds()
-        self.uniform = len(set(self.kinds)) == 1
+        # leading dense layers (MoE models) run unscanned before the stack
+        self.n_lead = cfg.first_dense if cfg.family == "moe" else 0
+        self.n_stack = cfg.n_layers - self.n_lead
+        self.uniform = len(set(self.kinds[self.n_lead:])) == 1
         if not self.uniform:
             period = len(cfg.pattern)
             self.n_groups = cfg.n_layers // period
@@ -171,9 +215,14 @@ class Model:
             "final_norm": jnp.zeros((cfg.d_model,), jnp.float32),
         }
         cross = cfg.family == "encdec"
+        if self.n_lead:
+            lkeys = jax.random.split(ks[7], self.n_lead)
+            params["lead"] = [
+                _init_layer(cfg, "attn+mlp", k, d_ff=cfg.dense_ff or None)
+                for k in lkeys]
         if self.uniform:
-            kind = self.kinds[0]
-            lkeys = jax.random.split(ks[2], cfg.n_layers)
+            kind = self.kinds[-1]
+            lkeys = jax.random.split(ks[2], self.n_stack)
             per = [_init_layer(cfg, kind, k, cross=cross) for k in lkeys]
             params["layers"] = jax.tree.map(lambda *a: jnp.stack(a), *per)
         else:
@@ -202,13 +251,32 @@ class Model:
 
     # -- shared stacks --------------------------------------------------------
 
+    def _run_lead(self, params, x, positions, *, cache=None, pos=None):
+        """The leading dense layers, each its own remat block."""
+        cfg = self.cfg
+        stats, new_cache = _zero_stats(cfg), []
+
+        def layer(p_l, xx, c_l):
+            if self.act_constraint is not None:
+                xx = self.act_constraint(xx)
+            return _apply_layer(cfg, "attn+mlp", p_l, xx.astype(cfg.cdtype),
+                                positions, cache=c_l, pos=pos)
+
+        if cfg.remat:
+            layer = jax.checkpoint(layer, policy=_remat_policy(cfg))
+        for i, p_l in enumerate(params["lead"]):
+            x, st, nc = layer(p_l, x, None if cache is None else cache[i])
+            stats = _add_stats(stats, st)
+            new_cache.append(nc)
+        return x, stats, new_cache
+
     def _run_uniform(self, params, x, positions, *, causal=True, cache=None,
                      pos=None, cross_kv=None):
         cfg = self.cfg
-        kind = self.kinds[0]
+        kind = self.kinds[-1]
 
         def body(carry, inp):
-            xx, aux = carry
+            xx, stats = carry
             if cache is None and cross_kv is None:
                 p_l, c_l, xkv = inp, None, None
             elif cache is None:
@@ -227,10 +295,10 @@ class Model:
             # compute dtype: anything that upcasts it to f32 doubles the
             # dominant memory-roofline term (measured on mistral-large)
             xx = xx.astype(cfg.cdtype)
-            xx, aux_l, nc = _apply_layer(cfg, kind, p_l, xx, positions,
-                                         causal=causal, cache=c_l, pos=pos,
-                                         cross_kv=xkv)
-            return (xx, aux + aux_l), nc
+            xx, st, nc = _apply_layer(cfg, kind, p_l, xx, positions,
+                                      causal=causal, cache=c_l, pos=pos,
+                                      cross_kv=xkv)
+            return (xx, _add_stats(stats, st)), nc
 
         if cfg.remat:
             body = jax.checkpoint(body, policy=_remat_policy(cfg))
@@ -241,17 +309,17 @@ class Model:
             xs = (params, cache)
         elif cross_kv is not None:
             xs = (params, cross_kv)
-        (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                            xs, unroll=min(cfg.scan_unroll,
-                                                           cfg.n_layers))
-        return x, aux, new_cache
+        (x, stats), new_cache = jax.lax.scan(body, (x, _zero_stats(cfg)), xs,
+                                             unroll=min(cfg.scan_unroll,
+                                                        self.n_stack))
+        return x, stats, new_cache
 
     def _run_pattern(self, params, x, positions, *, cache=None, pos=None):
         cfg = self.cfg
         pattern = cfg.pattern
 
         def gbody(carry, inp):
-            xx, aux = carry
+            xx, stats = carry
             ps = inp[0] if cache is not None else inp
             cs = inp[1] if cache is not None else (None,) * len(pattern)
             if self.layer_constraint is not None:
@@ -260,28 +328,28 @@ class Model:
                 xx = self.act_constraint(xx)
             ncs = []
             for kind, p_l, c_l in zip(pattern, ps, cs):
-                xx, a, nc = _apply_layer(cfg, kind, p_l, xx, positions,
-                                         cache=c_l, pos=pos)
-                aux = aux + a
+                xx, st, nc = _apply_layer(cfg, kind, p_l, xx, positions,
+                                          cache=c_l, pos=pos)
+                stats = _add_stats(stats, st)
                 ncs.append(nc)
-            return (xx, aux), tuple(ncs)
+            return (xx, stats), tuple(ncs)
 
         if cfg.remat:
             gbody = jax.checkpoint(gbody, policy=_remat_policy(cfg))
         xs = (params["groups"], cache["groups"]) if cache is not None else params["groups"]
-        (x, aux), new_gcache = jax.lax.scan(
-            gbody, (x, jnp.zeros((), jnp.float32)), xs,
+        (x, stats), new_gcache = jax.lax.scan(
+            gbody, (x, _zero_stats(cfg)), xs,
             unroll=min(cfg.scan_unroll, max(self.n_groups, 1)))
         new_tail = []
         for i, kind in enumerate(self.tail_kinds):
             c_l = cache["tail"][i] if cache is not None else None
-            x, a, nc = _apply_layer(cfg, kind, params["tail"][i], x, positions,
-                                    cache=c_l, pos=pos)
-            aux = aux + a
+            x, st, nc = _apply_layer(cfg, kind, params["tail"][i], x, positions,
+                                     cache=c_l, pos=pos)
+            stats = _add_stats(stats, st)
             new_tail.append(nc)
         new_cache = ({"groups": new_gcache, "tail": new_tail}
                      if cache is not None else None)
-        return x, aux, new_cache
+        return x, stats, new_cache
 
     def _encode(self, params, frames):
         """Audio encoder over stubbed frame embeddings [B, F, d]."""
@@ -316,7 +384,7 @@ class Model:
     # -- forward (train / prefill) -------------------------------------------
 
     def _forward(self, params, batch):
-        """Returns (hidden states [B, S_total, d], aux, text_offset)."""
+        """Returns (hidden states [B, S_total, d], stats, text_offset)."""
         cfg = self.cfg
         tok = batch["tokens"]
         # gather f32 rows locally, convert to bf16 BEFORE the model-axis
@@ -334,25 +402,30 @@ class Model:
         if cfg.family == "encdec":
             enc_out = self._encode(params, batch["frames"])
             cross_kv = self._cross_kv(params, enc_out)
+        stats = _zero_stats(cfg)
+        if self.n_lead:
+            x, stats, _ = self._run_lead(params, x, positions)
         if self.uniform:
-            x, aux, _ = self._run_uniform(params["layers"], x, positions,
-                                          cross_kv=cross_kv)
+            x, st, _ = self._run_uniform(params["layers"], x, positions,
+                                         cross_kv=cross_kv)
         else:
-            x, aux, _ = self._run_pattern(params, x, positions)
+            x, st, _ = self._run_pattern(params, x, positions)
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, aux, offset
+        return x, _add_stats(stats, st), offset
 
     def loss(self, params, batch):
-        """Next-token cross-entropy (+ MoE aux). labels: tokens shifted."""
+        """Next-token cross-entropy (+ the weighted MoE load-balance loss).
+        labels: tokens shifted.  Metrics: ``nll`` and the layers' stats
+        (``_zero_stats``)."""
         cfg = self.cfg
-        x, aux, off = self._forward(params, batch)
+        x, stats, off = self._forward(params, batch)
         tok = batch["tokens"]
         h = x[:, off:, :]                       # text region
         labels = jnp.concatenate(
             [tok[:, 1:], jnp.full((tok.shape[0], 1), -1, tok.dtype)], axis=1)
         nll = L.chunked_xent(h, _cast(params["unembed"], cfg.cdtype), labels,
                              chunk=cfg.xent_chunk)
-        return nll + 0.01 * aux, {"nll": nll, "aux": aux}
+        return nll + cfg.aux_weight * stats["aux"], {"nll": nll, **stats}
 
     def prefill_logits(self, params, batch):
         """Forward returning ONLY the last position's logits [B, V]."""
@@ -366,11 +439,16 @@ class Model:
     def init_cache(self, batch_size: int, cache_len: int):
         cfg = self.cfg
         if self.uniform:
-            kind = self.kinds[0]
+            kind = self.kinds[-1]
             one = _init_layer_cache(cfg, kind, batch_size, cache_len)
-            cache = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape).copy(), one)
-            return {"layers": cache}
+            cache = {"layers": jax.tree.map(
+                lambda a: jnp.broadcast_to(a, (self.n_stack,) + a.shape).copy(),
+                one)}
+            if self.n_lead:
+                cache["lead"] = [
+                    _init_layer_cache(cfg, "attn+mlp", batch_size, cache_len)
+                    for _ in range(self.n_lead)]
+            return cache
         groups = []
         for kind in cfg.pattern:
             one = _init_layer_cache(cfg, kind, batch_size, cache_len)
@@ -394,10 +472,14 @@ class Model:
             assert enc_out is not None
             cross_kv = self._cross_kv(params, enc_out)
         if self.uniform:
+            new_cache = {}
+            if self.n_lead:
+                x, _, new_cache["lead"] = self._run_lead(
+                    params, x, positions, cache=cache["lead"], pos=pos)
             x, _, nc = self._run_uniform(params["layers"], x, positions,
                                          cache=cache["layers"], pos=pos,
                                          cross_kv=cross_kv)
-            new_cache = {"layers": nc}
+            new_cache["layers"] = nc
         else:
             x, _, new_cache = self._run_pattern(params, x, positions,
                                                 cache=cache, pos=pos)

@@ -1,236 +1,129 @@
-"""Mixture-of-Experts layer: top-k routing with capacity, sort/scatter dispatch.
+"""Mixture-of-Experts layer: dropless, over the experts this chip holds.
 
-Dispatch never materializes a one-hot [T, E, C] tensor (impossible at
-32k x 32 shapes): token assignments are argsorted by expert id, ranked within
-their expert via a cummax segment trick, and scattered into an [E*C, d]
-buffer that feeds a grouped einsum against the stacked expert weights.
-Dropped tokens (beyond capacity) pass through the residual only — standard
-capacity-factor semantics.
+Expert parallelism is stated, not inferred: each MoE layer's ``E`` routed
+experts are divided over the chips of an expert group, and a layer is given
+the contiguous share it holds (``first_expert`` and the leading dim of its
+expert weights).  It routes every token over all ``E`` experts, as the
+router's published width says, and computes the part of the result that its
+own experts give; the other shares' parts belong to other chips and are
+neither computed nor stood in for.  With one share (``first_expert`` 0 and
+all ``E`` experts held) it is the whole layer.
 
-The routing is natively batched over groups (NOT vmapped) so each
-intermediate can carry an explicit sharding anchor: groups over 'data',
-routing feature dims replicated, experts over 'model' for the grouped
-einsum.  Without the anchors XLA's SPMD partitioner shards the
-gather/scatter index dims and CHECK-crashes under partial-manual shard_map
-(see DESIGN.md).
+Routing (per token, scores in float32):
+
+* ``softmax``: ``s = softmax(x Wr)``, top-k of ``s``;
+* ``sigmoid`` (DeepSeek-V3's ``noaux_tc``): ``s = sigmoid(x Wr)``, top-k of
+  ``s + b`` with ``b`` the selection bias (``router_bias``, not trained);
+
+then weights ``w = s_sel / sum(s_sel) * routed_scale``.
+
+Dispatch is dropless: the token-expert assignments are sorted by held
+expert (assignments to other shares last), each held expert's rows go
+through its SwiGLU as one grouped matmul (``jax.lax.ragged_dot``, group
+sizes = the held experts' loads), and the outputs are scattered back to
+their tokens with their weights.  There is no capacity and nothing is
+dropped.  A shared expert (``shared``), applied to every token, is added.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import _init
-
-# Optional sharding anchors (set by the launcher via set_moe_sharding).
-_MOE_AXES = {"enabled": False, "data": "data", "model": "model"}
+from repro.models import layers as L
 
 
-def set_moe_sharding(enabled: bool, data_axis="data", model_axis="model"):
-    _MOE_AXES.update(enabled=enabled, data=data_axis, model=model_axis)
-
-
-def _anchor(x, spec):
-    if not _MOE_AXES["enabled"]:
-        return x
-    from jax.sharding import PartitionSpec as P
-    mesh = jax.sharding.get_abstract_mesh()
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if mesh else {}
-
-    def ok(entry, dim):
-        axes = entry if isinstance(entry, tuple) else (entry,)
-        n = 1
-        for a in axes:
-            n *= sizes.get(a, 0) or 10**9
-        return dim % n == 0
-
-    clean = tuple(e if e is None or ok(e, x.shape[i]) else None
-                  for i, e in enumerate(spec))
-    return jax.lax.with_sharding_constraint(x, P(*clean))
-
-
-_D = lambda: (_MOE_AXES["data"],)   # noqa: E731
-_M = lambda: _MOE_AXES["model"]     # noqa: E731
-
-
-def init_moe(key, d_model, d_ff, n_experts, activation, dtype=jnp.float32):
+def init_moe(key, d_model, d_ff, n_experts, n_held, activation,
+             router="softmax", dtype=jnp.float32):
+    """Router over all ``n_experts``; weights of the ``n_held`` held."""
     ks = jax.random.split(key, 4)
     p = {
-        "router": _init(ks[0], (d_model, n_experts), scale=0.02, dtype=jnp.float32),
-        "w_up": _init(ks[1], (n_experts, d_model, d_ff), dtype=dtype),
-        "w_down": _init(ks[2], (n_experts, d_ff, d_model), dtype=dtype),
+        "router": L._init(ks[0], (d_model, n_experts), scale=0.02,
+                          dtype=jnp.float32),
+        "w_up": L._init(ks[1], (n_held, d_model, d_ff), dtype=dtype),
+        "w_down": L._init(ks[2], (n_held, d_ff, d_model), dtype=dtype),
     }
     if activation == "silu":
-        p["w_gate"] = _init(ks[3], (n_experts, d_model, d_ff), dtype=dtype)
+        p["w_gate"] = L._init(ks[3], (n_held, d_model, d_ff), dtype=dtype)
+    if router == "sigmoid":
+        p["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
     return p
 
 
-# ---------------------------------------------------------------------------
-# Gather-free dispatch/combine with scatter-only custom VJPs.
-#
-# XLA's SPMD partitioner CHECK-crashes when evaluating gather strategies for
-# computed indices on sharded operands inside a partial-manual shard_map —
-# including the gathers autodiff creates as scatter TRANSPOSES.  Both
-# directions are therefore written as scatters, using precomputed inverse
-# maps (index-of-slot / slots-of-token).
-# ---------------------------------------------------------------------------
-
-import functools as _ft
-
-
-@_ft.partial(jax.custom_vjp, nondiff_argnums=())
-def _dispatch(updates, slot, inv_slot):
-    """buf[g, slot[g,i]] = updates[g,i].  updates: [G,TK,d], slot: [G,TK]
-    (overflow slot = cap1-1), inv_slot: [G,cap1] (sentinel TK).  -> [G,cap1,d]
-    """
-    g, tk, d = updates.shape
-    cap1 = inv_slot.shape[1]
-    g_idx = jnp.broadcast_to(jnp.arange(g)[:, None], (g, tk))
-    return jnp.zeros((g, cap1, d), updates.dtype).at[g_idx, slot].set(updates)
+def route(p, x, *, top_k: int, router: str = "softmax",
+          routed_scale: float = 1.0):
+    """x: [T, d] -> (scores [T, E] f32, expert ids [T, K], weights [T, K])."""
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    if router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        pick = scores + jax.lax.stop_gradient(p["router_bias"])
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        pick = scores
+    _, idx = jax.lax.top_k(pick, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * routed_scale
+    return scores, idx, w
 
 
-def _dispatch_fwd(updates, slot, inv_slot):
-    return _dispatch(updates, slot, inv_slot), (slot, inv_slot, updates.shape)
+def _grouped_ffn(p, xs, sizes, activation):
+    """Each held expert's FFN over its rows of ``xs`` (sorted by expert;
+    rows past ``sum(sizes)`` give zeros).
 
+    Rows past the groups belong to no expert, and XLA's TPU kernel leaves
+    its output there unwritten, in the forward and in the backward's
+    product for the rows' cotangent.  So each product's rows past the
+    groups are zeroed on the way out, and on the way in, which zeroes the
+    cotangent that the backward sends back through them."""
+    rows = (jnp.arange(xs.shape[0]) < jnp.sum(sizes))[:, None]
 
-def _dispatch_bwd(res, ct_buf):
-    slot, inv_slot, (g, tk, d) = res
-    cap1 = inv_slot.shape[1]
-    g_idx = jnp.broadcast_to(jnp.arange(g)[:, None], (g, cap1))
-    # transpose as a SCATTER: ct_updates[inv_slot[s]] = ct_buf[s]
-    ct_up = jnp.zeros((g, tk + 1, d), ct_buf.dtype).at[g_idx, inv_slot].set(ct_buf)
-    return ct_up[:, :tk], None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@_ft.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(y, w_of_slot, tok_of_slot, slots_of_tok, t):
-    """out[g, tok_of_slot[g,s]] += y[g,s] * w_of_slot[g,s].
-
-    y: [G,cap1,d]; tok_of_slot: [G,cap1] (sentinel t); slots_of_tok: [G,T,K]
-    (sentinel cap1-1, the overflow slot).  -> [G,T,d]
-    """
-    g, cap1, d = y.shape
-    g_idx = jnp.broadcast_to(jnp.arange(g)[:, None], (g, cap1))
-    out = jnp.zeros((g, t + 1, d), y.dtype).at[g_idx, tok_of_slot].add(
-        y * w_of_slot[..., None])
-    return out[:, :t]
-
-
-def _combine_fwd(y, w_of_slot, tok_of_slot, slots_of_tok, t):
-    return (_combine(y, w_of_slot, tok_of_slot, slots_of_tok, t),
-            (y, w_of_slot, slots_of_tok))
-
-
-def _combine_bwd(t, res, ct_out):
-    y, w_of_slot, slots_of_tok = res
-    g, cap1, d = y.shape
-    tt, k = slots_of_tok.shape[1:]
-    # ct at each slot = ct_out at its token — via a SCATTER over (t, k):
-    ct_tk = jnp.broadcast_to(ct_out[:, :, None, :], (g, tt, k, d)
-                             ).reshape(g, tt * k, d)
-    flat_slots = slots_of_tok.reshape(g, tt * k)
-    g_idx = jnp.broadcast_to(jnp.arange(g)[:, None], (g, tt * k))
-    ct_slots = jnp.zeros((g, cap1, d), ct_out.dtype).at[g_idx, flat_slots].set(ct_tk)
-    # overflow slot (cap1-1) accumulates trash via collisions -> zero it
-    ct_slots = ct_slots.at[:, cap1 - 1].set(0.0)
-    ct_y = ct_slots * w_of_slot[..., None]
-    ct_w = jnp.sum(ct_slots * y, axis=-1)
-    return ct_y, ct_w, None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
-
-
-def _route_grouped(p, xg, *, top_k: int, capacity: int, activation: str):
-    """xg: [G, T, d] -> ([G, T, d], router probs [G, T, E]).
-
-    GATHER-FREE dispatch/combine (see block comment above): ranks come from a
-    one-hot exclusive cumsum (no sort), dispatch and combine are scatters
-    with scatter-only custom VJPs.
-    """
-    g, t, d = xg.shape
-    n_experts = p["router"].shape[1]
-    tk = t * top_k
-    cap1 = n_experts * capacity + 1          # +1 overflow slot
-
-    logits = xg.astype(jnp.float32) @ p["router"]              # [G,T,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, top_k)                    # [G,T,K]
-    gate = (gate / jnp.sum(gate, -1, keepdims=True)).astype(xg.dtype)
-
-    e_flat = _anchor(idx.reshape(g, tk), (_D(), None))         # [G,TK]
-    gate_flat = gate.reshape(g, tk)
-    # rank within expert via exclusive cumsum of the expert one-hot
-    onehot = (e_flat[..., None] == jnp.arange(n_experts)[None, None, :]
-              ).astype(jnp.int32)                              # [G,TK,E]
-    prior = jnp.cumsum(onehot, axis=1) - onehot
-    rank = jnp.sum(onehot * prior, axis=-1)                    # [G,TK]
-    keep = rank < capacity
-    slot = _anchor(jnp.where(keep, e_flat * capacity + rank, cap1 - 1),
-                   (_D(), None))
-    g_idx = jnp.broadcast_to(jnp.arange(g)[:, None], (g, tk))
-
-    # inverse maps (int scatters; no gradients flow through these)
-    inv_slot = jnp.full((g, cap1), tk, jnp.int32).at[g_idx, slot].set(
-        jnp.broadcast_to(jnp.arange(tk)[None, :], (g, tk)))
-    inv_slot = inv_slot.at[:, cap1 - 1].set(tk)     # overflow -> trash row
-    tok_flat = jnp.broadcast_to(jnp.arange(tk)[None, :] // top_k, (g, tk))
-    tok_of_slot = jnp.full((g, cap1), t, jnp.int32).at[g_idx, slot].set(tok_flat)
-    tok_of_slot = tok_of_slot.at[:, cap1 - 1].set(t)
-    slots_of_tok = jnp.where(keep, slot, cap1 - 1).reshape(g, t, top_k)
-
-    # dispatch: pure broadcast (x repeated K times) + scatter
-    updates = jnp.broadcast_to(xg[:, :, None, :], (g, t, top_k, d)
-                               ).reshape(g, tk, d)
-    updates = updates * keep[..., None].astype(xg.dtype)
-    buf = _dispatch(updates, slot, inv_slot)
-    eb = buf[:, :-1].reshape(g, n_experts, capacity, d)
-    eb = _anchor(eb, (_D(), _M(), None, None))                 # expert parallel
+    def mm(a, w):
+        a = jnp.where(rows, a, 0)
+        return jnp.where(rows, jax.lax.ragged_dot(a, w, sizes), 0)
 
     if activation == "silu":
-        h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", eb, p["w_gate"])) * \
-            jnp.einsum("gecd,edf->gecf", eb, p["w_up"])
+        h = jax.nn.silu(mm(xs, p["w_gate"])) * mm(xs, p["w_up"])
     elif activation == "gelu":
-        h = jax.nn.gelu(jnp.einsum("gecd,edf->gecf", eb, p["w_up"]))
+        h = jax.nn.gelu(mm(xs, p["w_up"]))
     else:
-        h = jnp.square(jax.nn.relu(jnp.einsum("gecd,edf->gecf", eb, p["w_up"])))
-    y = jnp.einsum("gecf,efd->gecd", h, p["w_down"]).reshape(g, -1, d)
-    y = _anchor(jnp.concatenate([y, jnp.zeros((g, 1, d), y.dtype)], axis=1),
-                (_D(), None, None))                            # [G,cap1,d]
-
-    # combine via inverse maps (scatter-only custom VJP); the gate weights
-    # are dispatched the same way so their gradient reaches the router
-    w_of_slot = _dispatch((gate_flat * keep.astype(xg.dtype))[..., None],
-                          slot, inv_slot)[..., 0]
-    out = _combine(y, w_of_slot, tok_of_slot, slots_of_tok, t)
-    return _anchor(out, (_D(), None, None)), probs
+        h = jnp.square(jax.nn.relu(mm(xs, p["w_up"])))
+    return mm(h, p["w_down"])
 
 
-def moe_apply(p, x, *, top_k: int, capacity_factor: float = 1.25,
-              activation: str = "silu", group_size: int = 4096):
-    """x: [B, S, d]. Routes per group of <= group_size tokens (per row when
-    S >= group_size, else over the flattened batch).
+def moe_apply(p, x, *, top_k: int, first_expert: int = 0,
+              router: str = "softmax", routed_scale: float = 1.0,
+              activation: str = "silu", shared=None):
+    """x: [B, S, d] -> (out [B, S, d], stats).
 
-    Returns (out, aux_loss) where aux_loss is the load-balance loss.
+    ``stats``: ``aux`` (the load-balance loss ``E * sum_e mean_t(p_te)^2``
+    of the normalised scores), ``moe_held_assignments`` (token-expert
+    assignments to held experts) and ``moe_max_load`` (the largest held
+    expert's load), all float32 scalars.
     """
-    b, s, d = x.shape
-    n_experts = p["router"].shape[1]
-
-    if s >= group_size and s % group_size == 0:
-        xg = x.reshape(b * (s // group_size), group_size, d)
-    else:
-        xg = x.reshape(1, b * s, d)
-    tokens_per_group = xg.shape[1]
-    capacity = max(int(tokens_per_group * top_k / n_experts * capacity_factor),
-                   top_k)
-
-    xg = _anchor(xg, (_D(), None, None))
-    out, probs = _route_grouped(p, xg, top_k=top_k, capacity=capacity,
-                                activation=activation)
-    out = out.reshape(b, s, d)
-    # load-balance aux loss (Switch-style smooth proxy)
-    me = jnp.mean(probs, axis=(0, 1))
-    aux = n_experts * jnp.sum(me * me)
-    return out, aux.astype(jnp.float32)
+    with jax.named_scope("moe"):
+        b, s, d = x.shape
+        t = b * s
+        xf = x.reshape(t, d)
+        n_held = p["w_up"].shape[0]
+        with jax.named_scope("route"):
+            scores, idx, w = route(p, xf, top_k=top_k, router=router,
+                                   routed_scale=routed_scale)
+            local = (idx - first_expert).reshape(-1)           # [T*K]
+            held = (local >= 0) & (local < n_held)
+            expert = jnp.where(held, local, n_held)
+            order = jnp.argsort(expert, stable=True)
+            tok = order // top_k                 # token of each sorted row
+            w_rows = jnp.where(held, w.reshape(-1), 0.0)[order]
+            sizes = jnp.bincount(expert, length=n_held + 1)[:n_held]
+        with jax.named_scope("experts"):
+            y = _grouped_ffn(p, xf[tok], sizes.astype(jnp.int32), activation)
+            out = jnp.zeros((t, d), y.dtype).at[tok].add(
+                y * w_rows[:, None].astype(y.dtype))
+        if shared is not None:
+            with jax.named_scope("shared"):
+                out = out + L.mlp_apply(shared, xf, activation)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        me = jnp.mean(probs, axis=0)
+        stats = {"aux": scores.shape[-1] * jnp.sum(me * me),
+                 "moe_held_assignments": jnp.sum(sizes).astype(jnp.float32),
+                 "moe_max_load": jnp.max(sizes).astype(jnp.float32)}
+        return out.reshape(b, s, d), stats

@@ -140,6 +140,7 @@ def test_attention_path_fused_for_whisper_tiny_on_tpu(sq, sk):
     ((1500, 1500), dict(causal=True), "causal"),
     ((1500, 1500), dict(softmax_dtype=jnp.bfloat16), "softmax dtype"),
     ((1500, 1500), dict(head_dim=128), "head dim"),
+    ((1500, 1500), dict(v_head_dim=32), "v head dim"),  # latent attention
     ((1500, 1500), dict(sharded=True), "partitioned mesh axis"),
     ((1500, 4096), dict(), "long"),
     ((448, 448), dict(), "short"),              # whisper's decoder length
@@ -150,7 +151,8 @@ def test_attention_path_falls_back_to_xla(shape, kw, reason):
     assert L.attention_path(*shape, **args) == ("xla", reason)
     assert L.attention_path(1500, 1500, **{**args, **dict(
         causal=False, window=0, backend="tpu", kv_heads=6, head_dim=64,
-        softmax_dtype=jnp.float32, sharded=False)}) == ("fused", "")
+        softmax_dtype=jnp.float32, sharded=False, v_head_dim=64)}) == (
+            "fused", "")
 
 
 def _decode_call(q, k, v):

@@ -18,9 +18,10 @@ from repro.models.model import build_model
 KEY = jax.random.PRNGKey(0)
 B, S = 2, 128
 
-# default-tier coverage: one dense transformer (SSM/MoE layer math is unit-
-# tested directly in test_layers.py / test_moe.py; full zoo runs via -m slow)
-FAST_ARCHS = {"starcoder2-7b"}
+# default-tier coverage: one dense transformer and the latent-attention MoE
+# (SSM/MoE layer math is unit-tested directly in test_layers.py /
+# test_moe.py / test_kanana.py; full zoo runs via -m slow)
+FAST_ARCHS = {"starcoder2-7b", "kanana-2-30b-a3b"}
 
 
 def _arch_params(archs):
@@ -99,17 +100,9 @@ def test_decode_step(arch):
                                                "recurrentgemma-2b",
                                                "mixtral-8x22b"]))
 def test_decode_matches_prefill(arch):
-    """Greedy decode step-by-step == teacher-forced forward (same tokens)."""
-    import dataclasses
-    cfg = configs.get_config(arch, reduced=True)
-    if cfg.family == "moe":
-        # capacity dropping differs between prefill/decode token grouping;
-        # use a dropless capacity factor for the consistency check
-        cfg = dataclasses.replace(cfg, capacity_factor=16.0)
-        model = build_model(cfg)
-        params = model.init(KEY)
-    else:
-        cfg, model, params = _model_and_params(arch)
+    """Greedy decode step-by-step == teacher-forced forward (same tokens);
+    MoE routing is per token and dropless, so it agrees too."""
+    cfg, model, params = _model_and_params(arch)
     T = 16
     toks = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0, cfg.vocab)
 

@@ -8,6 +8,7 @@ be described.  Nothing here runs a kernel.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -149,3 +150,9 @@ def test_artemis_step_fused_attention_on_four_chips(v5e_2x2, monkeypatch):
     assert calls[0]["q"] == [1, 1500, 4, 32]
     for name in ("flash_attn_fwd", "flash_attn_bwd", "bucket_acc"):
         assert name in text, name
+    # the model's backward sums nothing over the workers: each worker's
+    # gradient is its own until the wire (a map around the kernel that the
+    # backward transposes would psum the attention's cotangents)
+    model_sums = [l for l in text.splitlines()
+                  if re.search(r"all-reduce(-start)?\(", l) and "/model/" in l]
+    assert not model_sums, model_sums[:2]
