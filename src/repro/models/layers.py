@@ -1,9 +1,9 @@
 """Shared neural-net layers: norms, RoPE, attention, MLPs.
 
 Everything is functional: ``init_*(key, ...) -> params`` and
-``apply(params, x, ...) -> y``.  Attention takes one of three paths
+``apply(params, x, ...) -> y``.  Attention takes one of four paths
 (``attention`` says which and when): a fused Pallas flash attention on
-TPU, a scan over query chunks, or one masked block.
+TPU, causal or not, a scan over query chunks, or one masked block.
 """
 from __future__ import annotations
 
@@ -73,6 +73,12 @@ _INVALID_POS = jnp.iinfo(jnp.int32).max // 2
 # (chip runs in PERF.md, section 6); decode calls (one query) stay on XLA
 FUSED_MIN_SK = 1024
 FUSED_MIN_SQ = 128
+# causal calls: at kanana's 4,096 tokens (qk 192, v 128) the causal kernel
+# took 7.3 ms forward and backward against 27.0 ms for the q-chunked XLA
+# path on a v5e; whisper-tiny's 448-token decoder lost 8% of the b64 step
+# fused (PERF.md, section 6 and section 7 row 27); lengths in between are
+# not measured, and shorter causal calls stay on XLA
+FUSED_CAUSAL_MIN_S = 2048
 
 
 def attention_path(sq: int, sk: int, heads: int, kv_heads: int,
@@ -82,16 +88,23 @@ def attention_path(sq: int, sk: int, heads: int, kv_heads: int,
     """The path one ``attention`` call takes: ``("fused", "")`` or
     ``("xla", reason)``, from what the call can see.
 
-    Causal calls stay on XLA (the kernel has no causal mask), and with them
-    every cached decode, which ``attn_apply`` runs causal.  ``sharded``: a
-    mesh axis that GSPMD partitions (not manual, size > 1) is in scope, and
-    a Mosaic kernel cannot be partitioned.  ``v_head_dim``: V's head dim
-    when it differs from q's (latent attention); the kernel takes one.
+    A causal call is fused only as self-attention without a cache
+    (``sq == sk``; a cached decode has one query) from
+    ``FUSED_CAUSAL_MIN_S`` to ``MAX_CAUSAL_S`` tokens, with no window, one
+    K/V head per query head, an f32 softmax and no partitioned axis; every
+    other causal call stays on XLA with reason ``"causal"``.  ``sharded``:
+    a mesh axis that GSPMD partitions (not manual, size > 1) is in scope,
+    and a Mosaic kernel cannot be partitioned.  ``v_head_dim``: V's head
+    dim when it differs from q's (latent attention); only the causal
+    kernel takes one.
     """
     if backend != "tpu":
         return "xla", f"backend {backend}"
     if causal:
-        return "xla", "causal"
+        fused = (not 0 < window < sk and sq == sk and heads == kv_heads
+                 and jnp.dtype(softmax_dtype) == jnp.float32 and not sharded
+                 and FUSED_CAUSAL_MIN_S <= sk <= FA.MAX_CAUSAL_S)
+        return ("fused", "") if fused else ("xla", "causal")
     if v_head_dim is not None and v_head_dim != head_dim:
         return "xla", "v head dim"
     if 0 < window < max(sq, sk):
@@ -137,13 +150,18 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
     """q: [B, Sq, H, D]; k: [B, Sk, KV, D]; v: [B, Sk, KV, Dv] (Dv may
     differ from D, as in latent attention); grouped-query attention.
 
-    Three paths, chosen from shapes and flags (``attention_path``):
+    Four paths, chosen from shapes and flags (``attention_path``):
 
     - fused (TPU, not causal, no window below the lengths, KV == H, f32
       softmax, head dim below 128, ``FUSED_MIN_SK`` to ``MAX_SK`` keys,
-      at least ``FUSED_MIN_SQ`` queries):
-      ``kernels/flash_attn``; score tiles stay in VMEM, and no
-      [B, H, Sq, Sk] tensor reaches HBM, forward or backward;
+      at least ``FUSED_MIN_SQ`` queries): ``flash_attn.flash_attention``;
+      score tiles stay in VMEM, and no [B, H, Sq, Sk] tensor reaches HBM,
+      forward or backward;
+    - fused causal (TPU, causal self-attention without a cache or window,
+      KV == H, f32 softmax, ``FUSED_CAUSAL_MIN_S`` to ``MAX_CAUSAL_S``
+      tokens, any head dims): ``flash_attn.causal_flash_attention``, which
+      also never computes the key tiles above the diagonal; it takes the
+      positions to be ``0..S-1``, as training's are;
     - q-chunked (``Sq > q_chunk`` and ``Sq % q_chunk == 0``): a scan over
       query chunks, each against the full K/V with a position mask; peak
       score memory is [B, H, q_chunk, Sk] in ``softmax_dtype``; under
@@ -169,7 +187,8 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, q_chunk: int = 512,
         if call not in _traced_paths:
             _traced_paths.append(call)
     if path == "fused":
-        return FA.flash_attention(q, k, v, scale=scale)
+        fused = FA.causal_flash_attention if causal else FA.flash_attention
+        return fused(q, k, v, scale=scale)
 
     if sq <= q_chunk or sq % q_chunk != 0:
         return _attn_block(q, k, v, q_pos, k_pos, rep, scale, window, causal,

@@ -120,6 +120,71 @@ def test_fused_attention_matches_xla_and_f32(sq, sk, d, monkeypatch):
                                        rtol=1e-4, atol=2e-5, err_msg=name)
 
 
+def _f32_causal_attention(q, k, v):
+    """Plain f32 einsum reference for causal self-attention."""
+    d, s = q.shape[-1], q.shape[1]
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * d ** -0.5
+    sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("s,block_q,block_k,d", [
+    (384, 128, 128, 192),   # three tiles: below, on and above the diagonal
+    (300, 128, 128, 192),   # padded to 384: pad keys after every real query
+    (512, 256, 128, 192),   # the diagonal crosses two key tiles per query tile
+    (768, 128, 256, 192),   # ... and two query tiles per key tile
+    (256, 128, 128, 128)])  # qk 128 / v 128, as in a plain MHA layer
+def test_fused_causal_attention_matches_xla_and_f32(s, block_q, block_k, d,
+                                                    monkeypatch):
+    """``attention``'s causal fused branch (TPU interpret mode, the length
+    rule lowered, the tiles shrunk to several per sequence) against
+    ``_attn_block`` (causal) and an f32 einsum: forward and the gradients
+    of q, k and v.  V's head dim is 128; q's is latent attention's 192 or
+    128.  Each case has a shape of its own: the jitted kernel wrapper keeps
+    its trace, tiles included, per shape."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import flash_attn as FA
+    b, h, dv = 1, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(s + block_q), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, h, d))
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    ct = jax.random.normal(ks[3], (b, s, h, dv))
+    pos = jnp.arange(s)
+    calls, kernel = [], FA.causal_flash_attention
+
+    def spy(*a, **kw):
+        calls.append(kw)
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(L, "FUSED_CAUSAL_MIN_S", 0)
+    monkeypatch.setattr(L.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(FA, "causal_flash_attention", spy)
+    monkeypatch.setattr(FA, "BLOCK_Q", block_q)
+    monkeypatch.setattr(FA, "BLOCK_K", block_k)
+
+    def fused(q, k, v):
+        return L.attention(q, k, v, pos, pos, causal=True)
+
+    def block(q, k, v):
+        return L._attn_block(q, k, v, pos, pos, 1, d ** -0.5, 0, True,
+                             jnp.float32)
+
+    def fwd_grads(f):
+        out, vjp = jax.vjp(f, q, k, v)
+        return [out, *vjp(ct)]
+
+    with pltpu.force_tpu_interpret_mode():
+        got = fwd_grads(fused)
+    assert calls
+    for want in (fwd_grads(block), fwd_grads(_f32_causal_attention)):
+        for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-4, atol=2e-5, err_msg=name)
+
+
 # whisper-tiny: 6 heads of 64, as many K/V heads
 WT = dict(heads=6, kv_heads=6, head_dim=64)
 
@@ -153,6 +218,30 @@ def test_attention_path_falls_back_to_xla(shape, kw, reason):
         causal=False, window=0, backend="tpu", kv_heads=6, head_dim=64,
         softmax_dtype=jnp.float32, sharded=False, v_head_dim=64)}) == (
             "fused", "")
+
+
+# kanana-2-30b-a3b's latent attention: 32 heads, qk 128 + 64 rotary, v 128
+KANANA = dict(heads=32, kv_heads=32, head_dim=192, v_head_dim=128)
+
+
+@pytest.mark.parametrize("shape,kw,want", [
+    ((4096, 4096), dict(), ("fused", "")),      # kanana's training call
+    ((2048, 2048), dict(), ("fused", "")),      # FUSED_CAUSAL_MIN_S
+    ((8192, 8192), dict(), ("fused", "")),      # MAX_CAUSAL_S
+    ((448, 448), dict(), ("xla", "causal")),    # whisper's decoder length
+    ((1, 4096), dict(), ("xla", "causal")),     # a cached decode
+    ((4096, 4096), dict(kv_heads=8), ("xla", "causal")),
+    ((4096, 4096), dict(window=1024), ("xla", "causal")),
+    ((4096, 4096), dict(softmax_dtype=jnp.bfloat16), ("xla", "causal")),
+    ((4096, 4096), dict(sharded=True), ("xla", "causal")),
+    ((16384, 16384), dict(), ("xla", "causal")),
+    ((4096, 4096), dict(backend="cpu"), ("xla", "backend cpu"))])
+def test_attention_path_causal(shape, kw, want):
+    """A causal call is fused only as long self-attention without a cache,
+    window, grouped K/V heads, low-precision softmax or partitioned axis;
+    V's own head dim and qk 192 do not keep it from the causal kernel."""
+    args = {**KANANA, "causal": True, "backend": "tpu", **kw}
+    assert L.attention_path(*shape, **args) == want
 
 
 def _decode_call(q, k, v):
@@ -202,6 +291,26 @@ def test_whisper_tiny_attention_sites(monkeypatch):
     assert calls == [one((1500, 1500), False, "fused", ""),
                      one((448, 448), True, "xla", "causal"),
                      one((448, 1500), False, "fused", "")]
+
+
+def test_kanana_attention_sites(monkeypatch):
+    """Every latent attention call of kanana-2-30b-a3b's training loss, as
+    traced on TPU at 1 x 4,096 tokens (the dense layer and the scanned MoE
+    layers make one distinct call), takes the causal fused path: the
+    ``attn_path`` that ``train.main`` logs."""
+    from repro import configs
+    from repro.models.model import build_model
+    monkeypatch.setattr(L.jax, "default_backend", lambda: "tpu")
+    cfg = configs.get_config("kanana-2-30b-a3b")
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    batch = configs.input_specs(cfg,
+                                configs.InputShape("train", "train", 4096, 1))
+    with L.record_attention_paths() as calls:
+        jax.make_jaxpr(jax.grad(lambda p, b: model.loss(p, b)[0]))(
+            params, batch)
+    assert calls == [dict(q=[1, 4096, 32, 192], k=[1, 4096, 32, 192],
+                          causal=True, window=0, path="fused", reason="")]
 
 
 # -- rope -----------------------------------------------------------------------

@@ -109,6 +109,62 @@ def test_flash_attention_whisper_tiny_b8(one_chip, sq, sk):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("s,d,dv", [
+    (4096, 192, 128),                 # kanana-2-30b-a3b's latent attention
+    (FA.MAX_CAUSAL_S, 192, 128),      # the rule's longest fused call
+    (FA.MAX_CAUSAL_S, 128, 128)])     # ... with a plain MHA layer's dims
+def test_causal_flash_attention_kanana(one_chip, s, d, dv):
+    """Forward and backward of the causal kernel for 32 heads in bf16: at
+    kanana-2-30b-a3b's latent attention (1 x 4,096 tokens, qk 192, v 128)
+    and at ``MAX_CAUSAL_S`` tokens, where Mosaic's VMEM check bounds the
+    resident blocks."""
+    def loss(q, k, v):
+        o = FA.causal_flash_attention(q, k, v, scale=d ** -0.5)
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                         ((1, s, 32, d), jnp.bfloat16),
+                         ((1, s, 32, d), jnp.bfloat16),
+                         ((1, s, 32, dv), jnp.bfloat16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("flash_attn_causal_fwd", "flash_attn_causal_bwd"):
+        assert name in text, name
+
+
+def test_mla_layer_kernels_in_mla_scope(one_chip, monkeypatch):
+    """In one latent attention layer's compiled ``value_and_grad`` at
+    kanana's widths, both causal kernels carry the ``mla`` scope, plain or
+    wrapped by the transform (``jvp(mla)``): ``mla_ms.train`` reads its
+    time there."""
+    from repro.models import mla as MLA
+    monkeypatch.setattr(L.jax, "default_backend", lambda: "tpu")
+    cfg = configs.get_config("kanana-2-30b-a3b")
+    params = jax.eval_shape(lambda key: MLA.init_mla(
+        key, cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.qk_rope_dim,
+        cfg.v_head_dim, cfg.kv_lora_rank, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, 4096, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        y, _ = MLA.mla_apply(p, x, jnp.arange(4096)[None],
+                             n_heads=cfg.n_heads, nope=cfg.head_dim,
+                             rope_dim=cfg.qk_rope_dim, v_dim=cfg.v_head_dim,
+                             rope_theta=cfg.rope_theta, eps=cfg.norm_eps)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', l).group(1)
+               for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(kernels) == 2, kernels
+    in_mla = re.compile(r"(^|/)(\w+\()*mla\)*/")
+    for op_name in kernels:
+        assert in_mla.search(op_name), op_name
+
+
 def test_artemis_step_fused_attention_on_four_chips(v5e_2x2, monkeypatch):
     """The bucketed Artemis step on a 4x1 mesh (one worker per chip, as
     ``train --mesh 4x1`` and ``chip_smoke.py --four-chips`` run it) with
