@@ -12,9 +12,7 @@ Three entry points:
       - sweep engine (informational): the same toggle on the tiny-problem
         sweep grid, where rounds are a handful of flops and the relative
         overhead is intrinsically inflated.
-    The report is merged into BENCH_dist.json under a ``"fault_bench"`` key
-    (read-modify-write: the bucketed-ring suite owns the rest of that file
-    and runs first).
+    It prints its rows and writes no file.
 
   * ``python benchmarks/fault_bench.py --matrix``: the CI fault-matrix
     smoke — injects NaN blowups, huge finite blowups, and wire bit-flips
@@ -22,7 +20,7 @@ Three entry points:
     converging losses, sentinel rollbacks engaged, zero-fault identity
     bitwise).  Exits non-zero on any failed recovery.
 
-  * ``--step-child <wire> <scrub>``: internal subprocess body for the mesh
+  * ``--step-child <scrub>``: internal subprocess body for the mesh
     measurement (device count must be fixed before jax initializes).
 """
 from __future__ import annotations
@@ -41,9 +39,6 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 
 FAST = False      # set by benchmarks/run.py --fast: one cell, few iters
 
-BENCH_DIST_JSON = os.path.join(os.path.dirname(__file__), "..",
-                               "BENCH_dist.json")
-
 OVERHEAD_BUDGET_PCT = 5.0
 
 
@@ -51,7 +46,7 @@ OVERHEAD_BUDGET_PCT = 5.0
 # mesh-backend step overhead (subprocess: fake devices precede jax init)
 # ---------------------------------------------------------------------------
 
-def run_step_child(wire: str, scrub: bool):
+def run_step_child(scrub: bool):
     import jax
     from repro.core import dist, faults
     from repro.launch.mesh import make_host_mesh
@@ -64,7 +59,7 @@ def run_step_child(wire: str, scrub: bool):
     params = model.init(jax.random.PRNGKey(0))
     dcfg = dist.DistConfig(
         worker_axes=("pod",), variant="artemis", s=3, p_participation=0.7,
-        wire=wire, bucket_row=64,
+        bucket_row=64,
         faults=faults.FaultConfig(scrub=True) if scrub else None)
     init_state, step_fn = dist.make_train_step(model, sgd(0.05), dcfg, mesh)
     state = init_state(params)
@@ -85,7 +80,7 @@ def run_step_child(wire: str, scrub: bool):
     print(json.dumps({"step_us": best * 1e6}))
 
 
-def _step_us(wire: str, scrub: bool) -> float:
+def _step_us(scrub: bool) -> float:
     # fake CPU devices; pinned to the CPU so the child never competes for
     # an accelerator this process may already hold
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -93,7 +88,7 @@ def _step_us(wire: str, scrub: bool) -> float:
         4 if FAST else 8)
     src = os.path.join(_ROOT, "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.abspath(__file__), "--step-child", wire,
+    cmd = [sys.executable, os.path.abspath(__file__), "--step-child",
            "1" if scrub else "0"] + (["--fast"] if FAST else [])
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=900,
                           env=env)
@@ -147,39 +142,14 @@ def fault_overhead_suite():
     # within one pair is far more stable than any absolute best-of
     pairs = []
     for _ in range(1 if FAST else 3):
-        pairs.append((_step_us("bucketed", scrub=False),
-                      _step_us("bucketed", scrub=True)))
+        pairs.append((_step_us(scrub=False), _step_us(scrub=True)))
     pairs.sort(key=lambda p: (p[1] - p[0]) / p[0])
     base_us, scrub_us = pairs[len(pairs) // 2]
     mesh_pct = (scrub_us - base_us) / base_us * 100.0
 
     cells, iters, sw_base, sw_scrub, sw_full = _sweep_walls()
-    report = {
-        "mesh_step_us": round(base_us, 1),
-        "mesh_step_scrub_us": round(scrub_us, 1),
-        "mesh_scrub_overhead_pct": round(mesh_pct, 2),
-        "overhead_budget_pct": OVERHEAD_BUDGET_PCT,
-        "scrub_within_budget": mesh_pct < OVERHEAD_BUDGET_PCT,
-        "sweep_grid_cells": cells,
-        "sweep_iters": iters,
-        "sweep_baseline_wall_s": round(sw_base, 4),
-        "sweep_scrub_wall_s": round(sw_scrub, 4),
-        "sweep_scrub_overhead_pct": round((sw_scrub - sw_base) / sw_base * 100,
-                                          1),
-        "sweep_full_defense_wall_s": round(sw_full, 4),
-    }
-    if not FAST and os.path.exists(BENCH_DIST_JSON):
-        # bucketed_ring_suite owns this file and rewrites it wholesale;
-        # merge our key into whatever it last produced
-        with open(BENCH_DIST_JSON) as f:
-            full = json.load(f)
-        full["fault_bench"] = report
-        with open(BENCH_DIST_JSON, "w") as f:
-            json.dump(full, f, indent=2)
-            f.write("\n")
-
     return [
-        ("fault/mesh_step", base_us, "bucketed wire, defenses off"),
+        ("fault/mesh_step", base_us, "defenses off"),
         ("fault/mesh_step_scrub", scrub_us,
          f"overhead={mesh_pct:+.1f}% budget<{OVERHEAD_BUDGET_PCT:.0f}% "
          f"ok={mesh_pct < OVERHEAD_BUDGET_PCT}"),
@@ -245,7 +215,7 @@ if __name__ == "__main__":
         FAST = True
     if "--step-child" in sys.argv:
         i = sys.argv.index("--step-child")
-        run_step_child(sys.argv[i + 1], sys.argv[i + 2] == "1")
+        run_step_child(sys.argv[i + 1] == "1")
     elif "--matrix" in sys.argv:
         run_matrix()
     else:

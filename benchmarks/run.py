@@ -46,8 +46,6 @@ def main() -> None:
         log.start(config={"fast": fast, "filter": only},
                   fingerprint=f"bench:{'fast' if fast else 'full'}")
 
-    # fault_bench last: it merges into the BENCH_dist.json that dist_bench's
-    # bucketed-ring suite rewrites wholesale
     suites = paper_figs.ALL + kernel_bench.ALL + dist_bench.ALL + fault_bench.ALL
     print("name,us_per_call,derived")
     failures = 0

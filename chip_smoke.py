@@ -10,8 +10,8 @@
     ``bucket_acc`` ring kernel), and its aggregate must match the dense
     ``reduce_impl="psum"`` step on the same state and batch.
 (b) ``repro.core.sweep.run_sweep`` runs the paper grid of
-    ``BENCH_sweep.json`` (6 variants x 4 step sizes x 3 seeds, d=20, N=20,
-    200 rounds) with the fused Pallas uplink and with the dense one; the
+    ``benchmarks/dist_bench.py`` (6 variants x 4 step sizes x 3 seeds, d=20,
+    N=20, 200 rounds) with the fused Pallas uplink and with the dense one; the
     final losses must agree and the carry donation must hold.
 (c) ``--four-chips``: (a) on a 4x1 mesh, one Artemis worker per chip, the
     ring against the psum reduce of the same payloads; every chip must hold
@@ -118,10 +118,9 @@ def phase_train(mesh_spec):
     n_workers = mesh.shape["data"]
     dcfg = dist.DistConfig(worker_axes=("data",), variant="artemis")
     opt = adam(3e-3)                     # train.main's default --lr
-    model, gspecs, state, step = train.setup(cfg, mesh, dcfg, opt)
+    model, _, state, step = train.setup(cfg, mesh, dcfg, opt)
     _, step_psum = dist.make_train_step(
-        model, opt, dataclasses.replace(dcfg, reduce_impl="psum"), mesh,
-        grad_specs=gspecs)
+        model, opt, dataclasses.replace(dcfg, reduce_impl="psum"), mesh)
     batch = train.batch_source(cfg, mesh, BATCH, SEQ, "data")(0)
     with jax.set_mesh(mesh):
         t0 = time.perf_counter()

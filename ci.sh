@@ -8,9 +8,8 @@
 #      collective-permute bytes per dtype)
 #   1. fast test tier   (tier-1: pytest default set, < 2 min budget)
 #   2. slow test tier   (model-zoo smoke, XLA-compile bound)
-#   3. benchmark smoke  (one grid cell per suite; catches API rot cheaply;
-#      writes BENCH_dist.json [wire-layer fast numbers] next to
-#      BENCH_sweep.json — committed versions come from a non-fast run)
+#   3. benchmark smoke  (one grid cell per suite, printed as CSV rows; an
+#      API-rot smoke on the CPU that writes no file and gates no number)
 #   4. fault matrix     (self-healing smoke: inject NaN blowups / huge
 #      finite blowups / wire bit-flips, assert scrubbing + sentinel recover)
 #   5. observability    (instrumented sweep smoke: schema-valid JSONL event

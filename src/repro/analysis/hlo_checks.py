@@ -3,7 +3,7 @@ transfers (DESIGN.md §10).
 
 Generalizes ``launch/roofline.wire_bytes_match`` (which pins exact byte
 counts for the CI codecs) into invariants that hold for EVERY registered
-codec x wire:
+codec on the mesh wire:
 
   hlo-uncompressed-wire   error  a compressed codec's collective-permute /
                                  all-reduce traffic is f32-heavier than the
@@ -25,7 +25,7 @@ codec x wire:
                                  in a compiled module that should be
                                  device-resident end to end.
 
-The codec x wire matrix needs a multi-device mesh, so it runs in a child
+The codec matrix needs a multi-device mesh, so it runs in a child
 interpreter with 8 fake CPU devices (same pattern as trace_audit's
 bucket_ring entry); findings come back as JSON lines.
 """
@@ -164,12 +164,12 @@ def audit_sweep() -> List[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# entry: codec x wire matrix (8-device child)
+# entry: codec matrix on the mesh wire (8-device child)
 # ---------------------------------------------------------------------------
 
 def _child_mesh_wires():
-    """Child body: lower the mesh train step for every registered codec x
-    wire on a 4-worker mesh; print findings as JSON lines."""
+    """Child body: lower the mesh train step for every registered codec on
+    a 4-worker mesh; print findings as JSON lines."""
     import jax
     import numpy as np
     from repro.core import codec as wire
@@ -186,33 +186,32 @@ def _child_mesh_wires():
     batch = model.batch(jax.random.PRNGKey(1), n=16)
     codecs = [c for c in wire.available() if c != "none"]  # identity alias
     for cname in codecs:
-        for w in dist.WIRES:
-            entry = f"mesh:{cname}:{w}"
-            try:
-                dcfg = dist.DistConfig(worker_axes=("pod",),
-                                       variant="artemis", s=3, wire=w,
-                                       reduce_impl="pipelined", codec=cname)
-                init_state, step_fn = dist.make_train_step(
-                    model, sgd(0.05), dcfg, mesh)
-                state = init_state(params)
-                hlo = jax.jit(step_fn).lower(state, batch).compile().as_text()
-                lay = dcfg.layout(params)
-                wc = dcfg.wire_codec(lay.row)
-                declared = {dt: float(b) for dt, b in
-                            wc.wire_bytes((lay.rows, lay.row)).items()}
-                fs = wire_findings(
-                    roofline.collective_dtype_bytes(hlo), declared, entry,
-                    payload_f32_bytes=4.0 * n_elems)
-                fs.extend(host_transfer_findings(hlo, entry))
-            except Exception as e:
-                fs = [Finding(rule="hlo-entry-error", severity="error",
-                              path=entry, line=0,
-                              message=f"lowering failed: "
-                                      f"{type(e).__name__}: {e}")]
-            for f in fs:
-                print("HLOJSON " + json.dumps({  # repro-lint: allow=print-in-library (subprocess protocol)
-                    "rule": f.rule, "severity": f.severity, "path": f.path,
-                    "line": f.line, "message": f.message}))
+        entry = f"mesh:{cname}"
+        try:
+            dcfg = dist.DistConfig(worker_axes=("pod",),
+                                   variant="artemis", s=3,
+                                   reduce_impl="pipelined", codec=cname)
+            init_state, step_fn = dist.make_train_step(
+                model, sgd(0.05), dcfg, mesh)
+            state = init_state(params)
+            hlo = jax.jit(step_fn).lower(state, batch).compile().as_text()
+            lay = dcfg.layout(params)
+            wc = dcfg.wire_codec(lay.row)
+            declared = {dt: float(b) for dt, b in
+                        wc.wire_bytes((lay.rows, lay.row)).items()}
+            fs = wire_findings(
+                roofline.collective_dtype_bytes(hlo), declared, entry,
+                payload_f32_bytes=4.0 * n_elems)
+            fs.extend(host_transfer_findings(hlo, entry))
+        except Exception as e:
+            fs = [Finding(rule="hlo-entry-error", severity="error",
+                          path=entry, line=0,
+                          message=f"lowering failed: "
+                                  f"{type(e).__name__}: {e}")]
+        for f in fs:
+            print("HLOJSON " + json.dumps({  # repro-lint: allow=print-in-library (subprocess protocol)
+                "rule": f.rule, "severity": f.severity, "path": f.path,
+                "line": f.line, "message": f.message}))
     print("HLODONE")  # repro-lint: allow=print-in-library (subprocess protocol)
 
 

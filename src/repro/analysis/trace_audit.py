@@ -232,7 +232,7 @@ def _child_bucket_ring():
     model = ToyMLP(n_layers=2, d=32)
     params = model.init(jax.random.PRNGKey(0))
     dcfg = dist.DistConfig(worker_axes=("p", "q"), variant="artemis", s=3,
-                           wire="bucketed", reduce_impl="pipelined")
+                           reduce_impl="pipelined")
     init_state, step_fn = dist.make_train_step(model, sgd(0.05), dcfg, mesh)
     state = init_state(params)
     jstep = jax.jit(step_fn)

@@ -8,19 +8,17 @@ inside yields the per-worker gradient, un-psum'd — while the remaining
 data/model axes stay auto, letting GSPMD shard the model inside each worker
 exactly as in the uncompressed baseline.
 
-Wire layer (``wire="bucketed"``, the default — DESIGN.md §7): the gradient
-pytree is flattened into <= K equal byte-size f32 buckets
-(``core/bucketing.py``), each bucket squant-encoded into one contiguous
-``int8 levels + f32 row-scales`` payload, and the payloads move around a
-**pipelined double-buffered ring**: inside a ``lax.scan`` over the N-1 hops,
-hop j's ``ppermute`` of the stacked bucket payload is issued while hop j-1's
-payload is dequant-accumulated by ``kernels/bucket_ring.py`` — the carry
-holds the in-flight payload, so on real hardware the dequant hides under the
-wire latency and the step is bandwidth- (not latency-) bound.  The legacy
-``wire="leaf"`` path keeps the seed's one-ring-per-leaf schedule (N-1
-*sequential* hops per leaf) as the benchmark baseline.
+Wire layer (DESIGN.md §7): the gradient pytree is flattened into <= K equal
+byte-size f32 buckets (``core/bucketing.py``), each bucket encoded into one
+contiguous payload (``int8 levels + f32 row-scales`` for the native squant
+codec), and the payloads move around a **pipelined double-buffered ring**:
+inside a ``lax.scan`` over the N-1 hops, hop j's ``ppermute`` of the stacked
+bucket payload is issued while hop j-1's payload is dequant-accumulated by
+``kernels/bucket_ring.py`` — the carry holds the in-flight payload, so on
+real hardware the dequant hides under the wire latency and the step is
+bandwidth- (not latency-) bound.
 
-Wire format is real either way: the uplink moves **int8 levels + per-row f32
+The wire format is real: the uplink moves **int8 levels + per-row f32
 scales** across workers (visible in compiled HLO as s8 collective-permutes —
 ``launch/roofline.bucketed_wire_model`` predicts the bytes and
 ``tests/helpers/bucket_scenarios.py::hlo_wire_guard`` pins them in CI).  The
@@ -29,10 +27,10 @@ aggregate with an identical PRNG key (the TPU-native replacement for the
 server->worker broadcast).
 
 State per paper Algorithm 1 (PP2):
-  h    — per-worker memory h_i; bucketed: one [W, B, R, C] stack sharded
-         over the worker axes (leaf wire: per-leaf [W, ...] trees).
-  hbar — server memory \bar h; replicated (every worker updates it with the
-         same summed quantity, so it stays bitwise identical).
+  h    — per-worker memory h_i; one [W, B, R, C] stack sharded over the
+         worker axes.
+  hbar — server memory \bar h; replicated, but each worker adds the ring's
+         payloads up in its own order, so copies can differ in the last bit.
 """
 from __future__ import annotations
 
@@ -56,8 +54,7 @@ PyTree = Any
 
 VARIANTS = ("sgd", "qsgd", "diana", "biqsgd", "artemis", "dore")
 
-WIRES = ("bucketed", "leaf")
-REDUCE_IMPLS = ("pipelined", "sequential", "psum")
+REDUCE_IMPLS = ("pipelined", "psum")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,12 +70,11 @@ class DistConfig:
                                     # Local-SGD direction; 1 = every step)
     seed: int = 17
     # --- wire layer (DESIGN.md §7) ---
-    wire: str = "bucketed"          # "bucketed" flat ring | legacy "leaf" loop
     bucket_bytes: int = bucketing.DEFAULT_BUCKET_BYTES
     max_buckets: int = bucketing.DEFAULT_MAX_BUCKETS
     bucket_row: int = bucketing.DEFAULT_ROW      # per-row-scale tile C
-    reduce_impl: str = "pipelined"  # "pipelined" scan ring | "sequential"
-                                    # unrolled hops | "psum" dense reference
+    reduce_impl: str = "pipelined"  # "pipelined" scan ring | "psum" dense
+                                    # reference
     # --- wire codec (core/codec.py registry; DESIGN.md §9) ---
     codec: str = "squant"           # "squant" = the native row-scale wire
                                     # format; any registered codec works
@@ -96,8 +92,6 @@ class DistConfig:
     telemetry: bool = False
 
     def __post_init__(self):
-        if self.wire not in WIRES:
-            raise ValueError(f"wire={self.wire!r} not in {WIRES}")
         if self.reduce_impl not in REDUCE_IMPLS:
             raise ValueError(
                 f"reduce_impl={self.reduce_impl!r} not in {REDUCE_IMPLS}")
@@ -122,10 +116,6 @@ class DistConfig:
     def use_ef(self) -> bool:
         return self.error_feedback or self.variant == "dore"
 
-    @property
-    def bucketed(self) -> bool:
-        return self.wire == "bucketed"
-
     def layout(self, tree: PyTree) -> bucketing.BucketLayout:
         return bucketing.make_layout(tree, bucket_bytes=self.bucket_bytes,
                                      max_buckets=self.max_buckets,
@@ -142,38 +132,10 @@ class DistConfig:
         return wire.make_codec(name, row, **kw)
 
 
-# ---------------------------------------------------------------------------
-# distributed-friendly per-row s-quantization (sharding-transparent)
-# ---------------------------------------------------------------------------
-
-# The row-scale wire format now lives in core/codec.py ("row_squant") so the
-# kernels, the mesh wires, and the simulator share one definition; these
-# aliases keep the historical dist-level entry points.
-squant_encode = wire.row_squant_encode
-squant_decode = wire.row_squant_decode
-
-
-def _omega_row(row_len: int, s: int) -> float:
-    return wire.squant_omega(row_len, s)
-
-
-def default_alpha(params: PyTree, s: int) -> float:
-    """1 / (2 (omega_max + 1)) over leaves (Thm 1 condition)."""
-    rows = max(int(l.shape[-1]) if l.ndim else 1 for l in jax.tree.leaves(params))
-    return float(1.0 / (2.0 * (_omega_row(rows, s) + 1.0)))
-
-
-def default_alpha_bucketed(row: int, s: int) -> float:
-    """Thm 1 alpha for the bucketed wire: every row has length ``row``."""
-    return float(1.0 / (2.0 * (_omega_row(row, s) + 1.0)))
-
-
-def _codec_alpha(cfg: "DistConfig", rows) -> float:
-    """Thm 1 alpha from the wire codec's omega (max over message rows).
-    For the native squant wire this equals ``default_alpha*`` bit-for-bit
-    (same doubles through the same formula)."""
-    om = max(cfg.wire_codec(int(r)).omega for r in rows)
-    return float(1.0 / (2.0 * (om + 1.0)))
+def _codec_alpha(cfg: "DistConfig", row: int) -> float:
+    """Thm 1 alpha from the wire codec's omega for rows of length ``row``:
+    1 / (2 (omega + 1))."""
+    return float(1.0 / (2.0 * (cfg.wire_codec(row).omega + 1.0)))
 
 
 def _payload_nbytes(payload) -> float:
@@ -189,15 +151,6 @@ def _payload_nbytes(payload) -> float:
 # ---------------------------------------------------------------------------
 # bucketed ring transports (run INSIDE the worker-manual shard_map)
 # ---------------------------------------------------------------------------
-
-def bucket_encode(key: jax.Array, buckets: jax.Array, s: int):
-    """Per-bucket squant encode: [B, R, C] -> (q int8 [B,R,C], scales
-    [B,R,1] f32), one PRNG key per bucket (``bucketing.bucket_keys``).
-    Kept for benchmarks/tests; the aggregate now goes through
-    ``bucketing.encode_buckets`` with an arbitrary codec."""
-    keys = bucketing.bucket_keys(key, buckets.shape[0])
-    return jax.vmap(lambda k, x: squant_encode(k, x, s))(keys, buckets)
-
 
 def payload_decode(codec: wire.Codec, payload: wire.WirePayload) -> jax.Array:
     """Decode a bucket-stacked payload (leaves carry a leading B axis)."""
@@ -232,8 +185,8 @@ def bucket_ring_reduce(codec: wire.Codec, payload: wire.WirePayload,
     payload it currently holds — the two are data-independent inside the
     step, so the compiler overlaps the collective with the compute (comm
     hides under dequant or vice versa).  Accumulation order (own payload
-    first, then arrivals from w-1, w-2, ...) matches the sequential
-    transport bit-for-bit.
+    first, then arrivals from w-1, w-2, ...) matches
+    ``bucket_ring_reduce_sequential`` bit-for-bit.
     """
     itp = default_interpret() if interpret is None else interpret
     acc = jnp.zeros(jax.eval_shape(lambda p: payload_decode(codec, p),
@@ -258,9 +211,9 @@ def bucket_ring_reduce(codec: wire.Codec, payload: wire.WirePayload,
 def bucket_ring_reduce_sequential(codec: wire.Codec,
                                   payload: wire.WirePayload,
                                   axes: Tuple[str, ...], n: int) -> jax.Array:
-    """The pre-bucketing transport applied to the bucket payload: N-1
-    *blocking* hops with a dequant-accumulate stall between each (the
-    per-leaf ring of ``wire="leaf"``, kept as the pipelining baseline)."""
+    """The unpipelined ring: N-1 *blocking* hops with a decode-accumulate
+    between each.  No step runs it; it is the reference the pipelined ring
+    is held to bit for bit (``tests/helpers/bucket_scenarios.py``)."""
     acc = payload_decode(codec, payload)
     if n == 1:
         return acc
@@ -277,9 +230,9 @@ def bucket_ring_reduce_sequential(codec: wire.Codec,
 # ---------------------------------------------------------------------------
 
 class ArtemisDistState(NamedTuple):
-    h: PyTree        # per-worker memories; bucketed [W, B, R, C] stack
-    hbar: PyTree     # replicated server memory; bucketed [B, R, C]
-    e: PyTree        # per-worker EF buffers (Dore; zeros-scalar stub if off)
+    h: PyTree        # per-worker memories, one [W, B, R, C] stack
+    hbar: PyTree     # replicated server memory [B, R, C]
+    e: PyTree        # per-worker EF buffers (Dore; a [W] zero stub if off)
     acc: PyTree      # per-worker local grad accumulator (local_steps > 1)
     prev_active: jax.Array  # [W] last-round availability (Markov chain state)
     step: jax.Array
@@ -287,47 +240,38 @@ class ArtemisDistState(NamedTuple):
 
 def init_dist_state(cfg: Optional["DistConfig"], params: PyTree,
                     n_workers: int = 1) -> ArtemisDistState:
-    if cfg is not None and cfg.bucketed:
-        shape = cfg.layout(params).shape
+    """The wire's state in bucket space.  ``cfg=None`` (the plain
+    data-parallel baseline) carries per-leaf zero stubs instead, so its
+    checkpoints keep their tree."""
+    prev = jnp.zeros((n_workers,), jnp.float32)
+    step = jnp.zeros((), jnp.int32)
+    if cfg is None:
+        def leaf_stubs(shape):
+            return jax.tree.map(lambda p: jnp.zeros(shape, jnp.float32),
+                                params)
 
-        def full(dt):
-            return jnp.zeros((n_workers,) + shape, dt)
-
-        def stub():
-            return jnp.zeros((n_workers,), jnp.float32)
-
-        if cfg.memory:
-            mdt = jnp.dtype(cfg.memory_dtype)
-            h, hbar = full(mdt), jnp.zeros(shape, mdt)
-        else:
-            h, hbar = stub(), jnp.zeros((), jnp.float32)
-        e = full(jnp.float32) if cfg.use_ef else stub()
-        acc = full(jnp.float32) if cfg.local_steps > 1 else stub()
-        return ArtemisDistState(h=h, hbar=hbar, e=e, acc=acc,
-                                prev_active=jnp.zeros((n_workers,),
-                                                      jnp.float32),
-                                step=jnp.zeros((), jnp.int32))
+        return ArtemisDistState(h=leaf_stubs((n_workers,)),
+                                hbar=leaf_stubs(()),
+                                e=leaf_stubs((n_workers,)),
+                                acc=leaf_stubs((n_workers,)),
+                                prev_active=prev, step=step)
+    shape = cfg.layout(params).shape
 
     def full(dt):
-        return jax.tree.map(lambda p: jnp.zeros((n_workers,) + p.shape, dt),
-                            params)
+        return jnp.zeros((n_workers,) + shape, dt)
 
     def stub():
-        return jax.tree.map(lambda p: jnp.zeros((n_workers,), jnp.float32),
-                            params)
+        return jnp.zeros((n_workers,), jnp.float32)
 
-    if cfg is not None and cfg.memory:
+    if cfg.memory:
         mdt = jnp.dtype(cfg.memory_dtype)
-        h = full(mdt)
-        hbar = jax.tree.map(lambda p: jnp.zeros(p.shape, mdt), params)
+        h, hbar = full(mdt), jnp.zeros(shape, mdt)
     else:
-        h = stub()
-        hbar = jax.tree.map(lambda p: jnp.zeros((), jnp.float32), params)
-    e = full(jnp.float32) if (cfg is not None and cfg.use_ef) else stub()
-    acc = full(jnp.float32) if (cfg is not None and cfg.local_steps > 1) else stub()
-    return ArtemisDistState(h=h, hbar=hbar, e=e, acc=acc,
-                            prev_active=jnp.zeros((n_workers,), jnp.float32),
-                            step=jnp.zeros((), jnp.int32))
+        h, hbar = stub(), jnp.zeros((), jnp.float32)
+    e = full(jnp.float32) if cfg.use_ef else stub()
+    acc = full(jnp.float32) if cfg.local_steps > 1 else stub()
+    return ArtemisDistState(h=h, hbar=hbar, e=e, acc=acc, prev_active=prev,
+                            step=step)
 
 
 def _round_keys(cfg: DistConfig, step: jax.Array, wid: jax.Array,
@@ -335,11 +279,9 @@ def _round_keys(cfg: DistConfig, step: jax.Array, wid: jax.Array,
     """(uplink key — distinct per worker, downlink key — SHARED, active mask,
     availability, fault key).
 
-    Shared by the leaf and bucketed paths so switching the wire never changes
-    the participation pattern or the downlink stream.  ``prev`` is this
-    worker's last-round availability (the Markov chain state); ``part`` is
-    this round's availability BEFORE stragglers drop out — the chain evolves
-    on availability, not on who made the deadline."""
+    ``prev`` is this worker's last-round availability (the Markov chain
+    state); ``part`` is this round's availability BEFORE stragglers drop out
+    — the chain evolves on availability, not on who made the deadline."""
     fc = FLT.of(cfg.faults)
     base = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), step)
     up_key = jax.random.fold_in(base, wid + 1)
@@ -359,24 +301,22 @@ def _round_keys(cfg: DistConfig, step: jax.Array, wid: jax.Array,
     return up_key, dwn_key, active, part, flt_key
 
 
-def artemis_aggregate_bucketed(cfg: DistConfig, state: ArtemisDistState,
-                               gbuckets: jax.Array,
-                               layout: bucketing.BucketLayout,
-                               n_workers: int, wid: jax.Array):
+def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
+                      gbuckets: jax.Array, layout: bucketing.BucketLayout,
+                      n_workers: int, wid: jax.Array):
     """Bucketed per-worker grads [B, R, C] -> (descent buckets, new state).
 
     Inside shard_map, where each per-worker state leaf is the local
     [1, B, R, C] slice.  The uplink sum runs over ``cfg.reduce_impl``:
-    the pipelined scan ring (default), the sequential unrolled ring (the
-    pre-bucketing schedule — bit-identical result), or a dense
-    dequantize-then-psum (the equivalence-test reference).
+    the pipelined scan ring (default) or a dense dequantize-then-psum (the
+    equivalence-test reference).
     """
     axes = cfg.worker_axes
     n = n_workers
     fc = FLT.of(cfg.faults)
     wc = cfg.wire_codec(layout.row)
     alpha = cfg.alpha if cfg.alpha is not None else (
-        _codec_alpha(cfg, [layout.row]) if cfg.memory else 0.0)
+        _codec_alpha(cfg, layout.row) if cfg.memory else 0.0)
     p = cfg.p_participation
     mdt = jnp.dtype(cfg.memory_dtype)
 
@@ -438,9 +378,6 @@ def artemis_aggregate_bucketed(cfg: DistConfig, state: ArtemisDistState,
             # all-reduce proxy: result bytes ~ bytes sent per device on a
             # ring (the same convention launch/roofline uses)
             obs_bytes = 4.0 * float(np.prod(g32.shape))
-        elif cfg.reduce_impl == "sequential":
-            dhat_sum = bucket_ring_reduce_sequential(wc, enc, axes, n)
-            obs_bytes = (n - 1) * _payload_nbytes(enc)
         else:
             dhat_sum = bucket_ring_reduce(wc, enc, axes, n)
             obs_bytes = (n - 1) * _payload_nbytes(enc)
@@ -475,155 +412,6 @@ def artemis_aggregate_bucketed(cfg: DistConfig, state: ArtemisDistState,
            "mesh_scrubbed": obs_scrub.astype(jnp.float32),
            "mesh_blowup_hits": obs_blow}
     return ghat, new_state, obs
-
-
-def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState, grads: PyTree,
-                      n_workers: int, wid: jax.Array,
-                      grad_specs: Optional[PyTree] = None):
-    """Legacy leaf wire: per-worker grads -> (descent direction, new state).
-    One int8 ring per pytree leaf, N-1 sequential hops each.  Inside
-    shard_map, where each h leaf is the local [1, ...] slice.
-
-    grad_specs: optional tree of PartitionSpecs (auto axes only) matching
-    grads — WITHOUT it GSPMD tends to replicate the int8 payload before the
-    inter-worker all-gather, inflating collective bytes ~256x (measured; see
-    EXPERIMENTS.md §Perf iteration 1)."""
-    axes = cfg.worker_axes
-    n = n_workers
-    fc = FLT.of(cfg.faults)
-    up_key, dwn_key, active, part, flt_key = _round_keys(
-        cfg, state.step, wid, state.prev_active[0])
-    if fc.blowup_rate > 0.0:
-        blow_hit = jax.random.bernoulli(jax.random.fold_in(flt_key, 2),
-                                        fc.blowup_rate, ())
-    leaf_rows = [int(l.shape[-1]) if l.ndim else 1
-                 for l in jax.tree.leaves(grads)]
-    alpha = cfg.alpha if cfg.alpha is not None else (
-        _codec_alpha(cfg, leaf_rows) if cfg.memory else 0.0)
-
-    leaves, treedef = jax.tree.flatten(grads)
-    h_l = treedef.flatten_up_to(state.h)
-    hbar_l = treedef.flatten_up_to(state.hbar)
-    e_l = treedef.flatten_up_to(state.e)
-    spec_l = (treedef.flatten_up_to(grad_specs) if grad_specs is not None
-              else [None] * len(leaves))
-    p = cfg.p_participation
-
-    def _pin(x, spec, extra_lead=0):
-        if spec is None:
-            return x
-        full = P(*(((),) * extra_lead + tuple(spec)[:x.ndim - extra_lead]
-                   + (None,) * max(0, x.ndim - extra_lead - len(tuple(spec)))))
-        return jax.lax.with_sharding_constraint(x, full)
-
-    def _pin_rows(x, spec):
-        # scale has the last dim collapsed to 1 -> drop its sharding
-        if spec is None:
-            return x
-        t = tuple(spec)[:x.ndim]
-        t = t[:-1] + (None,) if t else t
-        return jax.lax.with_sharding_constraint(
-            x, P(*(t + (None,) * (x.ndim - len(t)))))
-
-    mdt = jnp.dtype(cfg.memory_dtype)
-    obs_blow = jnp.zeros((), jnp.float32)
-    if cfg.telemetry and fc.blowup_rate > 0.0:
-        obs_blow = blow_hit.astype(jnp.float32)
-    obs_scrub = jnp.zeros((), jnp.float32)
-    obs_bytes = 0.0
-    out_agg, out_h, out_hbar, out_e = [], [], [], []
-    for i, g in enumerate(leaves):
-        g32 = g.astype(jnp.float32)
-        act_l = active
-        if fc.blowup_rate > 0.0:
-            g32 = jnp.where(blow_hit, jnp.float32(fc.blowup_value), g32)
-        if fc.scrub:
-            # non-finite leaf => this worker sits the leaf's ring out
-            finite = jnp.all(jnp.isfinite(g32)).astype(jnp.float32)
-            act_l = act_l * finite
-            g32 = FLT.nan_to_zero(g32)
-        h = h_l[i][0].astype(jnp.float32) if cfg.memory else jnp.zeros_like(g32)
-        e_buf = e_l[i][0] if cfg.use_ef else None
-        delta = (g32 - h) * act_l
-        if cfg.use_ef:
-            delta = delta + e_buf
-        ok_l = act_l
-        if cfg.up_compress:
-            wcl = cfg.wire_codec(int(g.shape[-1]) if g.ndim else 1)
-            p_l = wcl.encode(jax.random.fold_in(up_key, i), delta)
-            # PP2: an inactive worker's payload (its EF buffer under Dore)
-            # must contribute EXACTLY zero to the ring sum — zero the wire
-            # float leaves (the scales for squant).
-            p_l = FLT.mask_payload(p_l, act_l)
-            if fc.bitflip_rate > 0.0:
-                p_l = FLT.corrupt_payload(jax.random.fold_in(flt_key, 10 + i),
-                                          p_l, fc.bitflip_rate, only=act_l)
-            if fc.scrub:
-                # per-LEAF checksum -> dropped via the zero-scale path
-                valid = wcl.validate(p_l)
-                ok_l = act_l * valid
-                p_l = FLT.scrub_payload(p_l, valid)
-                if cfg.telemetry:
-                    obs_scrub = obs_scrub + (1.0 - valid)
-            if "levels" in p_l.data:
-                # levels keep the leaf's auto-axis sharding; scales have the
-                # last dim collapsed (other codecs ship 1-D index/value
-                # payloads the leaf specs don't apply to)
-                p_l = p_l.replace(levels=_pin(p_l["levels"], spec_l[i]),
-                                  scales=_pin_rows(p_l["scales"], spec_l[i]))
-            # ---- the actual wire: an int8 ring. all_gather over a manual
-            # axis forces replication of the auto-sharded dims (measured
-            # 256x byte blowup); collective-permute keeps each hop at
-            # exactly one int8 shard, so the ring is N-1 shard-sized hops.
-            perm = [(j, (j + 1) % n) for j in range(n)]
-            dhat_sum = wcl.decode(p_l)
-            pr = p_l
-            for _ in range(n - 1):
-                pr = jax.tree.map(lambda l: jax.lax.ppermute(l, axes, perm),
-                                  pr)
-                dhat_sum = dhat_sum + wcl.decode(pr)
-            dhat_sum = _pin(dhat_sum, spec_l[i])
-            dhat_i = wcl.decode(p_l)
-            obs_bytes += (n - 1) * _payload_nbytes(p_l)
-        else:
-            dhat_i = delta * act_l
-            dhat_sum = jax.lax.psum(dhat_i, axes)
-            obs_bytes += 4.0 * float(np.prod(g.shape) if g.ndim else 1)
-        if cfg.use_ef:
-            # EF accumulates what compression lost (Dore-style)
-            out_e.append((ok_l * (delta - dhat_i)
-                          + (1 - ok_l) * e_buf)[None])
-        else:
-            out_e.append(e_l[i])
-        if cfg.memory:
-            hbar = hbar_l[i].astype(jnp.float32)
-            ghat = hbar + dhat_sum / (p * n)
-            out_h.append((h + alpha * dhat_i).astype(mdt)[None])
-            out_hbar.append((hbar + alpha * dhat_sum / n).astype(mdt))
-        else:
-            ghat = dhat_sum / (p * n)
-            out_h.append(h_l[i])
-            out_hbar.append(hbar_l[i])
-        if cfg.dwn_compress:
-            # zero-byte broadcast: identical key -> identical compression
-            wcd = cfg.wire_codec(int(g.shape[-1]) if g.ndim else 1)
-            ghat = wcd.decode(wcd.encode(jax.random.fold_in(dwn_key, i),
-                                         ghat))
-        out_agg.append(ghat.astype(g.dtype))
-
-    agg = jax.tree.unflatten(treedef, out_agg)
-    new_state = ArtemisDistState(jax.tree.unflatten(treedef, out_h),
-                                 jax.tree.unflatten(treedef, out_hbar),
-                                 jax.tree.unflatten(treedef, out_e),
-                                 state.acc, jnp.reshape(part, (1,)),
-                                 state.step + 1)
-    if not cfg.telemetry:
-        return agg, new_state
-    obs = {"wire_bytes": jnp.float32(obs_bytes),
-           "mesh_active": jnp.reshape(active, ()).astype(jnp.float32),
-           "mesh_scrubbed": obs_scrub.astype(jnp.float32),
-           "mesh_blowup_hits": obs_blow}
-    return agg, new_state, obs
 
 
 # ---------------------------------------------------------------------------
@@ -663,8 +451,8 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
     direction, realized as gradient accumulation so params stay replicated):
     run this k-1 times between make_train_step's communicating step. ZERO
     inter-worker collectives in its HLO — the roofline-visible comm saving.
-    (Bucketed wire: the accumulator lives in bucket space, so the
-    communicating step folds it in without re-flattening.)
+    The accumulator lives in bucket space, so the communicating step folds
+    it in without re-flattening.
     """
     waxes = dcfg.worker_axes
 
@@ -677,13 +465,8 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
                 (loss, metrics), grads = jax.value_and_grad(
                     model.loss, has_aux=True)(st.params, bt)
             with jax.named_scope("wire"), jax.named_scope("bucketize"):
-                if dcfg.bucketed:
-                    gb = bucketing.bucketize(dcfg.layout(grads), grads)
-                    acc = st.artemis.acc + gb[None]
-                else:
-                    acc = jax.tree.map(
-                        lambda a, g: a + g.astype(a.dtype)[None],
-                        st.artemis.acc, grads)
+                gb = bucketing.bucketize(dcfg.layout(grads), grads)
+                acc = st.artemis.acc + gb[None]
             return (st._replace(artemis=st.artemis._replace(acc=acc)),
                     (loss, metrics))
 
@@ -696,8 +479,10 @@ def make_local_step(model, dcfg: DistConfig, mesh: Mesh):
 
 
 def state_shardings(mesh: Mesh, state, pshard, dcfg: Optional[DistConfig]):
-    """Shardings for TrainState: params per policy; h gets a leading worker
-    dim over worker_axes; hbar like params; opt_state like params.
+    """Shardings for TrainState: params per policy; h, e and acc ([W, B, R, C]
+    or a [W] stub) carry a leading worker dim sharded over the worker axes;
+    hbar ([B, R, C]) and opt_state are replicated (every worker applies the
+    identical summed update).  ``dcfg=None`` replicates every leaf.
 
     These are exactly the shardings ``make_train_step``'s step emits, so a
     state placed on them round-trips through the step without a re-layout —
@@ -705,62 +490,27 @@ def state_shardings(mesh: Mesh, state, pshard, dcfg: Optional[DistConfig]):
     initial state costs (the jaxpr is cached but the executable is keyed on
     arg shardings; the trace audit pins this to one compile).
 
-    Bucketed wire: the artemis leaves are single stacked arrays, not
-    per-param trees — h/e/acc carry a leading worker dim ([W, B, R, C] or a
-    [W] stub) sharded over the worker axes, hbar ([B, R, C]) is replicated
-    (every worker applies the identical summed update).
-
     Params (the policy's ``pshard``) lose the worker axes: the step takes
     and returns them replicated over the workers, and an FSDP-style split
     over a worker axis would make the second step compile again."""
     rep = NamedSharding(mesh, P())
-    if dcfg is not None and dcfg.worker_axes:
+    waxes = dcfg.worker_axes if dcfg is not None else ()
+    wsh = NamedSharding(mesh, P(waxes)) if waxes else rep
+    if waxes:
         pshard = jax.tree.map(
-            lambda ns: NamedSharding(mesh, strip_axes(ns.spec,
-                                                      dcfg.worker_axes)),
+            lambda ns: NamedSharding(mesh, strip_axes(ns.spec, waxes)),
             pshard)
-    if dcfg is not None and dcfg.bucketed:
-        waxes = dcfg.worker_axes
-        wsh = NamedSharding(mesh, P(waxes))
-        opt_sh = jax.tree.map(lambda l: rep, state.opt_state) \
-            if state.opt_state != () else ()
-        return TrainState(
-            params=pshard, opt_state=opt_sh,
-            artemis=ArtemisDistState(
-                h=jax.tree.map(lambda _: wsh, state.artemis.h),
-                hbar=jax.tree.map(lambda _: rep, state.artemis.hbar),
-                e=jax.tree.map(lambda _: wsh, state.artemis.e),
-                acc=jax.tree.map(lambda _: wsh, state.artemis.acc),
-                prev_active=wsh,
-                step=rep),
-            step=rep)
-
-    def shift(ns):
-        spec = ns.spec
-        waxes = dcfg.worker_axes if dcfg else ()
-        return NamedSharding(mesh, P(waxes, *spec))
-
-    def worker_tree(struct_tree, full: bool):
-        if full:
-            return jax.tree.map(shift, pshard)
-        return jax.tree.map(lambda _: rep, struct_tree)
-
-    if dcfg is not None and dcfg.memory:
-        h_sh = worker_tree(state.artemis.h, True)
-        hbar_sh = jax.tree.map(lambda ns: ns, pshard)
-    else:
-        h_sh = worker_tree(state.artemis.h, False)
-        hbar_sh = jax.tree.map(lambda _: rep, state.artemis.hbar)
-    e_sh = worker_tree(state.artemis.e, dcfg is not None and dcfg.use_ef)
-    acc_sh = worker_tree(state.artemis.acc,
-                         dcfg is not None and dcfg.local_steps > 1)
     opt_sh = jax.tree.map(lambda l: rep, state.opt_state) \
         if state.opt_state != () else ()
-    waxes_sh = NamedSharding(mesh, P(dcfg.worker_axes if dcfg else ()))
     return TrainState(
         params=pshard, opt_state=opt_sh,
-        artemis=ArtemisDistState(h=h_sh, hbar=hbar_sh, e=e_sh, acc=acc_sh,
-                                 prev_active=waxes_sh, step=rep),
+        artemis=ArtemisDistState(
+            h=jax.tree.map(lambda _: wsh, state.artemis.h),
+            hbar=jax.tree.map(lambda _: rep, state.artemis.hbar),
+            e=jax.tree.map(lambda _: wsh, state.artemis.e),
+            acc=jax.tree.map(lambda _: wsh, state.artemis.acc),
+            prev_active=wsh,
+            step=rep),
         step=rep)
 
 
@@ -777,11 +527,12 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
 
     dcfg=None   -> plain data-parallel baseline (jit only; XLA aggregates).
     dcfg given  -> Artemis over dcfg.worker_axes via partial-manual shard_map
-                   (bucketed flat-ring wire by default; dcfg.wire="leaf" for
-                   the legacy per-leaf rings).
-    grad_specs  -> PartitionSpec tree (auto axes only) pinning the compressed
-                   payload sharding inside the leaf-wire aggregation
-                   (strongly recommended at scale; see artemis_aggregate).
+                   and the bucketed ring (``artemis_aggregate``).
+    grad_specs  -> accepted and ignored: nothing reads it.  The bucketed
+                   wire's payload is one flat stack, so per-leaf
+                   PartitionSpecs have nothing to pin; the keyword stays
+                   because the on-chip benchmark (``benchmarks/chip/``)
+                   still passes it.
     """
     sizes = _mesh_axis_sizes(mesh)
     n_workers = 1
@@ -820,7 +571,7 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
             (loss, metrics), grads = jax.value_and_grad(
                 model.loss, has_aux=True)(params, batch)
         obs = None
-        if dcfg is not None and dcfg.worker_axes and dcfg.bucketed:
+        if dcfg is not None and dcfg.worker_axes:
             with jax.named_scope("wire"):
                 layout = dcfg.layout(grads)
                 with jax.named_scope("bucketize"):
@@ -830,25 +581,14 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
                         # last sync
                         gb = (art.acc[0] + gb) / k_local
                         art = art._replace(acc=jnp.zeros_like(art.acc))
-                out = artemis_aggregate_bucketed(dcfg, art, gb, layout,
-                                                 n_workers, wid)
+                out = artemis_aggregate(dcfg, art, gb, layout, n_workers,
+                                        wid)
                 (agg_b, art, obs) = out if telem else out + (None,)
                 with jax.named_scope("unbucketize"):
                     agg = bucketing.unbucketize(layout, agg_b, like=grads)
         else:
-            if k_local > 1:
-                grads = jax.tree.map(lambda a, g: (a[0] + g) / k_local,
-                                     art.acc, grads)
-                art = art._replace(acc=jax.tree.map(
-                    lambda a: jnp.zeros_like(a), art.acc))
-            if dcfg is not None and dcfg.worker_axes:
-                with jax.named_scope("wire"):
-                    out = artemis_aggregate(dcfg, art, grads, n_workers, wid,
-                                            grad_specs)
-                (agg, art, obs) = out if telem else out + (None,)
-            else:
-                agg = grads
-                art = art._replace(step=art.step + 1)
+            agg = grads
+            art = art._replace(step=art.step + 1)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(agg, opt_state, stepno)
             params = jax.tree.map(
@@ -893,10 +633,10 @@ def make_train_step(model, optimizer, dcfg: Optional[DistConfig], mesh: Mesh,
             return (TrainState(params, opt_state, art, st.step + 1),
                     (loss, metrics))
 
-        # check_vma=False: params/hbar stay replicated across workers by
-        # construction (identically summed aggregate, shared downlink key),
-        # which vma tracking cannot see; the loss and every metric (the
-        # telemetry among them) leave reduced over the workers, replicated
+        # check_vma=False: params/hbar are declared replicated across workers
+        # (shared downlink key; the ring's sum agrees up to each worker's
+        # summation order), which vma tracking cannot see; the loss and every
+        # metric (the telemetry among them) leave reduced over the workers
         return jax.shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
                              out_specs=(sspec, P()),
                              axis_names=set(waxes),

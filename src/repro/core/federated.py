@@ -205,7 +205,7 @@ def run_percell(problem: Problem, cfg: art.ArtemisConfig, gamma: float,
     """Legacy single-cell loop: traces a fresh ``lax.scan`` per call and
     evaluates the full-batch loss every iteration.  Kept as the reference
     implementation the sweep engine is benchmarked and cross-checked against
-    (benchmarks/dist_bench.py, tests/test_sweep.py)."""
+    (``benchmarks/dist_bench.sweep_engine_suite``, tests/test_sweep.py)."""
     n, d = problem.n_workers, problem.dim
     n_per = problem.X.shape[1]
     c_up, c_dwn = cfg.compressors()

@@ -55,7 +55,7 @@ def bucket_acc(acc: jax.Array, q: jax.Array, scales: jax.Array, *,
     """One ring-hop fold: ``acc + dequant(q, scales)``.
 
     acc [B, R, C] f32, q [B, R, C] int8, scales [B, R, 1] f32 (per-row,
-    matching ``core/dist.squant_encode`` vmapped over buckets).
+    matching ``core/codec.row_squant_encode`` vmapped over buckets).
     ``block_rows``: rows per grid block; None sizes it to the VMEM budget
     (``default_block_rows``).  The grid is ``cdiv(R, block_rows)`` per
     bucket: the fold is elementwise, so the ragged last block needs no mask

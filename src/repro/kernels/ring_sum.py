@@ -6,7 +6,7 @@ reads N int8 buffers + writes N-1 f32 partials; fused it is one pass:
 VMEM-resident accumulator, one f32 write.
 
 Layout: q [N, M, C] int8, scales [N, M, 1] f32 (per-row, matching
-core/dist.squant_encode), output [M, C] f32.
+``core/codec.row_squant_encode``), output [M, C] f32.
 """
 from __future__ import annotations
 

@@ -84,10 +84,7 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, dist_variant: str,
                 None if os.environ.get("REPRO_NO_ACT_CONSTRAINT")
                 else M.act_constraint_fn(mesh, banned))
             opt = sgd(1e-2)
-            gspecs = jax.tree.map(
-                lambda ns: M.strip_axes(ns.spec, banned), pshard) if dcfg else None
-            init_state, step_fn = dist.make_train_step(model, opt, dcfg, mesh,
-                                                       grad_specs=gspecs)
+            init_state, step_fn = dist.make_train_step(model, opt, dcfg, mesh)
             state = jax.eval_shape(init_state, params)
             sshard = dist.state_shardings(mesh, state, pshard, dcfg)
             batch = configs.input_specs(cfg, shape, model)

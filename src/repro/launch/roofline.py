@@ -95,13 +95,13 @@ def collective_dtype_bytes(hlo_text: str) -> Dict[tuple, int]:
 
 
 # ---------------------------------------------------------------------------
-# Compressed-collective wire models (bucketed pipelined ring vs leaf loop)
+# Compressed-collective wire model (the bucketed pipelined ring)
 # ---------------------------------------------------------------------------
 
 def _codec_split(codec, shape) -> Dict[str, float]:
     """Per-message payload bytes by HLO dtype, read off the codec itself
     (``core/codec.Codec.wire_bytes``) — the single source of truth the
-    models below share with the actual encoders."""
+    model below shares with the actual encoders."""
     return {dt: float(b) for dt, b in codec.wire_bytes(tuple(shape)).items()}
 
 
@@ -155,78 +155,33 @@ def bucketed_wire_model(*, n_workers: int, n_buckets: int, rows: int,
     }
 
 
-def leaf_wire_model(leaf_shapes, *, n_workers: int, codec=None,
-                    ici_bw: float = ICI_BW, hbm_bw: float = HBM_BW,
-                    coll_lat: float = COLL_LAT) -> Dict[str, float]:
-    """Same accounting for the legacy per-leaf sequential rings: every leaf
-    pays its own N-1 blocking hops (one collective per payload leaf + a
-    dequant stall each), and the unrolled hops all appear in static HLO."""
-    hops = n_workers - 1
-    by_dtype: Dict[str, float] = {}
-    for s in leaf_shapes:
-        split = (_codec_split(codec, s) if codec is not None
-                 else _default_bucket_split(
-                     int(np.prod(s[:-1])) if len(s) > 1 else 1,
-                     int(s[-1]) if s else 1))
-        for dt, b in split.items():
-            by_dtype[dt] = by_dtype.get(dt, 0.0) + b
-    level_b = by_dtype.get("s8", 0.0)
-    scale_b = sum(b for dt, b in by_dtype.items() if dt != "s8")
-    n_leaves = len(leaf_shapes)
-    payload = level_b + scale_b
-    elems = float(sum(int(np.prod(s)) if s else 1 for s in leaf_shapes))
-    comm = hops * (payload / ici_bw + 2 * n_leaves * coll_lat)
-    deq = n_workers * (payload + 8.0 * elems) / hbm_bw
-    return {
-        "payload_bytes": payload,
-        "hlo_s8_bytes": hops * level_b,      # unrolled: every hop is an instr
-        "hlo_scale_bytes": hops * scale_b,
-        "hlo_bytes_by_dtype": {dt: hops * b for dt, b in by_dtype.items()},
-        "wire_bytes_per_step": hops * payload,
-        "comm_s": comm,
-        "dequant_s": deq,
-        "step_comm_serial_s": comm + deq,
-        "step_comm_pipelined_s": comm + deq,     # nothing overlaps
-        "exposed_comm_s": comm,
-    }
-
-
 def wire_bytes_match(hlo_text: str, model: Dict[str, float], *,
                      tol: float = 0.10) -> Dict[str, float]:
     """Measured-vs-model check for the compressed ring's HLO wire format.
 
     Returns {measured_s8, measured_scale_f32, model_s8, rel_err, ok,
-    by_dtype}.  Models carrying ``hlo_bytes_by_dtype`` (codec-derived) are
-    checked per payload dtype: every dtype the codec ships must appear as
-    collective-permute bytes within ``tol``.  Legacy models (bare
-    ``hlo_s8_bytes``) keep the original s8-only check — the guard that
-    catches the ~256x replication blowup documented in
-    ``artemis_aggregate`` from silently regressing.
+    by_dtype}.  ``model["hlo_bytes_by_dtype"]`` (``bucketed_wire_model``)
+    is checked per payload dtype: every dtype the codec ships must appear
+    as collective-permute bytes within ``tol``.
     """
     by = collective_dtype_bytes(hlo_text)
     s8 = float(by.get(("collective-permute", "s8"), 0))
     f32 = float(by.get(("collective-permute", "f32"), 0))
     out = {"measured_s8": s8, "measured_scale_f32": f32}
-    want_by = model.get("hlo_bytes_by_dtype")
-    if want_by:
-        checks = {}
-        ok = True
-        worst = 0.0
-        for dt, want in want_by.items():
-            if want <= 0:
-                continue
-            got = float(by.get(("collective-permute", dt), 0))
-            rel = abs(got - want) / max(want, 1.0)
-            checks[dt] = {"measured": got, "model": float(want), "rel_err": rel}
-            worst = max(worst, rel)
-            ok = ok and rel <= tol and got > 0
-        out.update({"model_s8": float(want_by.get("s8", 0.0)),
-                    "rel_err": worst, "ok": ok, "by_dtype": checks})
-        return out
-    want = float(model["hlo_s8_bytes"])
-    rel = abs(s8 - want) / max(want, 1.0)
-    out.update({"model_s8": want, "rel_err": rel,
-                "ok": rel <= tol and s8 > 0})
+    want_by = model["hlo_bytes_by_dtype"]
+    checks = {}
+    ok = True
+    worst = 0.0
+    for dt, want in want_by.items():
+        if want <= 0:
+            continue
+        got = float(by.get(("collective-permute", dt), 0))
+        rel = abs(got - want) / max(want, 1.0)
+        checks[dt] = {"measured": got, "model": float(want), "rel_err": rel}
+        worst = max(worst, rel)
+        ok = ok and rel <= tol and got > 0
+    out.update({"model_s8": float(want_by.get("s8", 0.0)),
+                "rel_err": worst, "ok": ok, "by_dtype": checks})
     return out
 
 

@@ -4,9 +4,9 @@ The transformer zoo in ``models/model.py`` is the right workload for
 rooflines, but its forward pass dwarfs the aggregation cost on CPU — useless
 for benchmarking the wire itself.  ``ToyMLP`` is the opposite: a dirt-cheap
 forward over a pytree with MANY leaves of mixed shapes (matrices + biases),
-so step wall-clock is dominated by exactly what the bucketed ring changes:
-per-leaf collective count, dequant stalls, and payload layout.  Used by
-``benchmarks/bucket_ring_bench.py`` and ``tests/helpers/bucket_scenarios.py``.
+so a step is dominated by the wire: bucketize, encode, ring and memory.
+Used by ``tests/helpers/bucket_scenarios.py``, ``benchmarks/fault_bench.py``
+and the ``repro.analysis`` audits.
 """
 from __future__ import annotations
 

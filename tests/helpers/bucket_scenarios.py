@@ -1,7 +1,8 @@
 """Bucketed-wire mesh scenarios (run in a subprocess with 8 fake CPU devices).
 
 Unlike tests/helpers/dist_scenarios.py (which exercises the production
-partial-manual mesh), these run on worker-only meshes.
+partial-manual mesh), these run on worker-only meshes, but for
+``grad_specs_inert``.
 
 Invoked by tests/test_bucketed.py as:
     python tests/helpers/bucket_scenarios.py <scenario>
@@ -26,7 +27,7 @@ from repro.optim import sgd
 VARIANTS = list(dist.VARIANTS)
 
 
-def _setup(variant="artemis", *, wire="bucketed", reduce_impl="pipelined",
+def _setup(variant="artemis", *, reduce_impl="pipelined",
            mesh_shape=(2, 2), axes=("p", "q"), p=1.0, s=3,
            bucket_bytes=4096, max_buckets=8, row=64, local_steps=1,
            error_feedback=False, fault_cfg=None,
@@ -35,8 +36,7 @@ def _setup(variant="artemis", *, wire="bucketed", reduce_impl="pipelined",
     model = ToyMLP(n_layers=4, d=64)
     params = model.init(jax.random.PRNGKey(0))
     dcfg = dist.DistConfig(worker_axes=axes, variant=variant, s=s,
-                           p_participation=p, wire=wire,
-                           reduce_impl=reduce_impl, bucket_bytes=bucket_bytes,
+                           p_participation=p, reduce_impl=reduce_impl, bucket_bytes=bucket_bytes,
                            max_buckets=max_buckets, bucket_row=row,
                            local_steps=local_steps,
                            error_feedback=error_feedback, faults=fault_cfg,
@@ -71,46 +71,66 @@ def scenario_ring_matches_psum():
 
 
 def scenario_ring_bitwise():
-    """The pipelined scan ring matches the sequential unrolled transport
-    (the pre-bucketing schedule applied to the same payload) BIT-FOR-BIT —
-    both multi-bucket and the degenerate buckets=1 / bucket_bytes=inf
-    layout, which is the leaf-loop wire collapsed to one message."""
-    grids = [dict(),                                        # multi-bucket
+    """The pipelined scan ring (``bucket_ring_reduce``) matches the
+    unpipelined reference (``bucket_ring_reduce_sequential``) BIT-FOR-BIT
+    on the same payloads over 8 workers, inside a worker shard_map — both
+    multi-bucket and the degenerate buckets=1 / bucket_bytes=inf layout."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import codec as wire
+    n = 8
+    mesh = make_host_mesh((n,), ("pod",))
+    params = ToyMLP(n_layers=4, d=64).init(jax.random.PRNGKey(0))
+    grids = [dict(bucket_bytes=4096, max_buckets=8),        # multi-bucket
              dict(bucket_bytes=1 << 40, max_buckets=1)]     # B=1, elems=all
     for kw in grids:
-        out = {}
-        for impl in ("pipelined", "sequential"):
-            state, loss = _run("artemis", reduce_impl=impl, **kw)
-            out[impl] = jax.tree.map(np.asarray, state.params)
-        for a, b in zip(jax.tree.leaves(out["pipelined"]),
-                        jax.tree.leaves(out["sequential"])):
-            np.testing.assert_array_equal(a, b, err_msg=str(kw))
+        lay = bucketing.make_layout(params, row=64, **kw)
+        assert (lay.n_buckets > 1) == (kw["max_buckets"] > 1), lay
+        wc = wire.make_codec("row_squant", lay.row, s=3)
+        grads = jax.random.normal(jax.random.PRNGKey(1), (n,) + lay.shape)
+
+        def both(g):
+            key = jax.random.fold_in(jax.random.PRNGKey(2),
+                                     jax.lax.axis_index("pod"))
+            enc = bucketing.encode_buckets(wc, key, g[0])
+            return (dist.bucket_ring_reduce(wc, enc, ("pod",), n)[None],
+                    dist.bucket_ring_reduce_sequential(wc, enc, ("pod",),
+                                                       n)[None],
+                    dist.payload_decode(wc, enc)[None])
+
+        ring, seq, dec = jax.jit(jax.shard_map(
+            both, mesh=mesh, in_specs=P("pod"),
+            out_specs=(P("pod"),) * 3, check_vma=False))(grads)
+        ring, seq, dec = np.asarray(ring), np.asarray(seq), np.asarray(dec)
+        assert np.any(ring != 0), kw
+        np.testing.assert_array_equal(ring, seq, err_msg=str(kw))
+        # the documented order: own payload first, then w-1, w-2, ...
+        for w in range(n):
+            want = dec[w]
+            for k in range(1, n):
+                want = want + dec[(w - k) % n]
+            np.testing.assert_array_equal(ring[w], want, err_msg=str(kw))
 
 
 def scenario_ef_pp_inactive_zero():
     """Satellite (EF + PP2 leak fix): a round where every worker is inactive
     must change params by EXACTLY zero and leave the EF buffers untouched —
-    previously the inactive worker's e kept riding the compressed uplink.
-    Checked on BOTH wires (the fix is `scale *= active` in each)."""
-    for wire in dist.WIRES:
-        _, _, params, _, init_state, step_fn, batch = _setup(
-            "dore", wire=wire, p=1e-9)
-        state = init_state(params)
-        e0 = jax.tree.map(lambda e: jnp.full_like(e, 0.3), state.artemis.e)
-        state = state._replace(artemis=state.artemis._replace(e=e0))
-        new, (loss, _) = jax.jit(step_fn)(state, batch)
-        assert np.isfinite(loss), wire
-        for p0, p1 in zip(jax.tree.leaves(state.params),
-                          jax.tree.leaves(new.params)):
-            np.testing.assert_array_equal(np.asarray(p0), np.asarray(p1),
-                                          err_msg=wire)
-        for a, b in zip(jax.tree.leaves(e0), jax.tree.leaves(new.artemis.e)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=wire)
-        for a, b in zip(jax.tree.leaves(state.artemis.h),
-                        jax.tree.leaves(new.artemis.h)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=wire)
+    previously the inactive worker's e kept riding the compressed uplink
+    (the fix is `scale *= active`)."""
+    _, _, params, _, init_state, step_fn, batch = _setup("dore", p=1e-9)
+    state = init_state(params)
+    e0 = jax.tree.map(lambda e: jnp.full_like(e, 0.3), state.artemis.e)
+    state = state._replace(artemis=state.artemis._replace(e=e0))
+    new, (loss, _) = jax.jit(step_fn)(state, batch)
+    assert np.isfinite(loss)
+    for p0, p1 in zip(jax.tree.leaves(state.params),
+                      jax.tree.leaves(new.params)):
+        np.testing.assert_array_equal(np.asarray(p0), np.asarray(p1))
+    for a, b in zip(jax.tree.leaves(e0), jax.tree.leaves(new.artemis.e)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree.leaves(state.artemis.h),
+                    jax.tree.leaves(new.artemis.h)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def scenario_hlo_wire_guard():
@@ -164,38 +184,35 @@ def scenario_bucketed_convergence():
 def scenario_fault_zero_bitwise():
     """Zero-fault identity on the mesh backend: DistConfig(faults=
     FaultConfig()) — every rate zero, defenses off — produces bit-identical
-    trajectories to faults=None on BOTH wires.  The fault paths are all
-    statically gated and their PRNG streams salted, so the config's mere
-    presence must not move a bit."""
-    for wire in dist.WIRES:
-        out = {}
-        for fc in (None, faults.FaultConfig()):
-            state, loss = _run("artemis", wire=wire, p=0.5, fault_cfg=fc)
-            out[fc is None] = (jax.tree.map(np.asarray, state.params), loss)
-        for a, b in zip(jax.tree.leaves(out[True][0]),
-                        jax.tree.leaves(out[False][0])):
-            np.testing.assert_array_equal(a, b, err_msg=wire)
-        assert out[True][1] == out[False][1], wire
+    trajectories to faults=None.  The fault paths are all statically gated
+    and their PRNG streams salted, so the config's mere presence must not
+    move a bit."""
+    out = {}
+    for fc in (None, faults.FaultConfig()):
+        state, loss = _run("artemis", p=0.5, fault_cfg=fc)
+        out[fc is None] = (jax.tree.map(np.asarray, state.params), loss)
+    for a, b in zip(jax.tree.leaves(out[True][0]),
+                    jax.tree.leaves(out[False][0])):
+        np.testing.assert_array_equal(a, b)
+    assert out[True][1] == out[False][1]
 
 
 def scenario_fault_matrix():
-    """Fault matrix x both wires: wire bit-flips, NaN gradient blowups, and
-    a straggler burst over sticky Markov participation — each with server
-    scrubbing on — must keep training finite (corrupt => inactive via the
-    PP2 zero-scale path)."""
+    """Fault matrix: wire bit-flips, NaN gradient blowups, and a straggler
+    burst over sticky Markov participation — each with server scrubbing on
+    — must keep training finite (corrupt => inactive via the PP2 zero-scale
+    path)."""
     matrix = {
         "bitflip": faults.FaultConfig(bitflip_rate=0.02, scrub=True),
         "nan_blowup": faults.FaultConfig(blowup_rate=0.5, scrub=True),
         "dropout_burst": faults.FaultConfig(straggler_rate=0.5, p_stay=0.8,
                                             scrub=True),
     }
-    for wire in dist.WIRES:
-        for name, fc in matrix.items():
-            state, loss = _run("artemis", wire=wire, p=0.5, steps=4,
-                               fault_cfg=fc)
-            assert np.isfinite(loss), (wire, name, loss)
-            for leaf in jax.tree.leaves(state.params):
-                assert np.all(np.isfinite(np.asarray(leaf))), (wire, name)
+    for name, fc in matrix.items():
+        state, loss = _run("artemis", p=0.5, steps=4, fault_cfg=fc)
+        assert np.isfinite(loss), (name, loss)
+        for leaf in jax.tree.leaves(state.params):
+            assert np.all(np.isfinite(np.asarray(leaf))), name
 
 
 def scenario_codec_sparsify():
@@ -250,10 +267,10 @@ def scenario_codec_wire_guard():
 def scenario_obs_wire_telemetry():
     """Observability (repro.obs): DistConfig(telemetry=True) attaches a
     psum'd ``obs`` dict to the step metrics whose per-worker wire_bytes
-    matches the codec-derived roofline model EXACTLY on both ring wires
-    (bucketed + leaf); the psum fallback reports its dense-f32 proxy; and
-    telemetry is loss-neutral + absent from metrics when off.  The measured
-    vs model numbers round-trip as schema-valid ``wire`` events."""
+    matches the codec-derived roofline model EXACTLY on the pipelined ring;
+    the psum fallback reports its dense-f32 proxy; and telemetry is
+    loss-neutral + absent from metrics when off.  The measured vs model
+    numbers round-trip as schema-valid ``wire`` events."""
     import dataclasses
     import tempfile
 
@@ -262,45 +279,69 @@ def scenario_obs_wire_telemetry():
 
     log_path = tempfile.mktemp(suffix=".jsonl")
     with obs_events.EventLog(log_path) as log:
-        for wire, impl in [("bucketed", "pipelined"),
-                           ("bucketed", "sequential"),
-                           ("bucketed", "psum"),
-                           ("leaf", "sequential")]:
+        for impl in ("pipelined", "psum"):
             mesh, model, params, dcfg, init_state, step_fn, batch = _setup(
-                "artemis", wire=wire, reduce_impl=impl,
-                mesh_shape=(4,), axes=("pod",))
+                "artemis", reduce_impl=impl, mesh_shape=(4,), axes=("pod",))
             dcfg_t = dataclasses.replace(dcfg, telemetry=True)
             init_t, step_t = dist.make_train_step(model, _sgd(0.05),
                                                   dcfg_t, mesh)
             state, (loss, m) = jax.jit(step_t)(init_t(params), batch)
-            assert "obs" in m, (wire, impl, sorted(m))
+            assert "obs" in m, (impl, sorted(m))
             obs = {k: float(v) for k, v in m["obs"].items()}
             assert obs["mesh_active"] == 4.0, obs
             # telemetry off: no obs key, identical loss
             _, (loss0, m0) = jax.jit(step_fn)(init_state(params), batch)
-            assert "obs" not in m0, (wire, impl)
-            assert float(loss0) == float(loss), (wire, impl)
-            if wire == "bucketed":
-                lay = dcfg.layout(params)
-                wm = roofline.bucketed_wire_model(
-                    n_workers=4, n_buckets=lay.n_buckets, rows=lay.rows,
-                    row=lay.row, codec=dcfg.wire_codec(lay.row))
-            else:
-                shapes = [tuple(l.shape) for l in jax.tree.leaves(params)]
-                wm = roofline.leaf_wire_model(shapes, n_workers=4,
-                                              codec=dcfg.wire_codec(64))
+            assert "obs" not in m0, impl
+            assert float(loss0) == float(loss), impl
+            lay = dcfg.layout(params)
+            wm = roofline.bucketed_wire_model(
+                n_workers=4, n_buckets=lay.n_buckets, rows=lay.rows,
+                row=lay.row, codec=dcfg.wire_codec(lay.row))
             per_worker = obs["wire_bytes"] / 4.0
-            log.emit("wire", wire=wire, reduce_impl=impl,
+            log.emit("wire", wire="bucketed", reduce_impl=impl,
                      measured_bytes=per_worker,
                      model_bytes=wm["wire_bytes_per_step"])
             if impl == "psum":          # dense all-reduce proxy, not a ring
-                assert per_worker > wm["wire_bytes_per_step"], (wire, impl)
+                assert per_worker > wm["wire_bytes_per_step"], impl
             else:
                 assert per_worker == wm["wire_bytes_per_step"], (
-                    wire, impl, per_worker, wm["wire_bytes_per_step"])
+                    impl, per_worker, wm["wire_bytes_per_step"])
     evs = obs_events.read_events(log_path)
-    assert len(evs) == 4
+    assert len(evs) == 2
     assert obs_events.validate_events(evs) == []
+
+
+def scenario_grad_specs_inert():
+    """``make_train_step``'s ``grad_specs`` keyword is inert: on a (2, 2, 2)
+    pod/data/model mesh with a reduced starcoder2 placed by the sharding
+    policy, the step built with and without it lowers to the same HLO."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import configs
+    from repro.launch import mesh as M
+    from repro.models.model import build_model
+
+    mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
+    cfg = configs.get_config("starcoder2-7b", reduced=True)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    pshard = M.params_shardings(mesh, params)
+    gspecs = jax.tree.map(lambda ns: M.strip_axes(ns.spec, ("pod",)), pshard)
+    dcfg = dist.DistConfig(worker_axes=("pod",), variant="artemis", s=3)
+    tokens = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (8, 128), 0, cfg.vocab),
+        NamedSharding(mesh, P(("pod", "data"))))
+    texts = []
+    with jax.set_mesh(mesh):
+        placed = jax.device_put(params, pshard)
+        for gs in (None, gspecs):
+            init_state, step_fn = dist.make_train_step(
+                model, sgd(0.05), dcfg, mesh, grad_specs=gs)
+            state = init_state(placed)
+            texts.append(jax.jit(step_fn).lower(
+                state, {"tokens": tokens}).as_text())
+    assert "collective_permute" in texts[0]
+    assert texts[0] == texts[1]
 
 
 if __name__ == "__main__":

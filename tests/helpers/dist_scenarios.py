@@ -29,12 +29,7 @@ def _setup(variant="artemis", worker_axes=("pod",), s=3, p=1.0):
     params = model.init(jax.random.PRNGKey(0))
     dcfg = None if variant == "none" else dist.DistConfig(
         worker_axes=worker_axes, variant=variant, s=s, p_participation=p)
-    pshard = M.params_shardings(mesh, params)
-    banned = dcfg.worker_axes if dcfg else ()
-    gspecs = (jax.tree.map(lambda ns: M.strip_axes(ns.spec, banned), pshard)
-              if dcfg else None)
-    init_state, step_fn = dist.make_train_step(model, sgd(0.05), dcfg, mesh,
-                                               grad_specs=gspecs)
+    init_state, step_fn = dist.make_train_step(model, sgd(0.05), dcfg, mesh)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 128), 0,
                                           cfg.vocab)}
     return mesh, model, params, init_state, step_fn, batch
@@ -68,6 +63,36 @@ def scenario_sgd_variant_matches_baseline():
                 state, (loss, _) = jstep(state, batch)
             out[tag] = float(loss)
     assert abs(out["explicit"] - out["baseline"]) < 5e-3, out
+
+
+def scenario_baseline_state_tree():
+    """dcfg=None (the plain data-parallel baseline) keeps its per-leaf
+    Artemis state, all replicated, through a step: h, e and acc a [1] f32
+    zero stub per parameter leaf, hbar a scalar zero per leaf — the tree a
+    ``--dist none`` checkpoint holds."""
+    mesh, model, params, init_state, step_fn, batch = _setup("none")
+    ptree = jax.tree.structure(params)
+    with jax.set_mesh(mesh):
+        state = init_state(params)
+        new, (loss, _) = jax.jit(step_fn)(state, batch)
+    assert np.isfinite(float(loss))
+    for st in (state, new):
+        art = st.artemis
+        for name, shape in (("h", (1,)), ("e", (1,)), ("acc", (1,)),
+                            ("hbar", ())):
+            tree = getattr(art, name)
+            assert jax.tree.structure(tree) == ptree, name
+            for leaf in jax.tree.leaves(tree):
+                assert leaf.shape == shape and leaf.dtype == jnp.float32, name
+                assert not np.any(np.asarray(leaf)), name
+        assert art.prev_active.shape == (1,) and art.step.shape == ()
+        for leaf in jax.tree.leaves(art):
+            assert leaf.sharding.is_fully_replicated, leaf.sharding
+    assert int(state.artemis.step) == 0 and int(new.artemis.step) == 1
+    shard = dist.state_shardings(mesh, state, M.params_shardings(mesh, params),
+                                 None)
+    for sh in jax.tree.leaves(shard.artemis):
+        assert sh.spec == P(), sh
 
 
 def scenario_all_variants_lower():

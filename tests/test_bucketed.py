@@ -20,6 +20,7 @@ SCENARIOS = [
     "fault_matrix",
     "codec_sparsify",
     "codec_wire_guard",
+    "grad_specs_inert",
 ]
 
 

@@ -12,6 +12,7 @@ import pytest
 from helpers.prop import given, settings, st
 
 from repro.core import bucketing as B
+from repro.core import codec
 from repro.core import dist
 from repro.kernels import bucket_ring as BK
 
@@ -87,18 +88,36 @@ def test_bucket_keys_distinct():
 
 
 def test_bucket_encode_decode_unbiased_scale():
-    """Per-bucket encode matches per-row squant semantics bucket by bucket."""
+    """``encode_buckets`` with the wire's ``row_squant`` codec matches
+    per-row squant semantics bucket by bucket."""
     tree = _tree(KEY, [(64, 32), (128,)])
     lay = B.make_layout(tree, bucket_bytes=1024, max_buckets=8, row=64)
     buckets = B.bucketize(lay, tree)
-    q, sc = dist.bucket_encode(KEY, buckets, s=3)
+    enc = B.encode_buckets(codec.make_codec("row_squant", lay.row, s=3), KEY,
+                           buckets)
+    q, sc = enc["levels"], enc["scales"]
     assert q.shape == lay.shape and q.dtype == jnp.int8
     assert sc.shape == (lay.n_buckets, lay.rows, 1)
     keys = B.bucket_keys(KEY, lay.n_buckets)
     for b in range(lay.n_buckets):
-        qb, sb = dist.squant_encode(keys[b], buckets[b], 3)
+        qb, sb = codec.row_squant_encode(keys[b], buckets[b], 3)
         np.testing.assert_array_equal(np.asarray(q[b]), np.asarray(qb))
         np.testing.assert_allclose(np.asarray(sc[b]), np.asarray(sb))
+
+
+@pytest.mark.parametrize("kw, err", [
+    ({"reduce_impl": "sequential"}, ValueError),
+    ({"reduce_impl": "ring"}, ValueError),
+    ({"codec": "nope"}, ValueError),
+    ({"wire": "leaf"}, TypeError),
+])
+def test_dist_config_rejects(kw, err):
+    """The mesh has one wire, the bucketed ring: ``DistConfig`` refuses a
+    ``wire`` field, a ``reduce_impl`` other than the pipelined ring and its
+    psum reference, and an unregistered codec."""
+    assert dist.REDUCE_IMPLS == ("pipelined", "psum")
+    with pytest.raises(err):
+        dist.DistConfig(**kw)
 
 
 # ---------------------------------------------------------------------------

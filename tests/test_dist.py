@@ -13,6 +13,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 SCENARIOS = [
     "convergence",
     "sgd_variant_matches_baseline",
+    "baseline_state_tree",
     "all_variants_lower",
     "partial_participation",
     "int8_ring_in_hlo",

@@ -25,7 +25,7 @@ from helpers.prop import given, settings, st
 
 from repro.checkpoint import checkpointer
 from repro.core import artemis as art
-from repro.core import dist
+from repro.core import codec
 from repro.core import faults
 from repro.core import federated as fed
 from repro.core import sweep as sw
@@ -322,9 +322,9 @@ def test_nan_tile_decodes_to_finite_zero_kernels():
 
 def test_nan_row_decodes_to_finite_zero_dist():
     x = jnp.full((4, 8), jnp.nan)
-    q, scale = dist.squant_encode(KEY, x, 1)
+    q, scale = codec.row_squant_encode(KEY, x, 1)
     assert np.all(np.asarray(scale) == 0.0)
-    out = dist.squant_decode(q, scale)
+    out = codec.row_squant_decode(q, scale)
     assert np.array_equal(np.asarray(out), np.zeros((4, 8), np.float32))
 
 

@@ -189,14 +189,14 @@ def test_ring_sum_matches_ref(n, shape):
 
 def test_ring_sum_roundtrip_consistency():
     """ring_sum of encoded worker deltas == sum of decoded deltas."""
-    from repro.core import dist as D
+    from repro.core import codec
     n, m, c = 4, 256, 256
     xs = jax.random.normal(jax.random.PRNGKey(0), (n, m, c))
     qs, ss = [], []
     for i in range(n):
-        q, s_ = D.squant_encode(jax.random.PRNGKey(i + 1), xs[i], 1)
+        q, s_ = codec.row_squant_encode(jax.random.PRNGKey(i + 1), xs[i], 1)
         qs.append(q)
         ss.append(s_)
     out = rs.ring_sum(jnp.stack(qs), jnp.stack(ss), interpret=True)
-    expect = sum(D.squant_decode(q, s_) for q, s_ in zip(qs, ss))
+    expect = sum(codec.row_squant_decode(q, s_) for q, s_ in zip(qs, ss))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-5)

@@ -304,8 +304,8 @@ def test_train_main_writes_attn_path_event(tmp_path, monkeypatch):
 
 
 def test_mesh_wire_telemetry_subprocess():
-    """wire_bytes matches the codec-derived roofline model on both mesh
-    wires (8 fake CPU devices; see scenario_obs_wire_telemetry)."""
+    """wire_bytes matches the codec-derived roofline model on the mesh
+    wire (8 fake CPU devices; see scenario_obs_wire_telemetry)."""
     helper = os.path.join(os.path.dirname(__file__), "helpers",
                           "bucket_scenarios.py")
     src = os.path.join(os.path.dirname(__file__), "..", "src")

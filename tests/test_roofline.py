@@ -54,28 +54,19 @@ def test_bucketed_wire_model_accounting():
     assert fast["exposed_comm_s"] == 0.0
 
 
-def test_leaf_wire_model_accounting():
-    shapes = [(64, 64), (64,), (64, 1)]
-    m = R.leaf_wire_model(shapes, n_workers=4)
-    payload_level = 64 * 64 + 64 + 64
-    assert m["hlo_s8_bytes"] == 3 * payload_level     # unrolled hops in HLO
-    assert m["wire_bytes_per_step"] == 3 * m["payload_bytes"]
-    # nothing overlaps on the leaf path
-    assert m["step_comm_pipelined_s"] == m["step_comm_serial_s"]
-    # same bytes, but the per-leaf latency term makes it slower than bucketed
-    b = R.bucketed_wire_model(n_workers=4, n_buckets=1,
-                              rows=payload_level // 64, row=64)
-    assert m["comm_s"] > b["comm_s"]
-
-
 def test_wire_bytes_match_guard():
-    m = {"hlo_s8_bytes": 32 * 16}
-    ok = R.wire_bytes_match(HLO, m)
+    """The bucketed model's levels and row scales against a ring hop's two
+    collective-permutes."""
+    hop = HLO + "  %cs = f32[32,1]{1,0} collective-permute(%s)\n"
+    m = R.bucketed_wire_model(n_workers=4, n_buckets=1, rows=32, row=16)
+    ok = R.wire_bytes_match(hop, m)
     assert ok["ok"] and ok["rel_err"] == 0.0
-    bad = R.wire_bytes_match(HLO, {"hlo_s8_bytes": 32 * 16 * 2})
+    assert ok["measured_s8"] == 32 * 16 and ok["measured_scale_f32"] == 4 * 32
+    bad = R.wire_bytes_match(hop, R.bucketed_wire_model(
+        n_workers=4, n_buckets=2, rows=32, row=16))
     assert not bad["ok"] and bad["rel_err"] == pytest.approx(0.5)
     none = R.wire_bytes_match("", m)
-    assert not none["ok"]                  # zero measured s8 never passes
+    assert not none["ok"]                  # zero measured bytes never pass
 
 
 def test_bucketed_wire_model_from_codec():
@@ -101,16 +92,6 @@ def test_bucketed_wire_model_from_codec():
     assert m_sp["hlo_bytes_by_dtype"] == {"s32": 8 * 4.0 * n,
                                           "f32": 8 * 4.0 * n}
     assert m_sp["hlo_s8_bytes"] == 0.0
-
-
-def test_leaf_wire_model_from_codec():
-    from repro.core import codec as wire
-    shapes = [(64, 64), (64,), (64, 1)]
-    rq = wire.make_codec("row_squant", 64, s=3)
-    m_codec = R.leaf_wire_model(shapes, n_workers=4, codec=rq)
-    m_legacy = R.leaf_wire_model(shapes, n_workers=4)
-    for k in ("payload_bytes", "hlo_s8_bytes", "hlo_scale_bytes", "comm_s"):
-        assert m_codec[k] == m_legacy[k], k
 
 
 def test_wire_bytes_match_per_dtype():

@@ -182,9 +182,7 @@ def test_artemis_step_fused_attention_on_four_chips(v5e_2x2, monkeypatch):
     pshard = M.params_shardings(mesh, params)
     model.set_sharding(M.layer_constraint_fn(mesh, ("data",)),
                        M.act_constraint_fn(mesh, ("data",)))
-    gspecs = jax.tree.map(lambda ns: M.strip_axes(ns.spec, ("data",)), pshard)
-    init_state, step = dist.make_train_step(model, adam(3e-3), dcfg, mesh,
-                                            grad_specs=gspecs)
+    init_state, step = dist.make_train_step(model, adam(3e-3), dcfg, mesh)
 
     def placed(tree, shardings):
         return jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(
