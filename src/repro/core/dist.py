@@ -47,6 +47,7 @@ from repro.core import bucketing
 from repro.core import codec as wire
 from repro.core import faults as FLT
 from repro.kernels import bucket_ring as BK
+from repro.kernels import wire_round as WR
 from repro.kernels import default_interpret
 from repro.launch.mesh import strip_axes
 
@@ -163,15 +164,28 @@ def _payload_acc(codec: wire.Codec, acc: jax.Array,
     kernels/bucket_ring path; any other codec decodes then adds."""
     if not codec.fused_acc:
         return acc + payload_decode(codec, payload)
-    fold = functools.partial(BK.bucket_acc, interpret=interpret)
-    # GSPMD cannot partition a Mosaic kernel: where the worker shard_map
-    # leaves mesh axes auto, run it manual over every axis (the bucket stack
-    # is replicated over the auto ones)
+    # the fold is elementwise: run it over the stacks' rows-major view
+    # (``wire_round.rows_major``, the layout the fused round keeps them
+    # in), as one bucket of R * B rows
+    b, r, c = acc.shape
+
+    def rows(x):
+        return WR.rows_major(x).reshape(1, r * b, x.shape[-1])
+
+    fold = _manual(functools.partial(BK.bucket_acc, interpret=interpret))
+    out = fold(rows(acc), rows(payload["levels"]), rows(payload["scales"]))
+    return WR.rows_major(out.reshape(r, b, c))
+
+
+def _manual(kernel):
+    """GSPMD cannot partition a Mosaic kernel: where the worker shard_map
+    leaves mesh axes auto, run it manual over every axis (the bucket stacks
+    are replicated over the auto ones)."""
     mesh = jax.sharding.get_abstract_mesh()
     if any(t != jax.sharding.AxisType.Manual for t in mesh.axis_types):
-        fold = jax.shard_map(fold, in_specs=P(), out_specs=P(),
+        return jax.shard_map(kernel, in_specs=P(), out_specs=P(),
                              check_vma=False)
-    return fold(acc, payload["levels"], payload["scales"])
+    return kernel
 
 
 def bucket_ring_reduce(codec: wire.Codec, payload: wire.WirePayload,
@@ -301,6 +315,18 @@ def _round_keys(cfg: DistConfig, step: jax.Array, wid: jax.Array,
     return up_key, dwn_key, active, part, flt_key
 
 
+def fused_round(cfg: DistConfig, row: int) -> bool:
+    """Whether a round runs on ``kernels/wire_round``'s two kernels: the
+    row-scale codec with worker and server memory and a compressed
+    downlink (Artemis), no error feedback, and no fault that changes the
+    payload between encode and the memory update (bit flips, scrubbing).
+    Every other configuration runs the XLA chain, which the kernels are
+    held to."""
+    return (cfg.wire_codec(row).fused_acc and cfg.memory
+            and cfg.dwn_compress and not cfg.use_ef
+            and not FLT.of(cfg.faults).wire_faults)
+
+
 def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
                       gbuckets: jax.Array, layout: bucketing.BucketLayout,
                       n_workers: int, wid: jax.Array):
@@ -309,7 +335,10 @@ def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
     Inside shard_map, where each per-worker state leaf is the local
     [1, B, R, C] slice.  The uplink sum runs over ``cfg.reduce_impl``:
     the pipelined scan ring (default) or a dense dequantize-then-psum (the
-    equivalence-test reference).
+    equivalence-test reference).  Where ``fused_round`` holds, the encode
+    with the worker's memory update and the server's memory update with
+    the downlink each run as one kernel (``kernels/wire_round``); the
+    payload on the ring is the same.
     """
     axes = cfg.worker_axes
     n = n_workers
@@ -319,6 +348,8 @@ def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
         _codec_alpha(cfg, layout.row) if cfg.memory else 0.0)
     p = cfg.p_participation
     mdt = jnp.dtype(cfg.memory_dtype)
+    fused = fused_round(cfg, layout.row)
+    itp = default_interpret()
 
     obs_blow = jnp.zeros((), jnp.float32)
     obs_scrub = jnp.zeros((), jnp.float32)
@@ -340,34 +371,50 @@ def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
             finite = jnp.all(jnp.isfinite(g32)).astype(jnp.float32)
             active = active * finite
             g32 = FLT.nan_to_zero(g32)
-        h = (state.h[0].astype(jnp.float32) if cfg.memory
-             else jnp.zeros_like(g32))
-        e_buf = state.e[0] if cfg.use_ef else None
-        delta = (g32 - h) * active
-        if cfg.use_ef:
-            delta = delta + e_buf
-
-        ok = active
-        if cfg.up_compress:
-            enc = bucketing.encode_buckets(wc, up_key, delta)
-            # PP2: an inactive worker's payload (its EF buffer under Dore)
-            # must contribute EXACTLY zero to the sum — zero the wire float
-            # leaves (the scales for squant, the values for sparsify).
-            enc = FLT.mask_payload(enc, active)
-            if fc.bitflip_rate > 0.0:
-                # only a payload actually on the wire can pick up flipped bits
-                enc = FLT.corrupt_payload(jax.random.fold_in(flt_key, 3), enc,
-                                          fc.bitflip_rate, only=active)
-            if fc.scrub:
-                # per-BUCKET checksum: a corrupt bucket is dropped through the
-                # same zero-scale path as inactivity; its h/e slices stay put
-                valid = jax.vmap(wc.validate)(enc)         # [B]
-                ok = active * valid.reshape(-1, 1, 1)      # [B,1,1] broadcast
-                enc = FLT.scrub_payload(enc, valid)
-                if cfg.telemetry:
-                    obs_scrub = valid.shape[0] - jnp.sum(valid)
+        if fused:
+            # the payload's tree and metadata, as the codec's encode builds
+            like = jax.eval_shape(functools.partial(
+                bucketing.encode_buckets, wc), up_key, g32)
+            s = like.meta.param("s")
+            levels, scales, h_new = _manual(functools.partial(
+                WR.uplink_round, alpha=alpha, s=s, interpret=itp))(
+                    g32, state.h[0], active,
+                    bucketing.bucket_keys(up_key, layout.n_buckets))
+            enc = like.replace(levels=levels, scales=scales)
+            h_new = h_new[None]
         else:
-            dhat_i = delta * active
+            h = (state.h[0].astype(jnp.float32) if cfg.memory
+                 else jnp.zeros_like(g32))
+            e_buf = state.e[0] if cfg.use_ef else None
+            delta = (g32 - h) * active
+            if cfg.use_ef:
+                delta = delta + e_buf
+
+            ok = active
+            if cfg.up_compress:
+                enc = bucketing.encode_buckets(wc, up_key, delta)
+                # PP2: an inactive worker's payload (its EF buffer under
+                # Dore) must contribute EXACTLY zero to the sum — zero the
+                # wire float leaves (the scales for squant, the values for
+                # sparsify).
+                enc = FLT.mask_payload(enc, active)
+                if fc.bitflip_rate > 0.0:
+                    # only a payload actually on the wire can pick up
+                    # flipped bits
+                    enc = FLT.corrupt_payload(jax.random.fold_in(flt_key, 3),
+                                              enc, fc.bitflip_rate,
+                                              only=active)
+                if fc.scrub:
+                    # per-BUCKET checksum: a corrupt bucket is dropped
+                    # through the same zero-scale path as inactivity; its
+                    # h/e slices stay put
+                    valid = jax.vmap(wc.validate)(enc)         # [B]
+                    ok = active * valid.reshape(-1, 1, 1)      # [B,1,1]
+                    enc = FLT.scrub_payload(enc, valid)
+                    if cfg.telemetry:
+                        obs_scrub = valid.shape[0] - jnp.sum(valid)
+            else:
+                dhat_i = delta * active
 
     with jax.named_scope("ring"):
         if not cfg.up_compress:
@@ -382,26 +429,36 @@ def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
             dhat_sum = bucket_ring_reduce(wc, enc, axes, n)
             obs_bytes = (n - 1) * _payload_nbytes(enc)
 
-    with jax.named_scope("memory"):
-        if cfg.up_compress:
-            dhat_i = payload_decode(wc, enc)
-        if cfg.use_ef:
-            e_new = (ok * (delta - dhat_i) + (1 - ok) * e_buf)[None]
-        else:
-            e_new = state.e
-        if cfg.memory:
-            hbar = state.hbar.astype(jnp.float32)
-            ghat = hbar + dhat_sum / (p * n)
-            h_new = (h + alpha * dhat_i).astype(mdt)[None]
-            hbar_new = (hbar + alpha * dhat_sum / n).astype(mdt)
-        else:
-            ghat = dhat_sum / (p * n)
-            h_new, hbar_new = state.h, state.hbar
-    if cfg.dwn_compress:
+    if fused:
         # zero-byte broadcast: identical key -> identical compression
         with jax.named_scope("downlink"):
-            ghat = payload_decode(wc, bucketing.encode_buckets(wc, dwn_key,
-                                                               ghat))
+            hbar_new, ghat = _manual(functools.partial(
+                WR.server_round, p=p, n=n, alpha=alpha, s=s,
+                interpret=itp))(
+                    state.hbar, dhat_sum,
+                    bucketing.bucket_keys(dwn_key, layout.n_buckets))
+        e_new = state.e
+    else:
+        with jax.named_scope("memory"):
+            if cfg.up_compress:
+                dhat_i = payload_decode(wc, enc)
+            if cfg.use_ef:
+                e_new = (ok * (delta - dhat_i) + (1 - ok) * e_buf)[None]
+            else:
+                e_new = state.e
+            if cfg.memory:
+                hbar = state.hbar.astype(jnp.float32)
+                ghat = hbar + dhat_sum / (p * n)
+                h_new = (h + alpha * dhat_i).astype(mdt)[None]
+                hbar_new = (hbar + alpha * dhat_sum / n).astype(mdt)
+            else:
+                ghat = dhat_sum / (p * n)
+                h_new, hbar_new = state.h, state.hbar
+        if cfg.dwn_compress:
+            # zero-byte broadcast: identical key -> identical compression
+            with jax.named_scope("downlink"):
+                ghat = payload_decode(wc, bucketing.encode_buckets(
+                    wc, dwn_key, ghat))
 
     new_state = ArtemisDistState(h_new, hbar_new, e_new, state.acc,
                                  jnp.reshape(part, (1,)), state.step + 1)
@@ -410,7 +467,8 @@ def artemis_aggregate(cfg: DistConfig, state: ArtemisDistState,
     obs = {"wire_bytes": jnp.float32(obs_bytes),
            "mesh_active": jnp.reshape(active, ()).astype(jnp.float32),
            "mesh_scrubbed": obs_scrub.astype(jnp.float32),
-           "mesh_blowup_hits": obs_blow}
+           "mesh_blowup_hits": obs_blow,
+           "mesh_fused_round": jnp.float32(fused)}
     return ghat, new_state, obs
 
 

@@ -1,7 +1,7 @@
 import os
 
 from repro.kernels import (  # noqa: F401
-    bucket_ring, fused_memory, ops, ref, ring_sum, squant)
+    bucket_ring, fused_memory, ops, ref, ring_sum, squant, wire_round)
 
 
 def default_interpret() -> bool:

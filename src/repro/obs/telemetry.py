@@ -222,6 +222,9 @@ MESH_METRICS = tuple(register(Metric(n, "gauge", d, unit=u)).name
     ("mesh_scrubbed", "payloads", "payload units (buckets/leaves) dropped "
                                   "by the server checksum this step"),
     ("mesh_blowup_hits", "count", "gradient blowups injected this step"),
+    ("mesh_fused_round", "flag", "1 when this worker's round ran on the "
+                                 "fused wire kernels (kernels/wire_round), "
+                                 "0 on the XLA chain"),
 ])
 
 

@@ -311,6 +311,64 @@ def scenario_obs_wire_telemetry():
     assert obs_events.validate_events(evs) == []
 
 
+def _xla_chain(run, *a, **kw):
+    """``run`` with every round on the XLA chain (``dist.fused_round``
+    answering no), the reference the fused wire kernels are held to."""
+    fused = dist.fused_round
+    dist.fused_round = lambda cfg, row: False
+    try:
+        return run(*a, **kw)
+    finally:
+        dist.fused_round = fused
+
+
+def scenario_fused_round_matches_chain():
+    """The fused wire round (``kernels/wire_round``) on 4 workers gives the
+    XLA chain's trajectory bit for bit: params, worker and server memory,
+    with full and partial participation, over the ring and over psum."""
+    for p, impl in ((1.0, "pipelined"), (0.5, "pipelined"), (0.5, "psum")):
+        kw = dict(p=p, reduce_impl=impl, mesh_shape=(4,), axes=("pod",))
+        assert dist.fused_round(_setup(**kw)[3], 64)
+        fused, loss_f = _run("artemis", steps=3, **kw)
+        chain, loss_c = _xla_chain(_run, "artemis", steps=3, **kw)
+        assert loss_f == loss_c, (p, impl, loss_f, loss_c)
+        for name in ("params", "artemis"):
+            for a, b in zip(jax.tree.leaves(getattr(fused, name)),
+                            jax.tree.leaves(getattr(chain, name))):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=f"{name} {p} {impl}")
+        assert float(jnp.sum(jnp.square(fused.artemis.h))) > 0
+
+
+def scenario_fused_round_gauge():
+    """``mesh_fused_round`` reads 1 per worker where the round runs on the
+    fused kernels (psum'd over the 4 workers: 4), and 0 on each
+    configuration that keeps the XLA chain: Dore, error feedback, bit
+    flips, scrubbing and a codec other than the row-scale one."""
+    import dataclasses
+
+    cases = {
+        "artemis": (dict(), 4.0),
+        "artemis_pp": (dict(p=0.5), 4.0),
+        "dore": (dict(variant="dore"), 0.0),
+        "ef": (dict(error_feedback=True), 0.0),
+        "bitflip": (dict(fault_cfg=faults.FaultConfig(bitflip_rate=0.01)),
+                    0.0),
+        "scrub": (dict(fault_cfg=faults.FaultConfig(scrub=True)), 0.0),
+        "sparsify": (dict(codec="sparsify", codec_kwargs=(("q", 0.5),)),
+                     0.0),
+    }
+    for name, (kw, want) in cases.items():
+        mesh, model, params, dcfg, _, _, batch = _setup(
+            mesh_shape=(4,), axes=("pod",), **kw)
+        assert dist.fused_round(dcfg, 64) == (want > 0), name
+        dcfg_t = dataclasses.replace(dcfg, telemetry=True)
+        init_t, step_t = dist.make_train_step(model, sgd(0.05), dcfg_t, mesh)
+        _, (loss, m) = jax.jit(step_t)(init_t(params), batch)
+        assert np.isfinite(float(loss)), name
+        assert float(m["obs"]["mesh_fused_round"]) == want, (name, m["obs"])
+
+
 def scenario_grad_specs_inert():
     """``make_train_step``'s ``grad_specs`` keyword is inert: on a (2, 2, 2)
     pod/data/model mesh with a reduced starcoder2 placed by the sharding

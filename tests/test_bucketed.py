@@ -21,6 +21,8 @@ SCENARIOS = [
     "codec_sparsify",
     "codec_wire_guard",
     "grad_specs_inert",
+    "fused_round_matches_chain",
+    "fused_round_gauge",
 ]
 
 

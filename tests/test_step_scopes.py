@@ -4,7 +4,8 @@ The bucketed Artemis step of reduced whisper-tiny is compiled on one CPU
 device and on four virtual ones (a subprocess each: the device count is
 fixed at JAX's start), and each instruction's ``op_name`` metadata is
 checked: the model's matmuls sit under ``model``, the ring under
-``wire/ring``, every wire sub-scope is there, and no instruction JAX
+``wire/ring``, every wire sub-scope is there (the fused round's kernels
+under ``wire/encode`` and ``wire/downlink``), and no instruction JAX
 emitted outside the short allowlist below carries none of ``model``,
 ``wire``, ``optimizer``.  The chip benchmark's ``model_ms.train``,
 ``wire_ms.train`` and ``optimizer_ms.train`` read these scopes.
@@ -18,8 +19,10 @@ import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 PHASES = ("model", "wire", "optimizer")
-WIRE_SUBS = ("bucketize", "encode", "ring", "memory", "downlink",
-             "unbucketize")
+# the Artemis round runs fused (``dist.fused_round``): its memory updates
+# sit in the ``encode`` and ``downlink`` kernels, and ``memory`` is empty
+WIRE_SUBS = ("bucketize", "encode", "ring", "downlink", "unbucketize")
+FUSED = {"wire_uplink": "encode", "wire_server": "downlink"}
 
 SCENARIO = r'''
 import os, sys
@@ -133,6 +136,11 @@ def check_ring_in_wire(n, insts):
 def check_wire_sub_scopes(n, insts):
     for sub in WIRE_SUBS:
         assert any(under(parts, "wire", sub) for *_, parts, _ in insts), sub
+    assert not any(under(parts, "wire", "memory") for *_, parts, _ in insts)
+    for kernel, sub in FUSED.items():
+        ops = [parts for *_, parts, _ in insts if kernel in parts]
+        assert ops, kernel
+        assert all(under(parts, "wire", sub, kernel) for parts in ops), kernel
 
 
 def check_no_unscoped_work(n, insts):

@@ -23,6 +23,7 @@ from repro.kernels import bucket_ring as BK
 from repro.kernels import flash_attn as FA
 from repro.kernels import fused_memory as fm
 from repro.kernels import ring_sum as rs
+from repro.kernels import wire_round as WR
 from repro.launch import mesh as M
 from repro.models import layers as L
 from repro.models.model import build_model
@@ -31,6 +32,8 @@ from repro.optim import adam
 # whisper-tiny (56.5 M params) on the default bucketed wire: 16 buckets of
 # 13795 rows x 256
 WHISPER_TINY_BUCKETS = (16, 13795, 256)
+# kanana-2-30b-a3b's one-chip share (424.96 M params): 16 x 103751 x 256
+KANANA_BUCKETS = (16, 103751, 256)
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +72,25 @@ def test_bucket_acc_whisper_tiny_layout(one_chip):
         one_chip, ((b, r, c), jnp.float32), ((b, r, c), jnp.int8),
         ((b, r, 1), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("layout", [WHISPER_TINY_BUCKETS, KANANA_BUCKETS])
+@pytest.mark.parametrize("mdt", [jnp.float32, jnp.bfloat16])
+def test_wire_round_kernels(one_chip, layout, mdt):
+    """The fused wire round's two kernels at the cells' bucket layouts,
+    with the worker and server memory in either dtype."""
+    b, r, c = layout
+    text = _compile_text(
+        lambda g, h, a, k: WR.uplink_round(g, h, a, k, alpha=0.03, s=1,
+                                           interpret=False),
+        one_chip, (layout, jnp.float32), (layout, mdt), ((), jnp.float32),
+        ((b, 2), jnp.uint32))
+    assert "wire_uplink" in text and "tpu_custom_call" in text
+    text = _compile_text(
+        lambda hb, d, k: WR.server_round(hb, d, k, p=1.0, n=1, alpha=0.03,
+                                         s=1, interpret=False),
+        one_chip, (layout, mdt), (layout, jnp.float32), ((b, 2), jnp.uint32))
+    assert "wire_server" in text and "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("tiles", [(20, 1, 20),        # sim grid: N=20, d=20
@@ -202,7 +224,8 @@ def test_artemis_step_fused_attention_on_four_chips(v5e_2x2, monkeypatch):
     # encoder self (1500 x 1500), decoder self (causal), cross (128 x 1500)
     assert [c["path"] for c in calls] == ["fused", "xla", "fused"]
     assert calls[0]["q"] == [1, 1500, 4, 32]
-    for name in ("flash_attn_fwd", "flash_attn_bwd", "bucket_acc"):
+    for name in ("flash_attn_fwd", "flash_attn_bwd", "bucket_acc",
+                 "wire_uplink", "wire_server"):
         assert name in text, name
     # the model's backward sums nothing over the workers: each worker's
     # gradient is its own until the wire (a map around the kernel that the
